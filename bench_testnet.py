@@ -19,9 +19,9 @@ import json
 import sys
 import time
 
-from bench_util import enable_tpu_compilation_cache
+from tendermint_tpu.utils import compile_cache
 
-enable_tpu_compilation_cache()  # must precede any jax import
+compile_cache.enable()  # before the first compile
 
 from tendermint_tpu.utils import knobs  # noqa: E402 (post-cache-setup)
 

@@ -1,0 +1,772 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does the system still start on the chip?
+
+One process, holding the chip from start to finish, drives the main
+paths once through the entry points a user calls, at upstream's own
+shapes (BASELINE.json `configs`; only chain length is cut, listed under
+each leg's `reduced`), on data made in-process from --seed:
+
+  A  config 3: a 10,000-validator commit through ValidatorSet.verify_commit
+     and the verifier a node gets (default_verifier()), three times
+     (first sighting -> cache fill -> cache hit), then the same lanes
+     with tampered and leniency-gap cases against the scalar host oracle.
+  B  config 4: fast-sync of 64-validator x 5,000-tx blocks through
+     BlockchainReactor at verify_window=256, app-hash chain and store
+     compared with the builder's serial apply; a forged precommit stops
+     a second chain exactly below the forgery and punishes the peer.
+  C  config 5: a 4,096-header x 64-validator lite chain signed ON THE
+     DEVICE (ops/ed25519.sign_batch, sample compared with OpenSSL) and
+     certified by lite.certify_chain; a forged header is rejected at
+     its height.
+  D  config 1: four complete Nodes on loopback TCP in this process (a
+     chip belongs to one process), upstream's default timeouts, driven
+     through JSONRPCClient; every write is read back from another node.
+     Runs last, in a process that has imported JAX and used the device,
+     which is what ops/merkle.py routes on.
+
+It refuses to start unless every device JAX reports is a TPU, fails if
+a native extension did not build from the sources in the tree, asserts
+on the chip that legs A-C ran on compiled Pallas kernels only, and
+prints which kernel, backend and Merkle/SHA implementation served each
+leg, as counts. No exception is caught and reported as a field: a leg
+that fails ends the run with a non-zero exit code.
+
+EVERY TIME PRINTED HERE IS SMOKE OUTPUT: one cold or warm pass on a
+shared host, compiles included where it says so. None is a benchmark
+number and none belongs in README.md or docs/.
+
+The last line of standard output is
+{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}.
+"""
+
+from __future__ import annotations
+
+import argparse
+import hashlib
+import json
+import os
+import random
+import shutil
+import struct
+import sys
+import tempfile
+import time
+
+# upstream's shapes (BASELINE.json configs 3, 4, 5, 1); lengths cut
+A_VALIDATORS = 10_000
+B_VALIDATORS, B_TXS, B_WINDOW = 64, 5_000, 256
+B_BLOCKS = 2 * B_WINDOW + 32      # two full windows + a bucket-2048 tail
+B_FORGED_BLOCKS, B_FORGED_AT = 32, 21
+C_HEADERS, C_VALIDATORS = 4_096, 64
+C_FORGED_HEADERS, C_FORGED_AT = 32, 20
+D_NODES, D_RPC_NODES, D_WRITES = 4, 2, 20
+
+# ops/ed25519.predecomp_stats() keys that count device dispatches
+KERNELS = ("pallas_full", "pallas_pre", "jnp_full", "jnp_pre", "mesh_jnp",
+           "decompress", "sign_pallas", "sign_scalar")
+# the jitted function behind each kernel name, as jax.monitoring sees it
+KERNEL_FN = {"pallas_full": "_verify_from_bytes_pallas",
+             "pallas_pre": "_verify_pre_pallas",
+             "jnp_full": "_verify_from_bytes_jnp",
+             "jnp_pre": "_verify_pre_jnp",
+             "mesh_jnp": "_verify_from_bytes_jnp",
+             "decompress": "_decompress_to_bytes",
+             "sign_pallas": "_sign_rb_pallas"}
+
+
+def say(kind: str, **fields) -> None:
+    print(json.dumps({"smoke": kind, **fields}, sort_keys=True), flush=True)
+
+
+class CompileLog:
+    """What JAX itself reports about compiling: per jitted function the
+    trace, lowering and backend-compile seconds of each compile in
+    order, and persistent-cache hits and misses."""
+
+    _EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+               "/jax/core/compile/backend_compile_duration":
+                   "backend_compile_s"}
+
+    def __init__(self):
+        import jax
+        self.secs: dict = {}    # (fun_name, field) -> [seconds, ...]
+        self.cache = {"cache_hits": 0, "cache_misses": 0}
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, secs, **kw):
+        field = self._EVENTS.get(event)
+        if field is not None:
+            name = str(kw.get("fun_name", "?"))
+            if name.startswith("jit(") and name.endswith(")"):
+                name = name[4:-1]
+            self.secs.setdefault((name, field), []).append(round(secs, 3))
+
+    def _event(self, event, **kw):
+        key = event.rsplit("/", 1)[-1]
+        if key in self.cache:
+            self.cache[key] += 1
+
+    def table(self, first_call_s: dict) -> dict:
+        """Per "kernel[shape]": the seconds its first dispatch spent in
+        the jit call (the package's own record), joined in dispatch
+        order with JAX's split of it. The join holds when JAX reported
+        exactly one compile per shape; otherwise the raw lists are
+        given beside the shapes."""
+        by_kernel: dict = {}
+        for key, secs in first_call_s.items():
+            by_kernel.setdefault(key.split("[")[0], []).append((key, secs))
+        out = {}
+        for kernel, shapes in by_kernel.items():
+            fn = KERNEL_FN.get(kernel)
+            split = {f: self.secs.get((fn, f), [])
+                     for f in self._EVENTS.values()}
+            joined = all(len(v) == len(shapes) for v in split.values())
+            for i, (key, secs) in enumerate(shapes):
+                out[key] = {"first_call_s": secs}
+                if joined:
+                    out[key].update({f: v[i] for f, v in split.items()})
+            if not joined:
+                out[f"{kernel}:unjoined"] = split
+        return out
+
+
+def kernel_counts() -> dict:
+    from tendermint_tpu.ops import ed25519
+    s = ed25519.predecomp_stats()
+    return {k: s[k] for k in KERNELS + ("full", "fill", "hit")}
+
+
+def merkle_counts() -> dict:
+    from tendermint_tpu import telemetry
+    out = {}
+    for fam, impls in (("merkle_roots_total", ("native", "host", "mesh")),
+                       ("merkle_sha_batches_total",
+                        ("native", "host", "device"))):
+        for impl in impls:
+            out[f"tm_{fam}{{impl={impl}}}"] = int(
+                telemetry.value(fam, {"impl": impl}) or 0)
+    return out
+
+
+def delta(after: dict, before: dict) -> dict:
+    return {k: after[k] - before[k] for k in after}
+
+
+def require(cond, what: str) -> None:
+    # not `assert`: the checks must survive python -O
+    if not cond:
+        raise AssertionError(what)
+
+
+def require_pallas_only(leg: str, kernels: dict) -> None:
+    """Legs A-C on the chip: every device dispatch took a compiled
+    Pallas kernel (ops/ed25519 never asks for interpret mode), none the
+    jnp ladder, none scalar signing."""
+    off = {k: kernels[k] for k in
+           ("jnp_full", "jnp_pre", "mesh_jnp", "sign_scalar") if kernels[k]}
+    require(not off, f"leg {leg}: dispatches off the Pallas kernels: {off}")
+
+
+def spec_merkle_root(items) -> bytes:
+    """ops/merkle.py's tree spec written out with hashlib alone."""
+    sha = hashlib.sha256
+    level = [sha(b"\x00" + it).digest() for it in items]
+    m = 1
+    while m < len(level):
+        m *= 2
+    level += [b"\x00" * 32] * (m - len(level))
+    while len(level) > 1:
+        level = [sha(b"\x01" + level[i] + level[i + 1]).digest()
+                 for i in range(0, len(level), 2)]
+    return sha(b"\x02" + struct.pack("<Q", len(items)) + level[0]).digest()
+
+
+def _openssl_key(seed: bytes):
+    from cryptography.hazmat.primitives.asymmetric.ed25519 import \
+        Ed25519PrivateKey
+    return Ed25519PrivateKey.from_private_bytes(seed)
+
+
+# ---------------------------------------------------------------- leg A
+
+def tampered_lanes(items, rng) -> dict:
+    """lane -> (case, item): adversarial replacements spread over both
+    device chunks. The y >= p and x = 0 encodings are OpenSSL's
+    leniency gap (types/keys._noncanonical_point), which the scalar
+    path routes to the RFC 8032 reference so that scalar and batch
+    verdicts cannot split; the signatures crafted for them satisfy the
+    verification equation for the identity point."""
+    from tendermint_tpu.models.verifier import BATCH_CHUNK
+    from tendermint_tpu.ops.ed25519 import L_ORDER
+    from tendermint_tpu.utils import ed25519_ref as ref
+
+    p255 = (1 << 255) - 19
+    n = len(items)
+
+    def small_order_sig():
+        # R = s*B: satisfies s*B - h*A == R for A = the identity point,
+        # whatever the message
+        s = rng.randrange(1, L_ORDER)
+        return ref.point_compress(ref.point_mul(s, ref.BASE)) + \
+            s.to_bytes(32, "little")
+
+    def off_curve_pubkey():
+        y = 2
+        while ref.point_decompress(y.to_bytes(32, "little")) is not None:
+            y += 1
+        return y.to_bytes(32, "little")
+
+    def with_sign(enc: bytes) -> bytes:
+        return enc[:31] + bytes([enc[31] | 0x80])
+
+    identity = (1).to_bytes(32, "little")
+    cases = [
+        ("flipped_R", lambda p, m, s: (p, m, bytes([s[0] ^ 1]) + s[1:])),
+        ("s_plus_L", lambda p, m, s: (p, m, s[:32] + (
+            int.from_bytes(s[32:], "little") + L_ORDER).to_bytes(
+                32, "little"))),
+        ("wrong_message", lambda p, m, s: (p, m + b"x", s)),
+        ("flipped_s_bit", lambda p, m, s: (p, m, s[:32] + bytes(
+            [s[32] ^ 1]) + s[33:])),
+        ("off_curve_pubkey", lambda p, m, s: (off_curve_pubkey(), m, s)),
+        ("noncanonical_R_y_ge_p", lambda p, m, s: (
+            p, m, (p255 + 1).to_bytes(32, "little") + s[32:])),
+        ("identity_pubkey_canonical",
+         lambda p, m, s: (identity, m, small_order_sig())),
+        ("identity_pubkey_y_ge_p", lambda p, m, s: (
+            (p255 + 1).to_bytes(32, "little"), m, small_order_sig())),
+        ("identity_pubkey_x0_sign_bit",
+         lambda p, m, s: (with_sign(identity), m, small_order_sig())),
+        ("minus_identity_x0_sign_bit", lambda p, m, s: (
+            with_sign((p255 - 1).to_bytes(32, "little")), m,
+            small_order_sig())),
+    ]
+    split = min(BATCH_CHUNK, n // 2)
+    lanes = sorted(rng.sample(range(split), len(cases)) +
+                   rng.sample(range(split, n), len(cases)))
+    return {lane: (cases[i % len(cases)][0],
+                   cases[i % len(cases)][1](*items[lane]))
+            for i, lane in enumerate(lanes)}
+
+
+def leg_a(seed: int) -> dict:
+    from tendermint_tpu.models.verifier import BATCH_CHUNK, default_verifier
+    from tendermint_tpu.ops import ed25519
+    from tendermint_tpu.types import Validator, ValidatorSet
+    from tendermint_tpu.types.block import BlockID, Commit, PartSetHeader
+    from tendermint_tpu.types.keys import verify_any
+    from tendermint_tpu.types.vote import Vote, VoteType
+
+    rng = random.Random(f"{seed}/A")
+    chain_id, height = f"smoke-commit-{seed}", 7
+    t0 = time.perf_counter()
+    signers = {}
+    for _ in range(A_VALIDATORS):
+        sk = _openssl_key(rng.randbytes(32))
+        signers[sk.public_key().public_bytes_raw()] = sk
+    valset = ValidatorSet([Validator(pk, 10) for pk in signers])
+    bid = BlockID(hashlib.sha256(chain_id.encode()).digest(),
+                  PartSetHeader(1, hashlib.sha256(b"parts").digest()))
+    precommits = []
+    for idx, val in enumerate(valset.validators):
+        v = Vote(val.address, idx, height, 0, 1_000 + idx,
+                 VoteType.PRECOMMIT, bid)
+        v.signature = signers[val.pubkey].sign(v.sign_bytes(chain_id))
+        precommits.append(v)
+    commit = Commit(bid, precommits)
+    build_s = time.perf_counter() - t0
+
+    verifier = default_verifier()
+    stats0 = dict(verifier.stats)
+    n_chunks = -(-A_VALIDATORS // BATCH_CHUNK)
+    outcomes, pass_s = [], []
+    prev = ed25519.predecomp_stats()
+    for _ in range(3):
+        t0 = time.perf_counter()
+        valset.verify_commit(chain_id, bid, height, commit,
+                             verifier=verifier)
+        pass_s.append(round(time.perf_counter() - t0, 3))
+        now = ed25519.predecomp_stats()
+        outcomes.append({o: now[o] - prev[o]
+                         for o in ("full", "fill", "hit")})
+        prev = now
+    want = [{"full": n_chunks, "fill": 0, "hit": 0},
+            {"full": 0, "fill": n_chunks, "hit": 0},
+            {"full": 0, "fill": 0, "hit": n_chunks}]
+    require(outcomes == want,
+            f"predecomp cache: want full->fill->hit {want}, got {outcomes}")
+
+    items, _power = valset.commit_verification_items(
+        chain_id, bid, height, commit)
+    lanes = tampered_lanes(items, rng)
+    for lane, (_case, item) in lanes.items():
+        items[lane] = item
+    t0 = time.perf_counter()
+    got = verifier.verify(items)
+    tamper_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    oracle = [verify_any(*it) for it in items]
+    oracle_s = time.perf_counter() - t0
+    diff = [i for i in range(len(items)) if bool(got[i]) != oracle[i]]
+    require(not diff, f"device and scalar oracle disagree on lanes {diff}: "
+            f"{[(i, lanes.get(i, ('untampered',))[0]) for i in diff]}")
+    by_case: dict = {}
+    for lane, (case, _item) in lanes.items():
+        by_case.setdefault(case, []).append(bool(got[lane]))
+    # what RFC 8032 settles is held to it; on the encodings it leaves
+    # open (y >= p, small-order keys) the oracle's verdict is the rule,
+    # and the lane-by-lane comparison above is the check
+    for case in ("flipped_R", "s_plus_L", "wrong_message", "flipped_s_bit",
+                 "off_curve_pubkey", "identity_pubkey_x0_sign_bit",
+                 "minus_identity_x0_sign_bit"):
+        require(not any(by_case[case]), f"{case} accepted: {by_case}")
+    require(all(oracle[i] for i in range(len(items)) if i not in lanes),
+            "untampered lane rejected")
+
+    stats = delta(dict(verifier.stats), stats0)
+    require(stats["sigs"] == stats["jax_sigs"] == 4 * A_VALIDATORS,
+            f"leg A signatures not all on the device: {stats}")
+    return {
+        "config": "BASELINE.json configs[2]: 10000-validator VerifyCommit",
+        "validators": A_VALIDATORS, "reduced": {},
+        "chunks": [min(BATCH_CHUNK, A_VALIDATORS - lo)
+                   for lo in range(0, A_VALIDATORS, BATCH_CHUNK)],
+        "verifier": {"backend": verifier.backend,
+                     "mesh_devices": verifier.mesh_devices,
+                     "stats_delta": stats},
+        "predecomp_per_pass": outcomes,
+        "tampered_lanes": {str(k): v[0] for k, v in lanes.items()},
+        "tampered_verdicts_by_case": by_case,
+        "oracle": "types/keys.verify_any (OpenSSL + RFC 8032 reference)",
+        "smoke_seconds": {"build": round(build_s, 2),
+                          "verify_commit_passes_incl_compiles": pass_s,
+                          "tampered_verify": round(tamper_s, 3),
+                          "scalar_oracle": round(oracle_s, 2)},
+    }
+
+
+# ---------------------------------------------------------------- leg B
+
+class _PunishedPeers:
+    """The slice of a p2p Switch the fast-sync reactor punishes through
+    (blockchain/reactor._stop_peer)."""
+
+    def __init__(self):
+        self.peers = self
+        self.stopped = []
+
+    def get(self, peer_id):
+        return peer_id
+
+    def stop_peer_for_error(self, peer, err):
+        self.stopped.append((peer, str(err)))
+
+
+def leg_b(seed: int) -> dict:
+    import bench_fastsync
+    from tendermint_tpu.models.verifier import BatchVerifier
+
+    t0 = time.perf_counter()
+    builder = bench_fastsync.ChainBuilder(
+        B_VALIDATORS, B_TXS, chain_id=f"smoke-sync-{seed}")
+    blocks = builder.build(B_BLOCKS + 1)    # + the sentinel's LastCommit
+    build_s = time.perf_counter() - t0
+
+    verifier = BatchVerifier("auto")
+    reactor = bench_fastsync.sync_reactor(builder.gen, verifier, B_WINDOW)
+    sync_s = bench_fastsync.drive_sync(reactor, blocks)
+    reactor.stop()
+    n_sigs = B_BLOCKS * B_VALIDATORS
+    require(reactor.state.last_block_height == B_BLOCKS ==
+            reactor.block_store.height(),
+            f"synced to {reactor.state.last_block_height}, store "
+            f"{reactor.block_store.height()}, want {B_BLOCKS}")
+    # the builder's serial apply: block h+1's header carries the app
+    # hash after block h, and every stored block is the builder's own
+    require(reactor.state.app_hash == blocks[B_BLOCKS].header.app_hash,
+            "final app hash differs from the builder's serial apply")
+    for blk in blocks[:B_BLOCKS]:
+        meta = reactor.block_store.load_block_meta(blk.header.height)
+        require(meta.block_id.hash == blk.hash() and
+                meta.header.app_hash == blk.header.app_hash,
+                f"stored block {blk.header.height} differs")
+    stats = dict(verifier.stats)
+    require(stats["jax_sigs"] == stats["sigs"] == n_sigs,
+            f"commit signatures {n_sigs}, verifier saw {stats}")
+
+    # a second, short chain from the same validators with one forged
+    # precommit in the commit FOR block B_FORGED_AT
+    forged = bench_fastsync.ChainBuilder(
+        B_VALIDATORS, B_TXS, chain_id=f"smoke-forged-{seed}")
+    fblocks = forged.build(B_FORGED_BLOCKS + 1)
+    vote = fblocks[B_FORGED_AT].last_commit.precommits[seed % B_VALIDATORS]
+    vote.signature = vote.signature[:40] + bytes(
+        [vote.signature[40] ^ 1]) + vote.signature[41:]
+    fverifier = BatchVerifier("auto")
+    freactor = bench_fastsync.sync_reactor(forged.gen, fverifier, B_WINDOW)
+    freactor.switch = _PunishedPeers()
+    bench_fastsync.drive_sync(freactor, fblocks)
+    freactor.stop()
+    require(freactor.state.last_block_height == B_FORGED_AT - 1 ==
+            freactor.block_store.height(),
+            f"forged commit for block {B_FORGED_AT}: applied up to "
+            f"{freactor.state.last_block_height}")
+    require({p for p, _ in freactor.switch.stopped} ==
+            {bench_fastsync.PEER_ID} and
+            bench_fastsync.PEER_ID not in freactor.pool.peers,
+            f"serving peer not punished: {freactor.switch.stopped}")
+    return {
+        "config": "BASELINE.json configs[3]: fast-sync replay, "
+                  "64 validators, 5000-tx blocks",
+        "validators": B_VALIDATORS, "txs_per_block": B_TXS,
+        "verify_window": B_WINDOW, "blocks": B_BLOCKS,
+        "reduced": {"blocks": f"{B_BLOCKS} of upstream's 50000"},
+        "commit_signatures": n_sigs,
+        "verifier": {"backend": verifier.backend,
+                     "mesh_devices": verifier.mesh_devices,
+                     "stats": stats},
+        "app_hash": reactor.state.app_hash.hex(),
+        "forged": {"blocks": B_FORGED_BLOCKS,
+                   "forged_commit_for_block": B_FORGED_AT,
+                   "applied": freactor.state.last_block_height,
+                   "punished": freactor.switch.stopped,
+                   "verifier_stats": dict(fverifier.stats)},
+        "smoke_seconds": {"build_chain": round(build_s, 2),
+                          "sync": round(sync_s, 3)},
+    }
+
+
+# ---------------------------------------------------------------- leg C
+
+def lite_chain(seed: int, n_headers: int):
+    """([FullCommit], valset, seeds by validator index): one constant
+    validator set, every precommit signed by ops/ed25519.sign_batch."""
+    from tendermint_tpu.lite.types import FullCommit, SignedHeader
+    from tendermint_tpu.ops import ed25519
+    from tendermint_tpu.types.block import (BlockID, Commit, Header,
+                                            PartSetHeader)
+    from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+    from tendermint_tpu.types.vote import Vote, VoteType
+
+    rng = random.Random(f"{seed}/C")
+    chain_id = f"smoke-lite-{seed}"
+    seed_of = {}
+    for _ in range(C_VALIDATORS):
+        s = rng.randbytes(32)
+        seed_of[_openssl_key(s).public_key().public_bytes_raw()] = s
+    valset = ValidatorSet([Validator(pk, 10) for pk in seed_of])
+    vals = valset.validators
+    seeds = [seed_of[v.pubkey] for v in vals]
+    vhash = valset.hash()
+    parts = PartSetHeader(1, hashlib.sha256(b"lite-parts").digest())
+    headers, bids, msgs = [], [], []
+    for h in range(1, n_headers + 1):
+        header = Header(chain_id=chain_id, height=h, time_ns=h,
+                        validators_hash=vhash,
+                        app_hash=h.to_bytes(32, "big"))
+        bid = BlockID(header.hash(), parts)
+        headers.append(header)
+        bids.append(bid)
+        # v0.16 sign bytes carry no validator identity and the votes
+        # share one timestamp: every validator signs the same bytes
+        msgs.append(Vote(vals[0].address, 0, h, 0, h, VoteType.PRECOMMIT,
+                         bid).sign_bytes(chain_id))
+    sigs = ed25519.sign_batch(
+        [s for _ in range(n_headers) for s in seeds],
+        [m for m in msgs for _ in range(C_VALIDATORS)])
+    fcs = []
+    for i, h in enumerate(range(1, n_headers + 1)):
+        precommits = []
+        for j, val in enumerate(vals):
+            v = Vote(val.address, j, h, 0, h, VoteType.PRECOMMIT, bids[i])
+            v.signature = sigs[i * C_VALIDATORS + j]
+            precommits.append(v)
+        fcs.append(FullCommit(
+            SignedHeader(headers[i], Commit(bids[i], precommits), bids[i]),
+            valset))
+    return chain_id, fcs, valset, seeds, msgs, sigs
+
+
+def leg_c(seed: int) -> dict:
+    from tendermint_tpu.lite.certifier import (CertificationError,
+                                               certify_chain,
+                                               default_window)
+    from tendermint_tpu.lite.types import FullCommit, SignedHeader
+    from tendermint_tpu.models.verifier import default_verifier
+    from tendermint_tpu.types.block import BlockID, Commit, Header
+    from tendermint_tpu.types.vote import Vote
+
+    t0 = time.perf_counter()
+    chain_id, fcs, valset, seeds, msgs, sigs = lite_chain(seed, C_HEADERS)
+    build_s = time.perf_counter() - t0
+    n_sigs = C_HEADERS * C_VALIDATORS
+    sample = range(0, n_sigs, n_sigs // 256)
+    keys = [_openssl_key(s) for s in seeds]
+    bad = [i for i in sample
+           if sigs[i] != keys[i % C_VALIDATORS].sign(msgs[i // C_VALIDATORS])]
+    require(not bad, f"device signatures differ from OpenSSL at {bad}")
+
+    verifier = default_verifier()
+    stats0 = dict(verifier.stats)
+    t0 = time.perf_counter()
+    certify_chain(chain_id, fcs, trusted=valset)
+    certify_s = time.perf_counter() - t0
+    stats = delta(dict(verifier.stats), stats0)
+    require(stats["sigs"] == stats["jax_sigs"] == n_sigs,
+            f"lite chain signatures not all on the device: {stats}")
+
+    # a header nobody signed, dressed in the genuine commit's signatures
+    real = fcs[C_FORGED_AT - 1].signed_header
+    header = Header(chain_id=chain_id, height=C_FORGED_AT,
+                    time_ns=C_FORGED_AT,
+                    validators_hash=real.header.validators_hash,
+                    app_hash=b"\xff" * 32)
+    bid = BlockID(header.hash(), real.block_id.parts)
+    votes = [Vote(v.validator_address, v.validator_index, v.height, v.round,
+                  v.timestamp_ns, v.type, bid, v.signature)
+             for v in real.commit.precommits]
+    forged = list(fcs[:C_FORGED_HEADERS])
+    forged[C_FORGED_AT - 1] = FullCommit(
+        SignedHeader(header, Commit(bid, votes), bid), valset)
+    try:
+        certify_chain(chain_id, forged, trusted=valset)
+    except CertificationError as e:
+        rejected = str(e)
+    else:
+        raise AssertionError("forged header certified")
+    require(rejected.startswith(f"height {C_FORGED_AT}:"),
+            f"forged header {C_FORGED_AT} rejected elsewhere: {rejected}")
+    return {
+        "config": "BASELINE.json configs[4]: lite-client chain "
+                  "certification, 64 validators",
+        "headers": C_HEADERS, "validators": C_VALIDATORS,
+        "reduced": {"headers": f"{C_HEADERS} of upstream's 1000000"},
+        "signatures": n_sigs, "signed_by": "ops/ed25519.sign_batch",
+        "openssl_sample": {"compared": len(sample), "different": 0},
+        "certify_window_headers": default_window(C_VALIDATORS),
+        "verifier": {"backend": verifier.backend,
+                     "mesh_devices": verifier.mesh_devices,
+                     "stats_delta": stats},
+        "forged": {"headers": C_FORGED_HEADERS, "forged_height": C_FORGED_AT,
+                   "rejected_with": rejected},
+        "smoke_seconds": {"build_and_sign_incl_compile": round(build_s, 2),
+                          "certify": round(certify_s, 3)},
+    }
+
+
+# ---------------------------------------------------------------- leg D
+
+def merkle_plane_check(seed: int) -> dict:
+    """The host-facing Merkle/SHA entry points in a process that holds
+    the device, against hashlib: a 5,000-leaf tx root and a 1,024-row
+    fixed-length SHA wave (the statetree's rehash shape)."""
+    from tendermint_tpu.ops import merkle
+    rng = random.Random(f"{seed}/D.merkle")
+    txs = [b"k%d=v%d" % (i, rng.randrange(1 << 30)) for i in range(B_TXS)]
+    require(merkle.root_host(txs) == spec_merkle_root(txs),
+            "ops.merkle.root_host differs from the hashlib spec")
+    wave = [rng.randbytes(65) for _ in range(1024)]
+    t0 = time.perf_counter()
+    got = merkle.sha256_many_host(wave)
+    first_s = time.perf_counter() - t0
+    require(got == [hashlib.sha256(p).digest() for p in wave],
+            "ops.merkle.sha256_many_host differs from hashlib")
+    t0 = time.perf_counter()
+    merkle.sha256_many_host([rng.randbytes(65) for _ in range(700)])
+    return {"tx_root_leaves": len(txs), "sha_wave_rows": [1024, 700],
+            "smoke_seconds": {
+                "sha_wave_first_incl_compile": round(first_s, 3),
+                "sha_wave_same_bucket": round(time.perf_counter() - t0, 3)}}
+
+
+def _wait(cond, what: str, timeout_s: float = 120.0) -> None:
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        if time.monotonic() > deadline:
+            raise TimeoutError(f"leg D: timed out waiting for {what}")
+        time.sleep(0.05)
+
+
+def leg_d(seed: int) -> dict:
+    from tendermint_tpu.config import default_config
+    from tendermint_tpu.models.verifier import default_verifier
+    from tendermint_tpu.node import Node
+    from tendermint_tpu.rpc.client import JSONRPCClient
+    from tendermint_tpu.types import GenesisDoc, GenesisValidator, PrivKey
+    from tendermint_tpu.types.priv_validator import (LocalSigner,
+                                                     PrivValidator)
+
+    merkle_check = merkle_plane_check(seed)
+    rng = random.Random(f"{seed}/D")
+    keys = [PrivKey.generate(rng.randbytes(32)) for _ in range(D_NODES)]
+    gen = GenesisDoc(chain_id=f"smoke-net-{seed}", genesis_time_ns=1,
+                     validators=[GenesisValidator(k.pubkey.ed25519, 10)
+                                 for k in keys])
+    verifier = default_verifier()
+    stats0 = dict(verifier.stats)
+    home = tempfile.mkdtemp(prefix="chip-smoke-")
+    nodes = []
+    t0 = time.perf_counter()
+    try:
+        for i, key in enumerate(keys):
+            cfg = default_config(os.path.join(home, f"node{i}"))
+            cfg.p2p.laddr = "tcp://127.0.0.1:0"
+            cfg.p2p.addr_book_strict = False
+            cfg.rpc.laddr = "tcp://127.0.0.1:0"
+            nodes.append(Node(cfg, gen,
+                              priv_validator=PrivValidator(LocalSigner(key)),
+                              in_memory=True, with_p2p=True,
+                              with_rpc=i < D_RPC_NODES))
+        require(all(n.verifier is verifier for n in nodes),
+                "nodes do not share the process verifier")
+        for node in nodes:
+            node.start()
+        for i, node in enumerate(nodes):
+            for other in nodes[:i]:
+                node.switch.dial_peer(other.switch.listen_address)
+        clients = [JSONRPCClient("http://%s:%d" % n.rpc_address)
+                   for n in nodes[:D_RPC_NODES]]
+        _wait(lambda: all(n.height >= 1 for n in nodes), "first block")
+        status = clients[0].call("status")
+        require(status["latest_block_height"] >= 1, f"status: {status}")
+        boot_s = time.perf_counter() - t0
+
+        t0 = time.perf_counter()
+        last_height = 0
+        for i in range(D_WRITES):
+            key, val = b"smoke%d-%d" % (seed, i), b"v%d" % rng.randrange(
+                1 << 30)
+            writer, reader = i % D_RPC_NODES, (i + 1) % D_RPC_NODES
+            res = clients[writer].call("broadcast_tx_commit",
+                                       tx=key + b"=" + val)
+            require(res["deliver_tx"]["code"] == 0, f"write {i}: {res}")
+            last_height = res["height"]
+            # acknowledged at res["height"]: any node that has reached
+            # that height must serve it
+            _wait(lambda: nodes[reader].height >= last_height,
+                  f"node{reader} to reach height {last_height}")
+            got = clients[reader].call("abci_query", path="/store",
+                                       data=key)
+            require(bytes.fromhex(got["response"]["value"]) == val,
+                    f"write {i} acknowledged at height {last_height} by "
+                    f"node{writer} not read back from node{reader}: {got}")
+        writes_s = time.perf_counter() - t0
+
+        # block last_height+1 carries the app hash after the last write
+        agree_at = last_height + 1
+        _wait(lambda: all(n.height >= agree_at for n in nodes),
+              f"all nodes at height {agree_at}")
+        metas = [n.block_store.load_block_meta(agree_at) for n in nodes]
+        require(len({m.block_id.hash for m in metas}) == 1 and
+                len({m.header.app_hash for m in metas}) == 1,
+                f"nodes disagree at height {agree_at}")
+        heights = [n.height for n in nodes]
+    finally:
+        for node in nodes:
+            node.stop()
+        shutil.rmtree(home, ignore_errors=True)
+    stats = delta(dict(verifier.stats), stats0)
+    return {
+        "config": "BASELINE.json configs[0]: 4-validator net, kvstore app",
+        "nodes": D_NODES, "rpc_nodes": D_RPC_NODES,
+        "transport": "loopback TCP, one process",
+        "timeouts": "upstream defaults (config.default_config)",
+        "reduced": {},
+        "writes": D_WRITES, "read_back_from_another_node": D_WRITES,
+        "agreed": {"height": agree_at,
+                   "block_hash": metas[0].block_id.hash.hex(),
+                   "app_hash": metas[0].header.app_hash.hex(),
+                   "node_heights_at_check": heights},
+        "verifier": {
+            "backend": verifier.backend,
+            "auto_threshold": verifier.auto_threshold,
+            "stats_delta": stats,
+            "signatures_total": stats["sigs"],
+            "signatures_on_device": stats["jax_sigs"],
+            "signatures_on_host": stats["sigs"] - stats["jax_sigs"],
+            "coalesced_calls": stats["coalesced_calls"]},
+        "merkle_plane_check": merkle_check,
+        "smoke_seconds": {"boot_to_first_block": round(boot_s, 2),
+                          "writes_and_reads": round(writes_s, 2)},
+    }
+
+
+# ----------------------------------------------------------------- main
+
+def native_check() -> dict:
+    from tendermint_tpu import native
+    status = native.status()    # builds and loads; a failure raises
+    missing = [name for name, s in status.items() if not s["loaded"]]
+    require(not missing, f"native extensions not loaded: {missing} "
+            "(no C++ toolchain, or TM_TPU_NO_NATIVE set)")
+    require(native.aead_available(),
+            "native AEAD kernels failed their RFC 8439 self-check")
+    return {"extensions": status, "available": native.available(),
+            "aead_self_check": True}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(
+        description="Drive the system's main paths once on the chip.")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of every key, transaction and tamper choice")
+    args = ap.parse_args(argv)
+
+    import jax
+    devs = jax.devices()
+    if any(d.platform != "tpu" for d in devs):
+        found = sorted({f"{d.platform} ({d.device_kind})" for d in devs})
+        print(f"chip_smoke: needs a TPU on every device; JAX found "
+              f"{len(devs)} device(s): {', '.join(found)} "
+              f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}). "
+              f"Nothing was built or run.", file=sys.stderr)
+        return 2
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+
+    from tendermint_tpu import telemetry
+    from tendermint_tpu.ops import ed25519
+    from tendermint_tpu.utils import compile_cache
+    from tendermint_tpu.utils.log import setup_logging
+    import jaxlib
+    import libtpu
+    setup_logging("error")      # four nodes at info drown the records
+    telemetry.configure(enabled=True)
+    log = CompileLog()
+    say("start", device=device, seed=args.seed,
+        versions={"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                  "libtpu": libtpu.__version__},
+        compile_cache_dir=compile_cache.enable(),
+        note="every time printed is smoke output, not a benchmark number")
+    say("native", **native_check())
+
+    t_all = time.perf_counter()
+    for name, leg in (("A", leg_a), ("B", leg_b), ("C", leg_c),
+                      ("D", leg_d)):
+        k0, m0, c0 = kernel_counts(), merkle_counts(), dict(log.cache)
+        t0 = time.perf_counter()
+        record = leg(args.seed)
+        kernels = delta(kernel_counts(), k0)
+        if name != "D":
+            require_pallas_only(name, kernels)
+        say("leg", leg=name, ok=True, kernels=kernels,
+            merkle_sha=delta(merkle_counts(), m0),
+            persistent_cache=delta(log.cache, c0),
+            smoke_leg_seconds=round(time.perf_counter() - t0, 2), **record)
+
+    memory = [{"id": d.id, "peak_bytes_in_use":
+               d.memory_stats()["peak_bytes_in_use"]} for d in devs]
+    require(all(m["peak_bytes_in_use"] for m in memory),
+            f"a device did no work: {memory}")
+    say("compiles",
+        per_shape=log.table(ed25519.predecomp_stats()["first_call_s"]),
+        persistent_cache=log.cache, device_memory=memory,
+        smoke_total_seconds=round(time.perf_counter() - t_all, 2))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

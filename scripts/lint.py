@@ -80,7 +80,8 @@ def main(argv=None) -> int:
                          "pragmas than this (default 15)")
     ap.add_argument("paths", nargs="*",
                     help="scan set override (default: the package, "
-                         "scripts/, bench*.py, benchmarks/)")
+                         "scripts/, bench*.py, benchmarks/, "
+                         "chip_smoke.py)")
     args = ap.parse_args(argv)
 
     import time

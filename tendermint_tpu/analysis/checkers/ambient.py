@@ -52,12 +52,10 @@ BLESSED = frozenset((
     # verification plane
     "tendermint_tpu/models/verifier.py:_default",
     "tendermint_tpu/models/verifier.py:_fetch_pool",
-    "tendermint_tpu/models/verifier.py:_mesh_kernels",
     "tendermint_tpu/ops/merkle.py:_mesh_state",
     "tendermint_tpu/ops/merkle.py:_root_from_digests_jit",
     "tendermint_tpu/ops/ed25519.py:_predecomp_stats",
     "tendermint_tpu/ops/ed25519.py:_sign_params_cache",
-    "tendermint_tpu/parallel/mesh.py:_impl",
     "tendermint_tpu/parallel/mesh.py:_mesh_cache",
     "tendermint_tpu/parallel/mesh.py:_kernel_cache",
     "tendermint_tpu/utils/ed25519_fast.py:_b_table",
@@ -65,14 +63,6 @@ BLESSED = frozenset((
     "tendermint_tpu/types/keys.py:_ossl_pub_cls",
     "tendermint_tpu/types/encoding.py:_native_state",
     # native library handles (feature-detected once per process)
-    "tendermint_tpu/native/__init__.py:_lib",
-    "tendermint_tpu/native/__init__.py:_tried",
-    "tendermint_tpu/native/__init__.py:_codec_mod",
-    "tendermint_tpu/native/__init__.py:_codec_tried",
-    "tendermint_tpu/native/__init__.py:_prep_mod",
-    "tendermint_tpu/native/__init__.py:_prep_tried",
-    "tendermint_tpu/native/__init__.py:_kv_mod",
-    "tendermint_tpu/native/__init__.py:_kv_tried",
     "tendermint_tpu/native/__init__.py:_aead_ok",
     # telemetry planes (process-wide by design; the registry IS the
     # blessed ambient every instrument rides on)

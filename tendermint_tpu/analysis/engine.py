@@ -36,7 +36,7 @@ GUARDED_RE = re.compile(r"#:\s*guarded_by\s+([A-Za-z_]\w*)")
 #: the default scan set, relative to the repo root
 DEFAULT_SCAN = ("tendermint_tpu", "scripts", "benchmarks",
                 "bench.py", "bench_lite.py", "bench_util.py",
-                "bench_fastsync.py", "bench_testnet.py")
+                "bench_fastsync.py", "bench_testnet.py", "chip_smoke.py")
 
 
 @dataclass

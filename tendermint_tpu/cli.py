@@ -466,6 +466,10 @@ def main(argv=None) -> int:
         fn=cmd_unsafe_reset_priv_validator)
 
     args = p.parse_args(argv)
+    if args.cmd in ("node", "replica", "shardset", "lite"):
+        # these verify signature batches, so on a TPU host they compile
+        from tendermint_tpu.utils import compile_cache
+        compile_cache.enable()
     return args.fn(args)
 
 

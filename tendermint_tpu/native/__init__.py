@@ -1,15 +1,25 @@
 """Native host-ops loader.
 
-Compiles hostops.cpp to a shared library on first use (g++ is in the
-image; build takes ~1s and is cached next to the source) and exposes the
-C ABI through ctypes. Every entry point has a pure-Python fallback, so
-the framework runs even where no compiler exists — `available()` reports
-which path is active.
+Four extensions are compiled from the C++ sources in this directory on
+first use (g++ is in the image; a build takes seconds): the ctypes
+library `_hostops` and the CPython modules `_tmcodec`, `_tmprep` and
+`_tmkv`. A built file is named after the hash of the sources and flags
+it was built from, so a binary left over from other sources, or copied
+in from another tree, is never loaded: the name it would need does not
+exist until this tree builds it.
+
+Every entry point has a pure-Python fallback, taken where the host has
+no C++ toolchain or TM_TPU_NO_NATIVE is set. A build or load that FAILS
+on a host that has the toolchain is an error (NativeBuildError), raised
+on every use, never a quiet return to the fallbacks. `status()` reports
+what is loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,180 +28,200 @@ from tendermint_tpu.utils import knobs
 from typing import List, Optional
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "hostops.cpp")
-_LIB = os.path.join(_HERE, "_hostops.so")
-
-_lib = None
-_tried = False
 _lock = threading.Lock()
 
 
-def _build() -> Optional[str]:
-    if os.path.exists(_LIB) and \
-            os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return _LIB
-    # per-PID tmp: concurrent builders must not interleave writes into
-    # one tmp file (os.replace keeps the install itself atomic)
-    tmp = _LIB + f".{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return None
-    os.replace(tmp, _LIB)
-    return _LIB
+class NativeBuildError(RuntimeError):
+    """A native extension failed to build or load although the host has
+    a compiler."""
+
+
+class _Ext:
+    """One extension: its sources, how to build it, and the loaded
+    handle (a ctypes CDLL, or the imported CPython module)."""
+
+    def __init__(self, name: str, src: str, deps: tuple = (),
+                 opt: str = "-O2", std: str = "c++17",
+                 cpython: bool = True, bind=None):
+        # deps: sources the src #includes. std: per extension — only
+        # kvcore needs c++20 (transparent unordered_map lookup). bind:
+        # declares a ctypes library's signatures once it is open.
+        self.name = name
+        self.src = src
+        self.deps = deps
+        self.opt = opt
+        self.std = std
+        self.cpython = cpython
+        self.bind = bind
+        self.handle = None
+        self.path: Optional[str] = None
+        self._tried = False
+        self._error: Optional[BaseException] = None
+
+    def _flags(self) -> list:
+        return [self.opt, "-shared", "-fPIC", f"-std={self.std}"]
+
+    def lib_path(self) -> str:
+        """<dir>/<name>.<hash of flags + source bytes>.so"""
+        h = hashlib.sha256(" ".join(self._flags()).encode())
+        for path in (self.src,) + self.deps:
+            with open(path, "rb") as f:
+                h.update(f.read())
+        return os.path.join(os.path.dirname(self.src),
+                            f"{self.name}.{h.hexdigest()[:16]}.so")
+
+    def build_lib(self) -> Optional[str]:
+        """Path of the library built from the sources as they are now,
+        compiling it unless that exact file exists. None where the host
+        has no toolchain. Builds of other sources are removed."""
+        lib = self.lib_path()
+        if os.path.exists(lib):
+            return lib
+        cmd = ["g++"] + self._flags()
+        if self.cpython:
+            import sysconfig
+            inc = sysconfig.get_paths().get("include")
+            if not inc or not os.path.exists(os.path.join(inc, "Python.h")):
+                return None
+            cmd.append(f"-I{inc}")
+        # per-PID tmp: concurrent builders must not interleave writes
+        # into one tmp file (os.replace keeps the install atomic)
+        tmp = f"{lib[:-3]}.{os.getpid()}.so.tmp"
+        try:
+            subprocess.run(cmd + [self.src, "-o", tmp], check=True,
+                           capture_output=True, timeout=300)
+        except FileNotFoundError:
+            return None  # no g++ on this host
+        except subprocess.CalledProcessError as e:
+            raise NativeBuildError(
+                f"{self.name}: g++ failed on {self.src}:\n"
+                f"{e.stderr.decode(errors='replace')[-2000:]}") from e
+        except subprocess.TimeoutExpired as e:
+            raise NativeBuildError(
+                f"{self.name}: g++ timed out on {self.src}") from e
+        os.replace(tmp, lib)
+        pattern = os.path.join(os.path.dirname(lib), self.name + ".*so")
+        for old in glob.glob(pattern):
+            if old != lib:
+                try:
+                    os.unlink(old)
+                except OSError:
+                    pass  # another process already removed it
+        return lib
+
+    def ensure_loaded(self):
+        """The loaded handle, building first if needed; None only where
+        native code is switched off or the host cannot build."""
+        if not self._tried:
+            with _lock:
+                if not self._tried:
+                    if not knobs.knob_set("TM_TPU_NO_NATIVE"):
+                        try:
+                            self.path = self.build_lib()
+                            if self.path is not None:
+                                self.handle = self._open_lib(self.path)
+                        except (NativeBuildError, OSError,
+                                ImportError) as e:
+                            self._error = e
+                    self._tried = True  # last: unlocked readers key on it
+        if self._error is not None:
+            raise NativeBuildError(
+                f"native extension {self.name} is unusable: "
+                f"{self._error}") from self._error
+        return self.handle
+
+    def _open_lib(self, path: str):
+        if not self.cpython:
+            lib = ctypes.CDLL(path)
+            if self.bind is not None:
+                self.bind(lib)
+            return lib
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(self.name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+
+def _src(name: str) -> str:
+    return os.path.join(_HERE, name)
+
+
+def _bind_hostops(lib) -> None:
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    u64p = ctypes.POINTER(ctypes.c_uint64)
+    lib.tm_sha256_batch.argtypes = [u8p, u64p, ctypes.c_uint64, u8p]
+    lib.tm_merkle_root.argtypes = [u8p, u64p, ctypes.c_uint64, u8p]
+    lib.tm_merkle_root_from_digests.argtypes = [
+        u8p, ctypes.c_uint64, u8p]
+    lib.tm_merkle_proof.argtypes = [u8p, u64p, ctypes.c_uint64,
+                                    ctypes.c_uint64, u8p, u8p]
+    lib.tm_merkle_proof.restype = ctypes.c_uint64
+    lib.tm_merkle_tree_proofs.argtypes = [u8p, u64p, ctypes.c_uint64,
+                                          u8p, u8p]
+    lib.tm_merkle_tree_proofs.restype = ctypes.c_uint64
+    lib.tm_partset_build.argtypes = [u8p, ctypes.c_uint64,
+                                     ctypes.c_uint64, u8p, u8p]
+    lib.tm_partset_build.restype = ctypes.c_uint64
+    lib.tm_ed25519_prepare.argtypes = [u8p, u8p, u8p, u64p,
+                                       ctypes.c_uint64, u8p, u8p]
+    lib.tm_aead_seal_one.argtypes = [
+        u8p, u8p, u8p, ctypes.c_uint64, u8p, ctypes.c_uint64, u8p]
+    lib.tm_aead_seal_burst.argtypes = [
+        u8p, ctypes.c_uint64, ctypes.c_uint32, u8p, u64p,
+        ctypes.c_uint64, u8p]
+    lib.tm_aead_open_burst.argtypes = [
+        u8p, ctypes.c_uint64, ctypes.c_uint32, u8p, u64p,
+        ctypes.c_uint64, u8p]
+    lib.tm_aead_open_burst.restype = ctypes.c_int64
+
+
+_HOSTOPS = _Ext("_hostops", _src("hostops.cpp"), opt="-O3", cpython=False,
+                bind=_bind_hostops)
+# A true CPython extension (not ctypes): the canonical-JSON encoder
+# walks Python object graphs, which a C ABI can't.
+_CODEC = _Ext("_tmcodec", _src("codec.cpp"))
+# Batched Ed25519 verify-prep + signing phases: takes the verifier's
+# items list and returns the device-bound arrays in one call (GIL
+# released for the SHA-512 loop). prep.cpp #includes hostops.cpp.
+_PREP = _Ext("_tmprep", _src("prep.cpp"), deps=(_src("hostops.cpp"),),
+             opt="-O3")
+# Native KVStore core.
+_KV = _Ext("_tmkv", _src("kvcore.cpp"), deps=(_src("hostops.cpp"),),
+           opt="-O3", std="c++20")
+_EXTS = (_HOSTOPS, _CODEC, _PREP, _KV)
+
+
+def status() -> dict:
+    """Per extension, whether it is loaded and from which file (the
+    file name carries the hash of the sources it was built from).
+    Builds and loads whatever is not loaded yet; a failure raises."""
+    return {e.name: {"loaded": e.ensure_loaded() is not None,
+                     "file": e.path and os.path.basename(e.path)}
+            for e in _EXTS}
 
 
 def _load():
-    global _lib, _tried
-    with _lock:
-        if _tried:
-            return _lib
-        _tried = True
-        if knobs.knob_set("TM_TPU_NO_NATIVE"):
-            return None
-        path = _build()
-        if path is None:
-            return None
-        try:
-            lib = ctypes.CDLL(path)
-        except OSError:
-            return None
-        u8p = ctypes.POINTER(ctypes.c_uint8)
-        u64p = ctypes.POINTER(ctypes.c_uint64)
-        lib.tm_sha256_batch.argtypes = [u8p, u64p, ctypes.c_uint64, u8p]
-        lib.tm_merkle_root.argtypes = [u8p, u64p, ctypes.c_uint64, u8p]
-        lib.tm_merkle_root_from_digests.argtypes = [
-            u8p, ctypes.c_uint64, u8p]
-        lib.tm_merkle_proof.argtypes = [u8p, u64p, ctypes.c_uint64,
-                                        ctypes.c_uint64, u8p, u8p]
-        lib.tm_merkle_proof.restype = ctypes.c_uint64
-        lib.tm_merkle_tree_proofs.argtypes = [u8p, u64p, ctypes.c_uint64,
-                                              u8p, u8p]
-        lib.tm_merkle_tree_proofs.restype = ctypes.c_uint64
-        try:
-            lib.tm_partset_build.argtypes = [u8p, ctypes.c_uint64,
-                                             ctypes.c_uint64, u8p, u8p]
-            lib.tm_partset_build.restype = ctypes.c_uint64
-        except AttributeError:
-            pass  # stale .so from before the part-set kernel: the
-            #       partset_build() wrapper reports unavailable
-        lib.tm_ed25519_prepare.argtypes = [u8p, u8p, u8p, u64p,
-                                           ctypes.c_uint64, u8p, u8p]
-        try:
-            lib.tm_aead_seal_one.argtypes = [
-                u8p, u8p, u8p, ctypes.c_uint64, u8p, ctypes.c_uint64, u8p]
-            lib.tm_aead_seal_burst.argtypes = [
-                u8p, ctypes.c_uint64, ctypes.c_uint32, u8p, u64p,
-                ctypes.c_uint64, u8p]
-            lib.tm_aead_open_burst.argtypes = [
-                u8p, ctypes.c_uint64, ctypes.c_uint32, u8p, u64p,
-                ctypes.c_uint64, u8p]
-            lib.tm_aead_open_burst.restype = ctypes.c_int64
-        except AttributeError:
-            pass  # stale .so from before the AEAD kernels: hostops
-            #       still serves merkle/sha; aead_available() stays False
-        _lib = lib
-        return _lib
+    return _HOSTOPS.ensure_loaded()
 
 
 def available() -> bool:
     return _load() is not None
 
 
-# -- canonical-JSON codec extension (codec.cpp) -----------------------------
-# A true CPython extension (not ctypes): the encoder walks Python object
-# graphs, which a C ABI can't. Built with the same g++ the hostops use,
-# against the running interpreter's headers.
-
-_CODEC_SRC = os.path.join(_HERE, "codec.cpp")
-_CODEC_LIB = os.path.join(_HERE, "_tmcodec.so")
-_codec_mod = None
-_codec_tried = False
-
-
 def codec():
     """The _tmcodec extension module, or None when unavailable.
     Exposes canonical_dumps(obj)->bytes and the Fallback exception."""
-    global _codec_mod, _codec_tried
-    with _lock:
-        if _codec_tried:
-            return _codec_mod
-        _codec_tried = True
-        if knobs.knob_set("TM_TPU_NO_NATIVE"):
-            return None
-        _codec_mod = _load_ext("_tmcodec", _CODEC_SRC, _CODEC_LIB)
-        return _codec_mod
-
-
-# -- batched Ed25519 verify-prep extension (prep.cpp) -----------------------
-# CPython extension like the codec: takes the verifier's items list and
-# returns the device-bound arrays in one call (GIL released for the
-# SHA-512 loop). Falls back to None -> callers use the Python path.
-
-_PREP_SRC = os.path.join(_HERE, "prep.cpp")
-_PREP_LIB = os.path.join(_HERE, "_tmprep.so")
-_prep_mod = None
-_prep_tried = False
-
-
-def _build_ext(src: str, lib: str, opt: str = "-O2",
-               extra_deps: tuple = (), std: str = "c++17") -> Optional[str]:
-    """Build a CPython extension .so from src, cached next to it.
-    extra_deps: sources the src #includes, for staleness checking.
-    std: per-extension — only kvcore needs c++20 (transparent
-    unordered_map lookup); the rest stay buildable on older g++."""
-    try:
-        deps = (src,) + tuple(extra_deps)
-        if os.path.exists(lib) and all(
-                os.path.getmtime(lib) >= os.path.getmtime(d) for d in deps):
-            return lib
-    except OSError:
-        return lib if os.path.exists(lib) else None
-    import sysconfig
-    inc = sysconfig.get_paths().get("include")
-    if not inc or not os.path.exists(os.path.join(inc, "Python.h")):
-        return None
-    tmp = lib + f".{os.getpid()}.tmp"
-    cmd = ["g++", opt, "-shared", "-fPIC", f"-std={std}",
-           f"-I{inc}", src, "-o", tmp]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return None
-    os.replace(tmp, lib)
-    return lib
-
-
-def _load_ext(modname: str, src: str, lib: str, opt: str = "-O2",
-              extra_deps: tuple = (), std: str = "c++17"):
-    """Build (if stale) and import a CPython extension; None on any
-    failure — callers fall back to pure Python."""
-    path = _build_ext(src, lib, opt, extra_deps, std)
-    if path is None:
-        return None
-    try:
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(modname, path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-    except Exception:
-        return None
-    return mod
+    return _CODEC.ensure_loaded()
 
 
 def _prep():
-    global _prep_mod, _prep_tried
-    with _lock:
-        if _prep_tried:
-            return _prep_mod
-        _prep_tried = True
-        if knobs.knob_set("TM_TPU_NO_NATIVE"):
-            return None
-        # prep.cpp #includes hostops.cpp, so it depends on both sources
-        _prep_mod = _load_ext("_tmprep", _PREP_SRC, _PREP_LIB, "-O3",
-                              extra_deps=(_SRC,))
-        return _prep_mod
+    return _PREP.ensure_loaded()
+
+
+def kv():
+    """The _tmkv extension module (native KVStore core), or None."""
+    return _KV.ensure_loaded()
 
 
 def prep_items(items):
@@ -211,28 +241,6 @@ def prep_items(items):
     as_mat = lambda b: np.frombuffer(b, np.uint8).reshape(n, 32)
     pre = np.frombuffer(pre_b, np.uint8).astype(bool)
     return as_mat(pk_b), as_mat(rb_b), as_mat(s_b), as_mat(h_b), pre
-
-
-# -- native KVStore core (kvcore.cpp) ---------------------------------------
-
-_KV_SRC = os.path.join(_HERE, "kvcore.cpp")
-_KV_LIB = os.path.join(_HERE, "_tmkv.so")
-_kv_mod = None
-_kv_tried = False
-
-
-def kv():
-    """The _tmkv extension module (native KVStore core), or None."""
-    global _kv_mod, _kv_tried
-    with _lock:
-        if _kv_tried:
-            return _kv_mod
-        _kv_tried = True
-        if knobs.knob_set("TM_TPU_NO_NATIVE"):
-            return None
-        _kv_mod = _load_ext("_tmkv", _KV_SRC, _KV_LIB, "-O3",
-                            extra_deps=(_SRC,), std="c++20")
-        return _kv_mod
 
 
 def _pack(items: List[bytes]):
@@ -349,9 +357,9 @@ def partset_build(data: bytes, part_size: int):
     part-set constructor's whole skeleton; types/part_set.py slices the
     payloads itself, they are views of bytes it already holds). Empty
     data yields one empty part, matching PartSet.from_data. None when
-    native is unavailable or the cached .so predates the kernel."""
+    native is unavailable."""
     lib = _load()
-    if lib is None or not hasattr(lib, "tm_partset_build"):
+    if lib is None:
         return None
     if part_size <= 0:
         raise ValueError("part_size must be positive")
@@ -462,7 +470,7 @@ def _aead_lib():
     self-check; None otherwise."""
     global _aead_ok
     lib = _load()
-    if lib is None or not hasattr(lib, "tm_aead_seal_burst"):
+    if lib is None:
         return None
     if _aead_ok is None:
         _aead_ok = _aead_self_check(lib)

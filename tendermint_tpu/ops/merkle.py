@@ -80,7 +80,8 @@ def _mesh_root_kernel() -> "tuple":
     else brings jax up (a batched verify) resolves for real. An
     explicit TM_TPU_MESH=N opts in unconditionally and raises, loudly,
     when N exceeds the devices present — same contract as the
-    verifier."""
+    verifier. A backend that fails to come up raises too: it is never
+    remembered as "no mesh"."""
     global _mesh_state
     st = _mesh_state
     if st is not None:
@@ -98,13 +99,8 @@ def _mesh_root_kernel() -> "tuple":
             return _mesh_state
         if spec == "auto" and "jax" not in sys.modules:
             return (None, 1)  # undecided — do not cache
-        try:
-            import jax
-            n_avail = len(jax.devices())
-        except Exception:
-            _mesh_state = (None, 1)  # no usable backend, ever
-            return _mesh_state
-        n = pmesh.resolve_mesh_size(spec, n_avail)
+        import jax
+        n = pmesh.resolve_mesh_size(spec, len(jax.devices()))
         if n < 2:
             _mesh_state = (None, 1)
         else:
@@ -160,13 +156,16 @@ def root_host(items: list[bytes]) -> bytes:
     tree builder (native/hostops.cpp) when available — one C call per
     tree instead of 2n hashlib round trips."""
     n = len(items)
+    from tendermint_tpu import native
     if n >= _MESH_MIN_LEAVES and _mesh_root_kernel()[0] is not None:
-        rows = np.stack(
-            [np.frombuffer(leaf_hash(it), np.uint8) for it in items])
+        # leaves are variable-length: hashed on the host (one native
+        # call when the extension is there), the tree on the mesh
+        leaves = native.sha256_batch([b"\x00" + it for it in items]) \
+            or [leaf_hash(it) for it in items]
+        rows = np.frombuffer(b"".join(leaves), np.uint8).reshape(n, 32)
         out = _mesh_root_from_digest_rows(rows, n)
         if out is not None:
             return out
-    from tendermint_tpu import native
     out = native.merkle_root(items)
     if out is not None:
         if telemetry.enabled():
@@ -304,10 +303,10 @@ def sha256_many_host(payloads: list) -> list[bytes]:
     """One SHA-256 digest per payload, batched — the statetree's
     dirty-node rehash plane (every commit hands its dirty leaf and
     inner payloads here in level-sized waves). Dispatch policy mirrors
-    root_host: the native C++ batch kernel when present; a device batch
-    only when jax is ALREADY imported in this process, the payloads
-    share one static length, and the batch is big enough to amortize a
-    dispatch; else a hashlib loop."""
+    root_host: a device batch only when jax is ALREADY imported in this
+    process, the payloads share one static length, and the batch is big
+    enough to amortize a dispatch (a device failure there raises); else
+    the native C++ batch kernel when present; else a hashlib loop."""
     n = len(payloads)
     if n == 0:
         return []
@@ -316,11 +315,9 @@ def sha256_many_host(payloads: list) -> list[bytes]:
         if "jax" in sys.modules:
             length = len(payloads[0])
             if all(len(p) == length for p in payloads):
-                out = _sha256_many_device(payloads, n, length)
-                if out is not None:
-                    if telemetry.enabled():
-                        _m_sha_batches.labels("device").inc()
-                    return out
+                if telemetry.enabled():
+                    _m_sha_batches.labels("device").inc()
+                return _sha256_many_device(payloads, n, length)
     from tendermint_tpu import native
     out = native.sha256_batch([bytes(p) for p in payloads])
     if out is not None:
@@ -334,19 +331,15 @@ def sha256_many_host(payloads: list) -> list[bytes]:
 
 
 def _sha256_many_device(payloads, n: int, length: int):
-    """uint8[n, L] batch through ops.sha256.hash_fixed, or None when
-    the device path is unusable (import/backend trouble mid-flight must
-    degrade to the host loop, never fail the commit)."""
-    try:
-        import jax.numpy as jnp
-
-        from tendermint_tpu.ops import sha256
-        rows = np.frombuffer(b"".join(payloads), np.uint8).reshape(
-            n, length)
-        out = np.asarray(sha256.hash_fixed(jnp.asarray(rows)))
-        return [out[i].tobytes() for i in range(n)]
-    except Exception:
-        return None
+    """uint8[n, L] batch through the jitted ops.sha256.hash_fixed. Rows
+    are padded to a power of two so the compiled shapes stay bounded
+    (one per bucket and payload length, not one per wave)."""
+    from tendermint_tpu.ops import sha256
+    rows = np.zeros((_padded_size(n), length), np.uint8)
+    rows[:n] = np.frombuffer(b"".join(payloads), np.uint8).reshape(
+        n, length)
+    out = np.asarray(sha256.hash_fixed_jit(rows))
+    return [out[i].tobytes() for i in range(n)]
 
 
 def verify_proof_host(root: bytes, total: int, index: int, item: bytes,

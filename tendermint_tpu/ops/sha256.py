@@ -125,3 +125,6 @@ def hash_fixed(data):
     for i in range(nblocks):
         state = compress(state, words[..., 16 * i : 16 * (i + 1)])
     return words_to_bytes(state)
+
+
+hash_fixed_jit = jax.jit(hash_fixed)

@@ -2,27 +2,16 @@
 
 Design (scaling-book recipe): one mesh axis `batch` for the
 embarrassingly-parallel signature dimension; shard_map partitions the
-batch, each chip verifies its shard on the MXU-friendly int32 ladder,
-verdicts stay sharded (or gather with one small all_gather). The Merkle
-kernel reduces its local subtree per chip, then all_gathers the 32-byte
-subtree roots — bytes over ICI per root are 32·n_devices, negligible.
+batch, each chip verifies its shard with the kernel the unsharded path
+would pick for that many rows (ops/ed25519._dispatch), verdicts stay
+sharded. The Merkle kernel reduces its local subtree per chip, then
+all_gathers the 32-byte subtree roots — bytes over ICI per root are
+32·n_devices, negligible.
 
-The shard_map API has moved across JAX releases; `shard_map_impl()`
-feature-detects once per process and every kernel builder routes
-through it:
-
-  1. `jax.shard_map`                      — the modern top-level API
-     (takes `check_vma`),
-  2. `jax.experimental.shard_map.shard_map` — the long-lived staging
-     home (takes `check_rep`),
-  3. plain `jax.jit` + `NamedSharding` in_shardings/out_shardings —
-     no shard_map at all; GSPMD partitions the same batch axis from
-     the sharding annotations alone.
-
-All three express the identical partitioning, so verdict/root bytes are
-independent of which one the installed JAX provides. A 1-device mesh is
-a degenerate no-op: the builders hand back the plain unsharded jit
-kernels, so callers never branch on mesh size.
+Every sharded program goes through `jax.shard_map` (the installed
+JAX's one entry point). A 1-device mesh is a degenerate no-op: the
+builders hand back the plain unsharded jit kernels, so callers never
+branch on mesh size.
 
 jax itself is imported lazily (inside the builders): this module also
 hosts the mesh spec helpers and the `tm_mesh_*` telemetry, which the
@@ -45,7 +34,6 @@ from tendermint_tpu import telemetry
 
 _mesh_cache: dict = {}
 _kernel_cache: dict = {}
-_impl = None  # ("shard_map" | "jit", wrapped shard_map fn | None)
 
 # One dispatch = one sharded kernel launch from the verifier or the
 # Merkle root plane. Occupancy is real rows / padded rows — with the
@@ -114,35 +102,6 @@ def resolve_mesh_size(spec, n_avail: int) -> int:
     return spec
 
 
-def shard_map_impl():
-    """('shard_map', fn) or ('jit', None), feature-detected once per
-    process: fn is the installed shard_map entry point with its
-    replication-check kwarg (check_vma on modern JAX, check_rep on the
-    jax.experimental staging API) already bound off."""
-    global _impl
-    if _impl is None:
-        import inspect
-
-        import jax
-        fn = getattr(jax, "shard_map", None)
-        if fn is None:
-            try:
-                from jax.experimental.shard_map import shard_map as fn
-            except ImportError:
-                fn = None
-        if fn is None:
-            _impl = ("jit", None)
-        else:
-            kw = {}
-            params = inspect.signature(fn).parameters
-            if "check_vma" in params:
-                kw["check_vma"] = False
-            elif "check_rep" in params:
-                kw["check_rep"] = False
-            _impl = ("shard_map", functools.partial(fn, **kw) if kw else fn)
-    return _impl
-
-
 def make_mesh(n_devices: Optional[int] = None):
     """Mesh over the first n devices, CACHED per device count: every
     Mesh/shard_map/jit closure combination owns its own compile cache,
@@ -157,40 +116,32 @@ def make_mesh(n_devices: Optional[int] = None):
     return _mesh_cache[n]
 
 
+def batch_sharded(fn, mesh):
+    """jit(shard_map(fn)): the leading axis of every argument and every
+    result split over mesh's `batch` axis, each device running fn on
+    its shard. Cached per (fn, mesh) — every jit closure owns its own
+    compile cache (compiles are minutes on small CI hosts). A 1-device
+    mesh hands fn back."""
+    key = (fn, mesh)
+    if key not in _kernel_cache:
+        if mesh.devices.size == 1:
+            _kernel_cache[key] = fn
+        else:
+            import jax
+            from jax.sharding import PartitionSpec as P
+            _kernel_cache[key] = jax.jit(jax.shard_map(
+                fn, mesh=mesh, in_specs=P("batch"), out_specs=P("batch"),
+                check_vma=False))
+    return _kernel_cache[key]
+
+
 def sharded_verify_kernel(mesh):
-    """Returns verify(pubkeys u8[N,32], r u8[N,32], s_bits i32[N,256],
-    h_bits i32[N,256]) -> bool[N], with N sharded over mesh's `batch` axis.
-    Drop-in `kernel=` for ops.ed25519.verify_batch / BatchVerifier.
-    Cached per mesh (compiles are minutes on 1-core CI hosts). A
-    1-device mesh degenerates to the plain unsharded jit kernel."""
-    # tmlint: allow(taint): id() is a per-process compile-cache key; the cached kernel's output is mesh-value-determined, bit-equal to host
-    key = ("verify", id(mesh))
-    if key in _kernel_cache:
-        return _kernel_cache[key]
-
-    from tendermint_tpu.ops.ed25519 import verify_kernel, verify_kernel_jit
-
-    if mesh.devices.size == 1:
-        _kernel_cache[key] = verify_kernel_jit
-        return verify_kernel_jit
-
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    api, smap = shard_map_impl()
-    if api == "shard_map":
-        _local = smap(verify_kernel, mesh=mesh,
-                      in_specs=(P("batch"), P("batch"), P("batch"),
-                                P("batch")),
-                      out_specs=P("batch"))
-        _verify = jax.jit(_local)
-    else:
-        sh = NamedSharding(mesh, P("batch"))
-        _verify = jax.jit(verify_kernel, in_shardings=(sh, sh, sh, sh),
-                          out_shardings=sh)
-
-    _kernel_cache[key] = _verify
-    return _verify
+    """verify(pubkeys u8[N,32], r u8[N,32], s u8[N,32], h u8[N,32]) ->
+    bool[N]: the jnp ladder from packed scalars with N sharded over
+    mesh's `batch` axis — the program BatchVerifier's mesh path runs
+    wherever the Pallas kernel does not apply."""
+    from tendermint_tpu.ops.ed25519 import _verify_from_bytes_jnp
+    return batch_sharded(_verify_from_bytes_jnp, mesh)
 
 
 def sharded_merkle_root(mesh):
@@ -199,8 +150,7 @@ def sharded_merkle_root(mesh):
     all_gathered and finished identically on every chip. Cached per
     mesh, like sharded_verify_kernel; a 1-device mesh degenerates to
     the plain device root."""
-    # tmlint: allow(taint): id() is a per-process compile-cache key; the cached root kernel is bit-equality-tested against the host path
-    key = ("merkle", id(mesh))
+    key = ("merkle", mesh)
     if key in _kernel_cache:
         return _kernel_cache[key]
 
@@ -212,41 +162,30 @@ def sharded_merkle_root(mesh):
 
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    api, smap = shard_map_impl()
-    if api == "shard_map":
-        def _subtree_local(digests):
-            level = digests
-            while level.shape[-2] > 1:
-                level = merkle._level_up(level)
-            # [1, 32] per chip -> all chips see all subtree roots
-            # [n_dev, 32]
-            roots = jax.lax.all_gather(level[0], "batch")
-            while roots.shape[-2] > 1:
-                roots = merkle._level_up(roots)
-            return roots[0]
+    def _subtree_local(digests):
+        level = digests
+        while level.shape[-2] > 1:
+            level = merkle._level_up(level)
+        # [1, 32] per chip -> all chips see all subtree roots [n_dev, 32]
+        roots = jax.lax.all_gather(level[0], "batch")
+        while roots.shape[-2] > 1:
+            roots = merkle._level_up(roots)
+        return roots[0]
 
-        _subtree = smap(_subtree_local, mesh=mesh,
-                        in_specs=P("batch"), out_specs=P())
+    _subtree = jax.shard_map(_subtree_local, mesh=mesh,
+                             in_specs=P("batch"), out_specs=P(),
+                             check_vma=False)
 
-        @functools.partial(jax.jit, static_argnames=("n_leaves",))
-        def _root(digests, n_leaves: int):
-            tree_root = _subtree(digests)
-            header = np.concatenate([
-                np.array([0x02], np.uint8),
-                np.frombuffer(struct.pack("<Q", n_leaves), np.uint8)])
-            return sha256.hash_fixed(
-                jnp.concatenate([jnp.asarray(header), tree_root], axis=-1))
-    else:
-        # GSPMD partitions the level-by-level reduction from the input
-        # sharding alone; the upper levels reshard automatically once
-        # rows < n_devices. Bit-identical output (SHA-256 is SHA-256).
-        sh = NamedSharding(mesh, P("batch"))
-        rep = NamedSharding(mesh, P())
-        _root = jax.jit(merkle._root_from_digests,
-                        static_argnames=("n_leaves",),
-                        in_shardings=(sh,), out_shardings=rep)
+    @functools.partial(jax.jit, static_argnames=("n_leaves",))
+    def _root(digests, n_leaves: int):
+        tree_root = _subtree(digests)
+        header = np.concatenate([
+            np.array([0x02], np.uint8),
+            np.frombuffer(struct.pack("<Q", n_leaves), np.uint8)])
+        return sha256.hash_fixed(
+            jnp.concatenate([jnp.asarray(header), tree_root], axis=-1))
 
     _kernel_cache[key] = _root
     return _root
@@ -256,13 +195,13 @@ def verify_step(mesh):
     """The flagship 'full step' over the mesh: batched commit verification
     + Merkle root of the same batch's messages-digests — i.e. everything a
     fast-sync block check does on-device, sharded. Returns
-    step(pk, rb, sbits, hbits, leaf_digests, n_leaves) ->
+    step(pk, rb, s_bytes, h_bytes, leaf_digests, n_leaves) ->
     (ok bool[N] sharded, root u8[32] replicated)."""
 
     verify = sharded_verify_kernel(mesh)
     root = sharded_merkle_root(mesh)
 
-    def step(pk, rb, sbits, hbits, leaf_digests, n_leaves: int):
-        return verify(pk, rb, sbits, hbits), root(leaf_digests, n_leaves)
+    def step(pk, rb, s_bytes, h_bytes, leaf_digests, n_leaves: int):
+        return verify(pk, rb, s_bytes, h_bytes), root(leaf_digests, n_leaves)
 
     return step

@@ -18,7 +18,7 @@ caused by the harness itself falling behind — counts against the
 server-visible number. Sweeping the offered rate exposes the knee:
 the last rate the system absorbs before goodput detaches from load.
 
-Error taxonomy (matched against the PR 12 admission plane):
+Error classes (matched against the PR 12 admission plane):
 HTTP 503 at the WS handshake = connection shed (conn cap),
 -32005 = rate-limited, -32000 = overloaded/shed at dispatch.
 """
