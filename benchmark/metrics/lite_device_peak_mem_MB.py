@@ -1,0 +1,6 @@
+"""`device_peak_mem_MB` in the lite cell: an entry of its own because
+that cell's end-to-end metric is `headers_per_s`."""
+
+from benchmark.metrics.device_peak_mem_MB import LAYER, read  # noqa: F401
+
+MOVES = "headers_per_s"

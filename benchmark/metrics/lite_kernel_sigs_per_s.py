@@ -1,0 +1,6 @@
+"""`kernel_sigs_per_s` in the lite cell: an entry of its own because that
+cell's end-to-end metric is `headers_per_s`."""
+
+from benchmark.metrics.kernel_sigs_per_s import LAYER, read  # noqa: F401
+
+MOVES = "headers_per_s"

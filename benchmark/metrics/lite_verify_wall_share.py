@@ -1,0 +1,6 @@
+"""`verify_wall_share` in the lite cell: an entry of its own because that
+cell's end-to-end metric is `headers_per_s`."""
+
+from benchmark.metrics.verify_wall_share import LAYER, read  # noqa: F401
+
+MOVES = "headers_per_s"
