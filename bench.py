@@ -1392,8 +1392,7 @@ def bench_trace_json(path: str = "BENCH_trace.json",
     consensus_events = [
         ev for ev in report["perfetto"]["traceEvents"]
         if ev.get("ph") == "M" or (
-            ev["name"] not in ("p2p.recv", "mempool.recv",
-                               "verify.dispatch")
+            ev["name"] not in ("p2p.recv", "mempool.recv")
             and ev.get("args", {}).get("height") in hset)]
 
     span_counts: dict = {}

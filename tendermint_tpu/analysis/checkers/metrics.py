@@ -67,6 +67,7 @@ INSTRUMENTED_MODULES = [
     "tendermint_tpu.serving.edge",       # tm_edge_* certified read tier
     "tendermint_tpu.serving.loadgen",    # tm_load_* open-loop harness
     "tendermint_tpu.serving.deploy",     # tm_deploy_* process driver
+    "tendermint_tpu.ops.ed25519",        # tm_verifier_h2d_bytes_total
 ]
 
 # Causal span names follow the same closed-catalog discipline as metric
@@ -78,6 +79,14 @@ INSTRUMENTED_MODULES = [
 _SPAN_NAME_RE = re.compile(
     r'(?:causal\.(?:span|point|record)|_cspan|_cpoint)\(\s*'
     r'[\'"]([a-z0-9_.]+)[\'"]')
+
+# The tracer's spans (telemetry/trace.py) are a closed catalogue too:
+# a literal name at a trace.span/complete/instant call site (also
+# reached as telemetry.* or TRACER.*) must be in trace.SPANS, or the
+# per-layer metrics that read the ring by name never see it.
+_TRACER_NAME_RE = re.compile(
+    r'(?:trace|telemetry|TRACER)\.(?:span|complete|instant)\(\s*'
+    r'[\'"]([A-Za-z0-9_.:]+)[\'"]')
 
 _LINE_RE = re.compile(
     r'^[a-z_][a-z0-9_]*(\{[a-z0-9_]+="(?:[^"\\]|\\.)*"'
@@ -141,10 +150,14 @@ def run() -> List[Finding]:
 
 
 def span_findings(root: str = "") -> List[Finding]:
-    """Lint causal span-name call sites against SPAN_CATALOG. `root`
-    defaults to the installed tendermint_tpu package tree (tests point
-    it at fixture dirs)."""
+    """Lint span-name call sites against their catalogues: causal
+    spans against causal.SPAN_CATALOG, the tracer's against
+    trace.SPANS. `root` defaults to the installed tendermint_tpu
+    package tree (tests point it at fixture dirs)."""
     from tendermint_tpu.telemetry.causal import SPAN_CATALOG
+    from tendermint_tpu.telemetry.trace import SPANS
+    rules = ((_SPAN_NAME_RE, SPAN_CATALOG, "telemetry.causal.SPAN_CATALOG"),
+             (_TRACER_NAME_RE, SPANS, "telemetry.trace.SPANS"))
     if not root:
         import tendermint_tpu
         pkg = os.path.dirname(os.path.abspath(tendermint_tpu.__file__))
@@ -165,12 +178,13 @@ def span_findings(root: str = "") -> List[Finding]:
             except OSError:
                 continue
             for i, line in enumerate(lines, 1):
-                for m in _SPAN_NAME_RE.finditer(line):
-                    if m.group(1) not in SPAN_CATALOG:
-                        findings.append(Finding(
-                            CHECKER_ID, path, i,
-                            f"span name {m.group(1)!r} not declared in "
-                            f"telemetry.causal.SPAN_CATALOG"))
+                for name_re, catalog, where in rules:
+                    for m in name_re.finditer(line):
+                        if m.group(1) not in catalog:
+                            findings.append(Finding(
+                                CHECKER_ID, path, i,
+                                f"span name {m.group(1)!r} not declared "
+                                f"in {where}"))
     return findings
 
 

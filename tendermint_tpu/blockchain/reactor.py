@@ -20,10 +20,12 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
+from tendermint_tpu import telemetry
 from tendermint_tpu.p2p.base_reactor import Reactor
 from tendermint_tpu.p2p.conn import ChannelDescriptor
 from tendermint_tpu.blockchain.pool import BlockPool
 from tendermint_tpu.state.execution import ApplyBlockError
+from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
 from tendermint_tpu.types.block import Block, BlockID
 
@@ -173,12 +175,20 @@ class BlockchainReactor(Reactor):
     # -------------------------------------------------------------- receive
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
+        t_decode = time.perf_counter() if telemetry.enabled() else 0.0
         msg = encoding.cloads(msg_bytes)
         t = msg.get("type")
         if t == "block_request":
             self._respond_to_block_request(peer, msg["height"])
         elif t == "block_response":
             block = Block.from_obj(msg["block"])
+            # where a node decodes the blocks it syncs: the message's
+            # parse and the block's, once it proves to be a block
+            if t_decode:
+                trace.complete("wire.decode_block", t_decode,
+                               time.perf_counter(),
+                               req=block.header.height,
+                               bytes=len(msg_bytes))
             if not self.pool.add_block(peer.id, block, len(msg_bytes)):
                 pass  # unsolicited; ignore (reference ignores too)
         elif t == "no_block_response":
@@ -297,9 +307,11 @@ class BlockchainReactor(Reactor):
         """(part_set, block_id) — built ONCE per block; part-set
         construction (serialize + split + merkle) is the CPU cost of the
         sync hot loop."""
-        parts = block.make_part_set(
-            self.state.consensus_params.block_gossip.block_part_size_bytes)
-        return parts, BlockID(block.hash(), parts.header())
+        with trace.span("sync.parts"):
+            parts = block.make_part_set(
+                self.state.consensus_params.block_gossip
+                .block_part_size_bytes)
+            return parts, BlockID(block.hash(), parts.header())
 
     def _verifier(self):
         verifier = self.block_exec.verifier
@@ -382,7 +394,8 @@ class BlockchainReactor(Reactor):
             # seen-commit = the commit FOR this block (= next block's
             # LastCommit), matching the reference's SaveBlock(first,
             # firstParts, second.LastCommit)
-            self.block_store.save_block(block, parts, commit)
+            with trace.span("sync.store"):
+                self.block_store.save_block(block, parts, commit)
             # trust_last_commit: this block's own LastCommit was already
             # batch-verified when its predecessor went through this loop.
             # (apply_block never mutates its input state — no copy.)
@@ -412,15 +425,14 @@ class BlockchainReactor(Reactor):
         """
         pending = self._pending_window
         skip = 0 if pending is None else max(0, len(pending[0]))
-        collected = self._collect_window(skip)
+        with trace.span("sync.collect", req=self.pool.height + skip):
+            collected = self._collect_window(skip)
 
         if collected is None:
             # nothing new to dispatch: drain the in-flight window if any
             self._pending_window = None
             if pending is not None:
-                per_block, fut, vs_hash, psz = pending
-                return self._apply_window(per_block, fut.result(), vs_hash,
-                                          psz) > 0
+                return self._settle_window(pending) > 0
             return False
 
         per_block, all_items, vs_hash, psz = collected
@@ -440,17 +452,25 @@ class BlockchainReactor(Reactor):
         self._pending_window = (per_block, fut, vs_hash, psz)
         progress = False
         if pending is not None:
-            prev_blocks, prev_fut, prev_hash, prev_psz = pending
-            applied = self._apply_window(prev_blocks, prev_fut.result(),
-                                         prev_hash, prev_psz)
+            applied = self._settle_window(pending)
             progress = applied > 0
-            if applied < len(prev_blocks):
+            if applied < len(pending[0]):
                 # the window was cut short (bad block -> punish + redo):
                 # the in-flight successor sits past a gap of re-requested
                 # heights and may hold blocks from the punished peer —
                 # drop it and re-collect once the pool recovers
                 self._pending_window = None
         return progress or self._pending_window is not None
+
+    def _settle_window(self, pending) -> int:
+        """Wait for a dispatched window's verdicts, then store + apply
+        it; returns how many blocks were applied."""
+        per_block, fut, vs_hash, psz = pending
+        req = per_block[0][0].header.height     # the window's first
+        with trace.span("sync.wait", req=req):
+            ok = fut.result()
+        with trace.span("sync.apply", req=req):
+            return self._apply_window(per_block, ok, vs_hash, psz)
 
     def _punish_bad_window(self, height: int) -> None:
         for peer_id in self.pool.redo_request(height):

@@ -133,6 +133,10 @@ class ConsensusState:
         # _new_step closes as one Chrome-trace complete event
         self._round_t0 = 0.0
         self._step_open = None  # (step_name, height, round, t0)
+        # which node's step an event of the shared ring belongs to
+        # (several nodes can live in one interpreter)
+        self._trace_node = (priv_validator.address.hex()[:8]
+                            if priv_validator is not None else "")
 
         self.ticker = ticker_factory(self._on_timeout_fire)
 
@@ -349,7 +353,8 @@ class ConsensusState:
             if self._step_open is not None:
                 name, h, r, t0 = self._step_open
                 telemetry.TRACER.complete(
-                    f"cs:{name}", t0, now, height=h, round=r)
+                    f"cs:{name}", t0, now, req=h, height=h, round=r,
+                    node=self._trace_node)
             rs = self.rs
             self._step_open = (rs.step.name, rs.height, rs.round, now)
             _m_steps.labels(rs.step.name).inc()
@@ -381,6 +386,12 @@ class ConsensusState:
         if ti.height != rs.height or ti.round < rs.round or \
                 (ti.round == rs.round and ti.step < rs.step):
             return  # stale tock
+        if ti.step in (Step.PROPOSE, Step.PREVOTE_WAIT, Step.PRECOMMIT_WAIT) \
+                and telemetry.enabled() and not self.replay_mode:
+            # a timeout that moves the state: the height pays for it
+            telemetry.instant("cs:timeout", req=ti.height,
+                              step=ti.step.name, round=ti.round,
+                              node=self._trace_node)
         if ti.step == Step.NEW_HEIGHT:
             self._enter_new_round(ti.height, 0)
         elif ti.step == Step.NEW_ROUND:
@@ -902,9 +913,10 @@ class ConsensusState:
         if telemetry.enabled() and not self.replay_mode:
             _m_commits.inc()
             _m_block_txs.observe(len(block.data.txs))
-            telemetry.instant("cs:finalize_commit", height=height,
-                              round=rs.commit_round,
-                              txs=len(block.data.txs))
+            telemetry.instant("cs:finalize_commit", req=height,
+                              height=height, round=rs.commit_round,
+                              txs=len(block.data.txs),
+                              node=self._trace_node)
         self._cpoint("commit", height, rs.commit_round,
                      txs=len(block.data.txs))
         self._point_transition_digest(height, rs.commit_round)
@@ -985,9 +997,10 @@ class ConsensusState:
         if telemetry.enabled() and not self.replay_mode:
             _m_commits.inc()
             _m_block_txs.observe(len(block.data.txs))
-            telemetry.instant("cs:finalize_commit", height=height,
-                              round=rs.commit_round,
-                              txs=len(block.data.txs))
+            telemetry.instant("cs:finalize_commit", req=height,
+                              height=height, round=rs.commit_round,
+                              txs=len(block.data.txs),
+                              node=self._trace_node)
             pipeline.observe_overlap(self._overlap_s,
                                      self._overlap_s + self._serial_s)
         self._cpoint("commit", height, rs.commit_round,

@@ -289,6 +289,7 @@ def certify_chain(chain_id: str, fcs: List[FullCommit],
     from concurrent.futures import ThreadPoolExecutor
 
     from tendermint_tpu.models.verifier import default_verifier
+    from tendermint_tpu.telemetry import trace
     verifier = verifier or default_verifier()
     if not fcs:
         return
@@ -326,17 +327,25 @@ def certify_chain(chain_id: str, fcs: List[FullCommit],
             except ValueError as e:
                 raise CertificationError(f"height {height}: {e}") from e
 
+    def settle(spans, fut, req):
+        with trace.span("lite.wait", req=req):
+            ok = fut.result()
+        with trace.span("lite.check", req=req):
+            check(spans, ok)
+
     pool = ThreadPoolExecutor(max_workers=1,
                               thread_name_prefix="tm-lite-resolve")
     try:
-        pending = None  # (spans, future)
+        pending = None  # (spans, future, the window's first height)
         for lo in range(0, len(fcs), window):
-            items_w, spans = collect(fcs[lo:lo + window])
+            req = fcs[lo].height
+            with trace.span("lite.collect", req=req):
+                items_w, spans = collect(fcs[lo:lo + window])
             fut = pool.submit(verifier.verify_async(items_w))
             if pending is not None:
-                check(pending[0], pending[1].result())
-            pending = (spans, fut)
+                settle(*pending)
+            pending = (spans, fut, req)
         if pending is not None:
-            check(pending[0], pending[1].result())
+            settle(*pending)
     finally:
         pool.shutdown(wait=False)

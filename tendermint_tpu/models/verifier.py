@@ -37,6 +37,7 @@ process-wide in ops/ed25519.predecomp_stats().
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Sequence
@@ -44,6 +45,7 @@ from typing import Sequence
 import numpy as np
 
 from tendermint_tpu import telemetry
+from tendermint_tpu.telemetry import trace
 # import-light: parallel.mesh only pulls jax inside its kernel builders,
 # so the spec helpers + tm_mesh_* instruments cost nothing at import
 from tendermint_tpu.parallel import mesh as _pmesh
@@ -90,6 +92,9 @@ _m_predecomp_evictions = telemetry.counter(
 _m_predecomp_keys = telemetry.gauge(
     "verifier_predecomp_keys",
     "Pubkey rows currently resident in the predecompression LRU")
+# the verifier's request id in the span recorder: every span of one
+# dispatch (telemetry/trace.py) carries its number as `req`
+_dispatch_seq = itertools.count(1)
 
 # Per-dispatch chunk. The fused pallas kernel tiles batches internally
 # (512/VMEM tile), so big dispatches amortize launch overhead. 8192
@@ -269,12 +274,15 @@ class BatchVerifier:
         if n == 0:
             out0 = np.zeros(0, np.bool_)
             return lambda: out0
+        req = next(_dispatch_seq) if telemetry.enabled() else None
+        with trace.span("verify.dispatch", req=req, n=n,
+                        backend=self.backend) as span:
+            return self._dispatch_direct(items, n, span)
+
+    def _dispatch_direct(self, items, n: int, span):
+        """_verify_async_direct's body, inside its `verify.dispatch`
+        span; the resolver it returns names that span as its cause."""
         t_dispatch = time.perf_counter()
-        # causal timeline marker (no height at this layer — the cluster
-        # merge shows WHEN verify work ran relative to consensus stages)
-        from tendermint_tpu.telemetry import causal
-        if causal.enabled():
-            causal.point("verify.dispatch", -1, n=n, backend=self.backend)
         _m_batch_size.observe(n)
         use_jax = self.backend == "jax" or (
             self.backend == "auto" and n > self.auto_threshold)
@@ -295,7 +303,8 @@ class BatchVerifier:
         # returns None for batches that need the general path below
         # (secp256k1 keys, non-bytes members, native unavailable)
         from tendermint_tpu import native
-        prep = native.prep_items(items)
+        with trace.span("verify.prep", n=n):
+            prep = native.prep_items(items)
         if prep is not None:
             from tendermint_tpu.ops import ed25519
             if not self._mesh_resolved:
@@ -304,11 +313,14 @@ class BatchVerifier:
             pk, rb, sb, hb, pre = prep
             pending = []
             occ = telemetry.enabled()
+            t_enqueued = 0.0
             for lo in range(0, n, BATCH_CHUNK):
                 hi = min(lo + BATCH_CHUNK, n)
                 res = ed25519.verify_prepared_async(
                     pk[lo:hi], rb[lo:hi], sb[lo:hi], hb[lo:hi],
                     mesh=self._mesh)
+                if occ and not t_enqueued:
+                    t_enqueued = time.perf_counter()
                 pending.append((lo, hi, res, pre[lo:hi]))
                 if occ:
                     b = ed25519._bucket(
@@ -316,7 +328,8 @@ class BatchVerifier:
                     _m_occupancy.observe((hi - lo) / b)
                     if self.mesh_devices >= 2:
                         _pmesh.record_dispatch("verify", hi - lo, b)
-            return self._make_resolver(n, pending, t_dispatch=t_dispatch)
+            return self._make_resolver(n, pending, t_dispatch, span,
+                                       t_enqueued)
         # mixed-key routing: 33-byte compressed-SEC1 pubkeys are
         # secp256k1 — verified on host (off the TPU hot path by design,
         # types/keys.py); everything else goes to the ed25519 device
@@ -360,10 +373,13 @@ class BatchVerifier:
         sigs = [it[2] for it in items]
         pending = []
         occ = telemetry.enabled()
+        t_enqueued = 0.0
         for lo in range(0, n, BATCH_CHUNK):
             hi = min(lo + BATCH_CHUNK, n)
             res, pre = ed25519.verify_batch_async(
                 pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi], mesh=self._mesh)
+            if occ and not t_enqueued:
+                t_enqueued = time.perf_counter()
             pending.append((lo, hi, res, pre))
             if occ:
                 b = ed25519._bucket(
@@ -371,7 +387,7 @@ class BatchVerifier:
                 _m_occupancy.observe((hi - lo) / b)
                 if self.mesh_devices >= 2:
                     _pmesh.record_dispatch("verify", hi - lo, b)
-        return self._make_resolver(n, pending, t_dispatch=t_dispatch)
+        return self._make_resolver(n, pending, t_dispatch, span, t_enqueued)
 
     def _record_jax_dispatch(self, n: int) -> None:
         """Stats + calls/sigs samples for one device dispatch (chunk
@@ -385,17 +401,30 @@ class BatchVerifier:
         _m_sigs.labels("jax").inc(n)
 
     @staticmethod
-    def _make_resolver(n: int, pending, t_dispatch: float = 0.0):
+    def _make_resolver(n: int, pending, t_dispatch: float, span,
+                       t_enqueued: float):
+        """`span` is the dispatch's `verify.dispatch`: the fetch, on
+        whatever thread resolves, names it as its cause and shares its
+        request id; `t_enqueued` is when the first chunk was enqueued,
+        from where the device has had work of this dispatch."""
+        cause, req = span.id, span.req
+
         def resolve() -> np.ndarray:
             out = np.zeros(n, np.bool_)
-            if len(pending) > 1:
-                arrs = list(_fetch_pool_get().map(
-                    lambda p: np.asarray(p[2]), pending))
-            else:
-                arrs = [np.asarray(pending[0][2])]
+            with trace.span("verify.fetch", req=req, cause=cause,
+                            chunks=len(pending)):
+                if len(pending) > 1:
+                    arrs = list(_fetch_pool_get().map(
+                        lambda p: np.asarray(p[2]), pending))
+                else:
+                    arrs = [np.asarray(pending[0][2])]
+            t_fetched = time.perf_counter() if telemetry.enabled() else 0.0
+            if t_fetched and t_enqueued:
+                trace.complete("verify.inflight", t_enqueued, t_fetched,
+                               req=req, cause=cause)
             for (lo, hi, _res, pre), arr in zip(pending, arrs):
                 out[lo:hi] = arr[:hi - lo] & pre
-            if t_dispatch and telemetry.enabled():
+            if t_fetched:
                 _m_dispatch.labels("jax").observe(
                     time.perf_counter() - t_dispatch)
             return out

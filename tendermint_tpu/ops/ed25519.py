@@ -37,10 +37,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tendermint_tpu import telemetry
 from tendermint_tpu.ops import curve
 from tendermint_tpu.ops import field as fe
+from tendermint_tpu.telemetry import trace
 
 L_ORDER = (1 << 252) + 27742317777372353535851937790883648493
+
+# counted where the bytes leave the host (_dispatch), beside the
+# `verify.enqueue` span
+_m_h2d_bytes = telemetry.counter(
+    "verifier_h2d_bytes_total",
+    "Bytes of host arrays handed to the device by verify dispatches")
 
 
 # ---------------------------------------------------------------------------
@@ -176,8 +184,10 @@ def _verify_from_bytes_pallas(pk, rb, s_bytes, h_bytes):
 
 
 def _dispatch(variant: str, mesh, *args):
-    """Enqueue one padded batch on the kernel its shape selects and
-    count it. variant: 'full' | 'pre' | 'decompress'. The fused Pallas
+    """Hand one padded batch (host arrays) to the device and enqueue
+    it on the kernel its shape selects, counted; the transfers and the
+    jitted call are the `verify.enqueue` span.
+    variant: 'full' | 'pre' | 'decompress'. The fused Pallas
     kernel takes every batch whose per-device rows fill its 512 tile
     on a TPU; everything else (CPU backends, interactive sizes where
     kernel choice barely matters) takes the jnp ladder. With a mesh
@@ -200,9 +210,14 @@ def _dispatch(variant: str, mesh, *args):
         shape = f"{name}[{rows}/{ndev}]"
         if name.startswith("jnp_"):
             name = "mesh_jnp"
-    t0 = time.perf_counter()
-    out = fn(*args)
-    _note_kernel(name, shape, time.perf_counter() - t0)
+    with trace.span("verify.enqueue", kernel=name, rows=rows):
+        if telemetry.enabled():
+            _m_h2d_bytes.inc(sum(
+                a.nbytes for a in args if isinstance(a, np.ndarray)))
+        on_device = [jnp.asarray(a) for a in args]
+        t0 = time.perf_counter()
+        out = fn(*on_device)
+        _note_kernel(name, shape, time.perf_counter() - t0)
     return out
 
 
@@ -261,9 +276,9 @@ _predecomp_stats = {"hit": 0, "fill": 0, "full": 0, "evict": 0,
 
 def _predecomp_note(outcome: str, n: int = 1) -> None:
     """Mirror a cache outcome into tm_verifier_predecomp_* telemetry
-    (registered by models/verifier so lint stays import-light; lazy
-    import — models.verifier is loaded in any process that dispatches
-    batches here)."""
+    (registered by models/verifier beside the other tm_verifier_*
+    families; lazy import — models.verifier is loaded in any process
+    that dispatches batches here)."""
     _predecomp_stats[outcome] += n
     from tendermint_tpu.models import verifier
     if outcome == "evict":
@@ -334,7 +349,17 @@ def _verify_pre_pallas(xnb, yb, ok, rb, s_bytes, h_bytes):
 
 def _verify_cached_predecomp(pk_np, rb, s_bytes, h_bytes, mesh=None):
     """Returns verdicts via the predecompressed path, or None when this
-    batch's pubkeys are mostly fresh (a first-sighting batch must not
+    batch takes the fused full kernel (_predecomp_rows says which)."""
+    with trace.span("verify.predecomp", rows=pk_np.shape[0]):
+        rows = _predecomp_rows(pk_np, mesh)
+    if rows is None:
+        return None
+    return _dispatch("pre", mesh, *rows, rb, s_bytes, h_bytes)
+
+
+def _predecomp_rows(pk_np, mesh):
+    """The batch's cached rows (xneg bytes, y bytes, ok), or None when
+    its pubkeys are mostly fresh (a first-sighting batch must not
     pay the extra decompress dispatch — it takes the fused full kernel
     while its keys are marked seen; any later batch made of seen keys
     decompresses ONCE and fills per-key rows)."""
@@ -364,8 +389,7 @@ def _verify_cached_predecomp(pk_np, rb, s_bytes, h_bytes, mesh=None):
         # repeat traffic over uncached keys: decompress the whole batch
         # once (outside the lock — device dispatch), store per-key rows.
         # A concurrent duplicate fill is harmless: same key, same bytes.
-        xnb_d, yb_d, ok_d = _dispatch("decompress", mesh,
-                                      jnp.asarray(pk_np))
+        xnb_d, yb_d, ok_d = _dispatch("decompress", mesh, pk_np)
         xnb_h = np.asarray(xnb_d)
         yb_h = np.asarray(yb_d)
         ok_h = np.asarray(ok_d)
@@ -384,9 +408,7 @@ def _verify_cached_predecomp(pk_np, rb, s_bytes, h_bytes, mesh=None):
         xnb_h = np.stack([r[0] for r in rows])
         yb_h = np.stack([r[1] for r in rows])
         ok_h = np.array([r[2] for r in rows], np.bool_)
-    return _dispatch("pre", mesh, jnp.asarray(xnb_h), jnp.asarray(yb_h),
-                     jnp.asarray(ok_h), jnp.asarray(rb),
-                     jnp.asarray(s_bytes), jnp.asarray(h_bytes))
+    return xnb_h, yb_h, ok_h
 
 
 # ---------------------------------------------------------------------------
@@ -536,7 +558,9 @@ def verify_batch_async(pubkeys, msgs, sigs, mesh=None):
     (device_result, precheck bool[N]). jax dispatch is asynchronous, so
     a caller with several chunks can enqueue them all and let device
     compute overlap host prep + transfers."""
-    pk, rb, s_bytes, h_bytes, pre = prepare_batch_bytes(pubkeys, msgs, sigs)
+    with trace.span("verify.prep", n=len(pubkeys)):
+        pk, rb, s_bytes, h_bytes, pre = prepare_batch_bytes(
+            pubkeys, msgs, sigs)
     res = verify_prepared_async(pk, rb, s_bytes, h_bytes, mesh=mesh)
     return res, pre
 
@@ -566,8 +590,7 @@ def verify_prepared_async(pk, rb, s_bytes, h_bytes, mesh=None):
         res = _verify_cached_predecomp(pk_p, rb_p, sb_p, hb_p, mesh)
         if res is not None:
             return res
-    return _dispatch("full", mesh, jnp.asarray(pk_p), jnp.asarray(rb_p),
-                     jnp.asarray(sb_p), jnp.asarray(hb_p))
+    return _dispatch("full", mesh, pk_p, rb_p, sb_p, hb_p)
 
 
 def verify_batch(pubkeys, msgs, sigs, mesh=None) -> np.ndarray:

@@ -172,23 +172,26 @@ class BlockExecutor:
         pipelined finalize, which validates once for the consensus
         failure classification and must not pay the commit-signature
         batch twice per height."""
-        from tendermint_tpu.telemetry import causal
+        from tendermint_tpu.telemetry import causal, trace
         from tendermint_tpu.utils import fail
-        with causal.span("apply", block.header.height,
-                         txs=len(block.data.txs)):
+        height = block.header.height
+        with causal.span("apply", height, txs=len(block.data.txs)):
             if not pre_validated:
-                self.validate_block(state, block,
-                                    trust_last_commit=trust_last_commit)
-            responses = exec_block_on_app(self.app_conn, block,
-                                          state.validators)
+                with trace.span("apply.validate", req=height):
+                    self.validate_block(
+                        state, block, trust_last_commit=trust_last_commit)
+            with trace.span("apply.exec", req=height):
+                responses = exec_block_on_app(self.app_conn, block,
+                                              state.validators)
             fail.fail_point("execution.after_exec_block")
             state_store = self.state_store
             if group is not None and state_store is not None:
                 from tendermint_tpu.storage.state_store import StateStore
                 state_store = StateStore(group.staged(self.state_store.db))
             if state_store is not None:
-                state_store.save_abci_responses(
-                    block.header.height, responses.to_obj())
+                with trace.span("apply.save", req=height):
+                    state_store.save_abci_responses(
+                        height, responses.to_obj())
             fail.fail_point("execution.after_save_abci_responses")
             new_state = update_state(state, block_id, block, responses)
 
@@ -197,8 +200,9 @@ class BlockExecutor:
             # between app Commit and mempool.update.
             self.mempool.lock()
             try:
-                app_hash = self.app_conn.commit()
-                self.mempool.update(block.header.height, block.data.txs)
+                with trace.span("apply.commit", req=height):
+                    app_hash = self.app_conn.commit()
+                    self.mempool.update(height, block.data.txs)
             finally:
                 self.mempool.unlock()
 
@@ -207,7 +211,8 @@ class BlockExecutor:
             if self.divergence is not None:
                 self.divergence.record(block, responses, new_state)
             if state_store is not None:
-                state_store.save(new_state)
+                with trace.span("apply.save", req=height):
+                    state_store.save(new_state)
             fail.fail_point("execution.after_save_state")
             self.evidence_pool.update(block, new_state)
             if self.event_bus is not None:

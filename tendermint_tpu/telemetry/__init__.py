@@ -11,7 +11,7 @@ Public surface:
                                 buckets=telemetry.POW2_BUCKETS)
     _size.observe(n)
 
-    with telemetry.span("verify", batch=n): ...
+    with telemetry.span("verify.prep", n=n): ...   # a name of trace.SPANS
     text = telemetry.expose()          # Prometheus text format 0.0.4
 
 Conventions (enforced by scripts/check_metrics.py):
@@ -45,8 +45,10 @@ from tendermint_tpu.telemetry.registry import (  # noqa: F401
     set_enabled,
 )
 from tendermint_tpu.telemetry.trace import (  # noqa: F401
+    SPANS,
     TRACER,
     Tracer,
+    complete,
     dump_trace,
     instant,
     span,

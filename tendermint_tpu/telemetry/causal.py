@@ -57,7 +57,6 @@ from tendermint_tpu.utils import knobs
 #   block.full       part set complete, block decodable
 #   quorum.prevote   +2/3 prevotes for a block observed
 #   quorum.precommit +2/3 precommits observed (enter commit)
-#   verify.dispatch  signature-verifier device/host dispatch (span)
 #   apply            BlockExecutor.apply_block (span)
 #   flush            height's store writes committed (span)
 #   wal.fsync        the ENDHEIGHT WAL fsync (span)
@@ -80,7 +79,7 @@ from tendermint_tpu.utils import knobs
 SPAN_CATALOG = frozenset((
     "height.begin", "propose", "proposal.recv", "part.first",
     "block.full", "quorum.prevote", "quorum.precommit",
-    "verify.dispatch", "apply", "flush", "wal.fsync", "commit",
+    "apply", "flush", "wal.fsync", "commit",
     "p2p.recv", "mempool.recv", "stall",
     "snapshot.restore", "sync.chunk", "queue.saturated", "slo.sample",
     "block.reconstruct", "votes.agg", "transition.digest",

@@ -13,6 +13,7 @@ from dataclasses import dataclass, field
 from typing import List, Optional
 
 from tendermint_tpu.ops import merkle
+from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
 from tendermint_tpu.types.vote import Vote, VoteType
 
@@ -379,9 +380,10 @@ class Block:
 
     @classmethod
     def from_bytes(cls, b: bytes) -> "Block":
-        blk = cls.from_obj(encoding.cloads(b))
-        blk.__dict__["_bytes"] = bytes(b)
-        blk.__dict__["_bytes_hh"] = blk.header.hash()
+        with trace.span("wire.decode_block", bytes=len(b)):
+            blk = cls.from_obj(encoding.cloads(b))
+            blk.__dict__["_bytes"] = bytes(b)
+            blk.__dict__["_bytes_hh"] = blk.header.hash()
         return blk
 
     def make_part_set(self, part_size: int):

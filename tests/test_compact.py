@@ -43,9 +43,12 @@ def _fresh_knobs(monkeypatch):
 
 @pytest.fixture
 def metrics():
+    was = telemetry.enabled()
     telemetry.configure(enabled=True)
     yield telemetry.REGISTRY
-    telemetry.configure(enabled=False)
+    # the flag it found, not off: telemetry is process-wide, and xdist
+    # runs other files' tests in this worker afterwards
+    telemetry.configure(enabled=was)
 
 
 def _gen(n, chain_id):
