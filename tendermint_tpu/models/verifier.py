@@ -92,6 +92,11 @@ _m_predecomp_evictions = telemetry.counter(
 _m_predecomp_keys = telemetry.gauge(
     "verifier_predecomp_keys",
     "Pubkey rows currently resident in the predecompression LRU")
+_m_predecomp_assembled = telemetry.counter(
+    "verifier_predecomp_assembled_total",
+    "Device batches that got predecompressed rows, by whether the "
+    "arrays were built (per-key lookups, or a fill) or reused from the "
+    "memo of whole key sequences", ("how",))
 # the verifier's request id in the span recorder: every span of one
 # dispatch (telemetry/trace.py) carries its number as `req`
 _dispatch_seq = itertools.count(1)

@@ -240,8 +240,16 @@ def verify_from_bytes_best(pk, rb, s_bytes, h_bytes):
 # once a validator's key has been decompressed once, EVERY later batch
 # containing it hits, regardless of batch composition or order. Rows
 # are the canonical field bytes of (-A).x / A.y plus the validity flag
-# (65 bytes each) — host-resident, re-assembled and re-uploaded per
-# batch (m x 64B, trivial next to the sqrt the *_pre kernels skip).
+# (65 bytes each) — host-resident and uploaded with every batch (m x
+# 65B, trivial next to the sqrt the *_pre kernels skip). Looking up and
+# stacking 8,192 rows in Python costs most of a kernel's time, and a
+# commit's keys arrive in validator-set order every time, so the rows
+# are ASSEMBLED once per key sequence: _predecomp_memo keeps the last
+# few batches' arrays under their whole key bytes, and a batch whose
+# keys arrive in a sequence seen before is handed those arrays again.
+# The per-pubkey cache stays the source of truth: a memo entry is used
+# only while every one of its keys is still resident, and a use
+# refreshes their recency and counts as the `hit` it is.
 
 _PREDECOMP_MAX_KEYS = 16384  # rows, ~1MB — covers a 10k-validator set
 # batches below this padded size skip the cache: one-shot small batches
@@ -250,6 +258,13 @@ _PREDECOMP_MAX_KEYS = 16384  # rows, ~1MB — covers a 10k-validator set
 _PREDECOMP_MIN_BATCH = 64
 # pubkey -> (xneg_bytes u8[32], y_bytes u8[32], ok bool)
 _predecomp: "OrderedDict[bytes, tuple]" = OrderedDict()
+# a padded batch's key bytes -> [its distinct keys (None until the first
+# reuse), (xneg u8[m,32], y u8[m,32], ok bool[m]) read-only]. A
+# dispatch window is at most 4 chunks, each window starts on a commit,
+# so a chain repeats 4 sequences: hold 8, least recently used out
+# (8 x 8,192 rows x (32 key + 65 row bytes) = 6.4 MB at most)
+_PREDECOMP_MEMO_MAX = 8
+_predecomp_memo: "OrderedDict[bytes, list]" = OrderedDict()
 # pubkeys sighted once (first sighting stays on the fused full kernel:
 # a one-shot batch must not pay a separate decompress dispatch)
 _predecomp_seen: "OrderedDict[bytes, bool]" = OrderedDict()
@@ -274,17 +289,21 @@ _predecomp_stats = {"hit": 0, "fill": 0, "full": 0, "evict": 0,
                     "first_call_s": {}}
 
 
-def _predecomp_note(outcome: str, n: int = 1) -> None:
+def _predecomp_note(outcome: str, n: int = 1, how: str = "") -> None:
     """Mirror a cache outcome into tm_verifier_predecomp_* telemetry
     (registered by models/verifier beside the other tm_verifier_*
     families; lazy import — models.verifier is loaded in any process
-    that dispatches batches here)."""
+    that dispatches batches here). `how` says, for a batch that gets
+    rows, whether they were "built" from the per-key rows (or the
+    device) or "reused" from the batch memo."""
     _predecomp_stats[outcome] += n
     from tendermint_tpu.models import verifier
     if outcome == "evict":
         verifier._m_predecomp_evictions.inc(n)
     else:
         verifier._m_predecomp.labels(outcome).inc(n)
+    if how:
+        verifier._m_predecomp_assembled.labels(how).inc()
     verifier._m_predecomp_keys.set(len(_predecomp))
 # Batched verifies dispatch concurrently (fast-sync collector, lite
 # certify, RPC handlers all share default_verifier()), and OrderedDict
@@ -362,9 +381,29 @@ def _predecomp_rows(pk_np, mesh):
     its pubkeys are mostly fresh (a first-sighting batch must not
     pay the extra decompress dispatch — it takes the fused full kernel
     while its keys are marked seen; any later batch made of seen keys
-    decompresses ONCE and fills per-key rows)."""
+    decompresses ONCE and fills per-key rows). A batch whose whole key
+    sequence was assembled before gets those arrays again, read-only
+    and shared between dispatches."""
     n = pk_np.shape[0]
     raw = pk_np.tobytes()
+    with _predecomp_lock:
+        memo = _predecomp_memo.get(raw)
+        if memo is not None:
+            distinct, out = memo
+            if distinct is None:
+                # first reuse (a sequence never asked for again never
+                # pays for this): its distinct keys, in the order of
+                # their last row, so that moving them to the end one by
+                # one leaves the LRU as moving every row's key does
+                distinct = memo[0] = tuple(reversed(dict.fromkeys(
+                    raw[i - 32:i] for i in range(32 * n, 0, -32))))
+            if all(map(_predecomp.__contains__, distinct)):
+                for k in distinct:
+                    _predecomp.move_to_end(k)
+                _predecomp_memo.move_to_end(raw)
+                _predecomp_note("hit", how="reused")
+                return out
+            del _predecomp_memo[raw]    # a key was evicted: build anew
     keys = [raw[i * 32:(i + 1) * 32] for i in range(n)]
     with _predecomp_lock:
         rows = [_predecomp.get(k) for k in keys]
@@ -372,7 +411,7 @@ def _predecomp_rows(pk_np, mesh):
         if not miss:
             for k in keys:
                 _predecomp.move_to_end(k)
-            _predecomp_note("hit")
+            _predecomp_note("hit", how="built")
         else:
             fresh = miss - _predecomp_seen.keys()
             for k in fresh:
@@ -384,7 +423,7 @@ def _predecomp_rows(pk_np, mesh):
                 # dispatch); the NEXT batch over these keys fills rows
                 _predecomp_note("full")
                 return None
-            _predecomp_note("fill")
+            _predecomp_note("fill", how="built")
     if miss:
         # repeat traffic over uncached keys: decompress the whole batch
         # once (outside the lock — device dispatch), store per-key rows.
@@ -404,11 +443,16 @@ def _predecomp_rows(pk_np, mesh):
                 evicted += 1
             if evicted:
                 _predecomp_note("evict", evicted)
-    else:
-        xnb_h = np.stack([r[0] for r in rows])
-        yb_h = np.stack([r[1] for r in rows])
-        ok_h = np.array([r[2] for r in rows], np.bool_)
-    return xnb_h, yb_h, ok_h
+        return xnb_h, yb_h, ok_h
+    out = (np.stack([r[0] for r in rows]), np.stack([r[1] for r in rows]),
+           np.array([r[2] for r in rows], np.bool_))
+    for a in out:
+        a.flags.writeable = False
+    with _predecomp_lock:
+        _predecomp_memo[raw] = [None, out]
+        while len(_predecomp_memo) > _PREDECOMP_MEMO_MAX:
+            _predecomp_memo.popitem(last=False)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,14 @@
 """Differential tests: TPU batch-verify kernel vs pure-Python RFC 8032 ref."""
 
+import contextlib
 import hashlib
 import random
+import sys
+import threading
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from tendermint_tpu.ops import curve, ed25519, field as fe
 from tendermint_tpu.utils import ed25519_ref as ref
@@ -303,3 +307,290 @@ def test_scalar_openssl_matches_pure_oracle():
         assert not PubKey(pk).verify(msg + b"!", sig)
     finally:
         keys_mod._ossl_pub_cls = orig
+
+
+# --------------------------------------------------------------------------
+# The memo of assembled rows in front of the per-pubkey cache (ISSUE 25):
+# a chunk whose keys arrive in a sequence assembled before is handed the
+# same arrays again; the per-pubkey cache stays the source of truth.
+# --------------------------------------------------------------------------
+
+ASSEMBLED = "verifier_predecomp_assembled_total"
+
+
+@contextlib.contextmanager
+def predecomp_sandbox(min_batch=8, max_keys=None):
+    """Empty caches, counters on, the gate at the small shapes the
+    earlier tests compiled; everything as it was afterwards."""
+    from tendermint_tpu import telemetry
+    was_enabled = telemetry.enabled()
+    orig = ed25519._PREDECOMP_MIN_BATCH, ed25519._PREDECOMP_MAX_KEYS
+    caches = (ed25519._predecomp, ed25519._predecomp_seen,
+              ed25519._predecomp_memo)
+    telemetry.set_enabled(True)
+    ed25519._PREDECOMP_MIN_BATCH = min_batch
+    if max_keys is not None:
+        ed25519._PREDECOMP_MAX_KEYS = max_keys
+    for c in caches:
+        c.clear()
+    try:
+        yield
+    finally:
+        telemetry.set_enabled(was_enabled)
+        ed25519._PREDECOMP_MIN_BATCH, ed25519._PREDECOMP_MAX_KEYS = orig
+        for c in caches:
+            c.clear()
+
+
+def assembled():
+    """(built, reused) of the counter, and the cache's own stats."""
+    from tendermint_tpu import telemetry
+    return (tuple(telemetry.value(ASSEMBLED, {"how": how}) or 0.0
+                  for how in ("built", "reused")),
+            ed25519.predecomp_stats())
+
+
+def fill_by_hand(keys):
+    """Rows for `keys` as a fill would leave them, without the sqrt:
+    the row bytes are a function of the key, which is all the cache
+    layer knows of them."""
+    for k in keys:
+        d = hashlib.sha512(k).digest()
+        ed25519._predecomp[k] = (np.frombuffer(d[:32], np.uint8).copy(),
+                                 np.frombuffer(d[32:], np.uint8).copy(),
+                                 bool(d[0] & 1))
+
+
+def as_rows(keys):
+    return np.frombuffer(b"".join(keys), np.uint8).reshape(len(keys), 32)
+
+
+def rows_match_their_keys(rows, keys):
+    xn, y, ok = rows
+    assert xn.shape == y.shape == (len(keys), 32) and ok.shape == (len(keys),)
+    for i, k in enumerate(keys):
+        want = ed25519._predecomp[k]
+        assert xn[i].tobytes() == want[0].tobytes(), i
+        assert y[i].tobytes() == want[1].tobytes(), i
+        assert bool(ok[i]) == want[2], i
+
+
+def valset(n, tag=0):
+    return [hashlib.sha256(b"memo val %d.%d" % (tag, i)).digest()
+            for i in range(n)]
+
+
+LAYOUTS = {
+    # 64 validators all signing 128 commits, in validator-set order
+    "64_keys_x128_set_order": lambda: valset(64) * 128,
+    "8192_distinct": lambda: valset(8192),
+    # the same multiset as the first, each validator's rows together
+    "64_keys_x128_grouped": lambda: [k for k in valset(64)
+                                     for _ in range(128)],
+    # 100 commits and a tail of padding rows (the all-zero key)
+    "tail_of_zero_key_rows": lambda: valset(64) * 100 + [bytes(32)] * 1792,
+}
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_reused_rows_equal_rows_built_with_the_memo_emptied(layout):
+    keys = LAYOUTS[layout]()
+    pk = as_rows(keys)
+    with predecomp_sandbox():
+        fill_by_hand(set(keys))
+        (b0, r0), s0 = assembled()
+        first = ed25519._predecomp_rows(pk, None)
+        again = ed25519._predecomp_rows(pk.copy(), None)
+        (b1, r1), s1 = assembled()
+        assert (b1 - b0, r1 - r0) == (1.0, 1.0)
+        assert s1["hit"] == s0["hit"] + 2 and s1["fill"] == s0["fill"]
+        assert all(a is b for a, b in zip(first, again))
+        ed25519._predecomp_memo.clear()
+        built = ed25519._predecomp_rows(pk, None)
+        assert all(a is not b for a, b in zip(built, again))
+        for a, b in zip(built, again):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+        rows_match_their_keys(again, keys)
+        # the same keys in another order are another sequence: never
+        # answered with this one's rows, before or after it is memoised
+        other = keys[1:] + keys[:1]
+        if layout != "8192_distinct":
+            other = sorted(keys)
+        assert sorted(other) == sorted(keys) and other != keys
+        for _ in range(2):
+            rows_match_their_keys(
+                ed25519._predecomp_rows(as_rows(other), None), other)
+        rows_match_their_keys(ed25519._predecomp_rows(pk, None), keys)
+        (b2, r2), _ = assembled()
+        assert (b2 - b1, r2 - r1) == (2.0, 2.0)
+
+
+def signed_batch(tag, n=8):
+    from bench_util import fast_signer
+    pubs, msgs, sigs = [], [], []
+    for i in range(n):
+        seed = bytes([tag, i]) * 16
+        m = b"memo %d.%d" % (tag, i)
+        pubs.append(ref.public_key(seed))
+        msgs.append(m)
+        sigs.append(fast_signer(seed)(m))
+    return pubs, msgs, sigs
+
+
+def test_reuse_hands_out_rows_not_verdicts():
+    """Two batches over identical keys, the second with one tampered
+    signature: the rows are reused, each lane's verdict is its own."""
+    pubs, msgs, sigs = signed_batch(11)
+    pubs[6] = b"\xff" * 32          # a key that is no point, cached as such
+    bad = list(sigs)
+    bad[3] = bad[3][:7] + bytes([bad[3][7] ^ 4]) + bad[3][8:]   # R, not s
+    with predecomp_sandbox():
+        for _ in range(3):          # full, fill, hit: assembled and kept
+            ed25519.verify_batch(pubs, msgs, sigs)
+        (b0, r0), _ = assembled()
+        good_got = ed25519.verify_batch(pubs, msgs, sigs)
+        bad_got = ed25519.verify_batch(pubs, msgs, bad)
+        (b1, r1), _ = assembled()
+        assert (b1 - b0, r1 - r0) == (0.0, 2.0)
+    assert good_got.tolist() == [ref.verify(p, m, s)
+                                 for p, m, s in zip(pubs, msgs, sigs)]
+    assert bad_got.tolist() == [ref.verify(p, m, s)
+                                for p, m, s in zip(pubs, msgs, bad)]
+    assert good_got.tolist() == [i != 6 for i in range(8)]
+    assert bad_got.tolist() == [i not in (3, 6) for i in range(8)]
+
+
+def test_a_cleared_cache_is_not_answered_from_the_memo():
+    a = signed_batch(12)
+    with predecomp_sandbox():
+        for _ in range(4):          # full, fill, hit, reuse
+            assert ed25519.verify_batch(*a).all()
+        (b0, r0), s0 = assembled()
+        assert r0 >= 1.0 and len(ed25519._predecomp_memo) == 1
+        ed25519._predecomp.clear()
+        ed25519._predecomp_seen.clear()
+        for _ in range(3):          # full -> fill -> hit again
+            assert ed25519.verify_batch(*a).all()
+        (b1, r1), s1 = assembled()
+        assert [s1[k] - s0[k] for k in ("full", "fill", "hit")] == [1, 1, 1]
+        assert (b1 - b0, r1 - r0) == (2.0, 0.0)     # fill and hit built
+        assert ed25519.verify_batch(*a).all()
+        (b2, r2), s2 = assembled()
+        assert (b2 - b1, r2 - r1) == (0.0, 1.0) and s2["hit"] == s1["hit"] + 1
+
+
+def test_an_evicted_key_sends_its_sequence_through_fill_again():
+    a = signed_batch(13)
+    b = signed_batch(14)
+    mixed = [x[:7] + y[:1] for x, y in zip(a, b)]   # seven of a's, one new
+    with predecomp_sandbox(max_keys=8):
+        for _ in range(4):
+            assert ed25519.verify_batch(*a).all()
+        (b0, r0), s0 = assembled()
+        for _ in range(2):          # full, then a fill that stores a 9th row
+            assert ed25519.verify_batch(*mixed).all()
+        _, s1 = assembled()
+        assert s1["evict"] == s0["evict"] + 1 and s1["keys"] == 8
+        assert a[0][0] not in ed25519._predecomp    # the oldest of a's
+        (b1, r1), s1 = assembled()
+        assert ed25519.verify_batch(*a).all()
+        (b2, r2), s2 = assembled()
+        assert s2["fill"] == s1["fill"] + 1 and s2["hit"] == s1["hit"]
+        assert (b2 - b1, r2 - r1) == (1.0, 0.0)
+        # the telemetry mirrors the stats, as it did before the memo
+        from tendermint_tpu import telemetry
+        assert telemetry.value("verifier_predecomp_keys") == 8.0
+
+
+def test_a_reuse_counts_as_the_hit_it_is_and_refreshes_recency():
+    keys = valset(8, tag=1) * 2
+    with predecomp_sandbox():
+        fill_by_hand(valset(8, tag=1))
+        ed25519._predecomp_rows(as_rows(keys), None)
+        fill_by_hand(valset(8, tag=2))      # younger than every key of it
+        from tendermint_tpu import telemetry
+        hit0 = telemetry.value("verifier_predecomp_batches_total",
+                               {"outcome": "hit"}) or 0.0
+        (b0, r0), s0 = assembled()
+        ed25519._predecomp_rows(as_rows(keys), None)
+        (b1, r1), s1 = assembled()
+        assert (b1 - b0, r1 - r0) == (0.0, 1.0)
+        assert s1["hit"] == s0["hit"] + 1
+        assert telemetry.value("verifier_predecomp_batches_total",
+                               {"outcome": "hit"}) == hit0 + 1.0
+        # the LRU's order is what per-row move_to_end leaves
+        assert list(ed25519._predecomp) == valset(8, tag=2) + valset(8, tag=1)
+
+
+def test_the_memo_holds_at_most_its_bound():
+    keys = valset(16, tag=3)
+    with predecomp_sandbox():
+        fill_by_hand(keys)
+        seqs = [keys[i:] + keys[:i] for i in range(12)]
+        for seq in seqs:
+            ed25519._predecomp_rows(as_rows(seq), None)
+            assert len(ed25519._predecomp_memo) <= ed25519._PREDECOMP_MEMO_MAX
+        assert ed25519._PREDECOMP_MEMO_MAX == 8
+        # the least recently used went: the last eight are reuses
+        (b0, r0), _ = assembled()
+        for seq in seqs[4:]:
+            rows_match_their_keys(
+                ed25519._predecomp_rows(as_rows(seq), None), seq)
+        (b1, r1), _ = assembled()
+        assert (b1 - b0, r1 - r0) == (0.0, 8.0)
+        ed25519._predecomp_rows(as_rows(seqs[0]), None)
+        assert assembled()[0][0] == b1 + 1.0
+
+
+def test_reused_arrays_refuse_a_write():
+    keys = valset(8, tag=4)
+    with predecomp_sandbox():
+        fill_by_hand(keys)
+        for _ in range(2):
+            rows = ed25519._predecomp_rows(as_rows(keys), None)
+            for a in rows:
+                assert not a.flags.writeable
+                with pytest.raises(ValueError):
+                    a[0] = 0
+        rows_match_their_keys(rows, keys)
+
+
+def test_threads_asking_for_one_sequence_at_once_get_equal_rows():
+    keys = valset(64, tag=5) * 8
+    pk = as_rows(keys)
+    n_threads, rounds = 16, 6
+    got, errors = [], []
+    gate = threading.Barrier(n_threads)
+
+    def ask():
+        try:
+            for _ in range(rounds):
+                gate.wait(timeout=30)
+                got.append(ed25519._predecomp_rows(pk.copy(), None))
+        except Exception as e:      # noqa: BLE001 - reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with predecomp_sandbox():
+            fill_by_hand(set(keys))
+            (b0, r0), s0 = assembled()
+            threads = [threading.Thread(target=ask) for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads) and not errors
+            (b1, r1), s1 = assembled()
+            assert len(got) == n_threads * rounds
+            want = [a.tobytes() for a in got[0]]
+            for rows in got:
+                assert [a.tobytes() for a in rows] == want
+            rows_match_their_keys(got[-1], keys)
+            # every call was a hit, built or reused, and none was lost
+            assert s1["hit"] - s0["hit"] == len(got)
+            assert (b1 - b0) + (r1 - r0) == len(got) and r1 - r0 >= rounds - 1
+            assert len(ed25519._predecomp_memo) == 1
+    finally:
+        sys.setswitchinterval(interval)
