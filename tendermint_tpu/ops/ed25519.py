@@ -469,6 +469,20 @@ def _predecomp_rows(pk_np, mesh):
 _sign_params_cache: dict = {}
 
 
+def _public_key(seed: bytes) -> bytes:
+    """The RFC 8032 public key of a seed: OpenSSL's (~40 us) where
+    `cryptography` is installed, else the reference's pure-Python point
+    multiply (~2.4 ms: 10,000 signers cost 24 s of it)."""
+    try:
+        from cryptography.hazmat.primitives.asymmetric.ed25519 import \
+            Ed25519PrivateKey
+    except ImportError:  # pragma: no cover
+        from tendermint_tpu.utils import ed25519_ref as ref
+        return ref.public_key(seed)
+    return Ed25519PrivateKey.from_private_bytes(
+        seed).public_key().public_bytes_raw()
+
+
 def signing_params(seed: bytes):
     """(a32, prefix32, pk32) for an RFC 8032 seed, cached per seed."""
     ent = _sign_params_cache.get(seed)
@@ -478,10 +492,11 @@ def signing_params(seed: bytes):
         a[0] &= 248
         a[31] &= 127
         a[31] |= 64
-        from tendermint_tpu.utils import ed25519_ref as ref
-        ent = (bytes(a), h[32:], ref.public_key(seed))
-        if len(_sign_params_cache) > 4096:
-            _sign_params_cache.clear()
+        ent = (bytes(a), h[32:], _public_key(seed))
+        # holds one validator set of the size the predecomp cache holds;
+        # one seed more puts the oldest out, never the whole set
+        if len(_sign_params_cache) >= _PREDECOMP_MAX_KEYS:
+            del _sign_params_cache[next(iter(_sign_params_cache))]
         _sign_params_cache[seed] = ent
     return ent
 

@@ -64,6 +64,11 @@ SPANS = {
     "verify.enqueue": "device kernels",  # transfers + the jitted call
     "verify.fetch": "verifier",         # blocking fetch of the verdicts
     "verify.inflight": "device",        # first enqueue -> end of fetch
+    # one commit, one caller that waits (ValidatorSet.verify_commit_async
+    # and its finisher; req = the commit's height)
+    "commit.collect": "verifier",
+    "commit.wait": "verifier",          # verify.fetch nests in it
+    "commit.check": "verifier",
     "lite.collect": "verifier",
     "lite.wait": "verifier",
     "lite.check": "verifier",

@@ -15,6 +15,7 @@ from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
 from tendermint_tpu.ops import merkle
+from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
 from tendermint_tpu.types.keys import PubKey, address_of
 from tendermint_tpu.types.vote import VoteType
@@ -287,12 +288,16 @@ class ValidatorSet:
         verifier merge concurrent commit verifies into one batch."""
         from tendermint_tpu.models.verifier import default_verifier
         verifier = verifier or default_verifier()
-        items, item_power = self.commit_verification_items(
-            chain_id, block_id, height, commit)
+        with trace.span("commit.collect", req=height):
+            items, item_power = self.commit_verification_items(
+                chain_id, block_id, height, commit)
         resolve_ok = verifier.verify_async(items)
 
         def finish() -> None:
-            self.check_commit_results(resolve_ok(), item_power)
+            with trace.span("commit.wait", req=height):
+                ok = resolve_ok()
+            with trace.span("commit.check", req=height):
+                self.check_commit_results(ok, item_power)
 
         return finish
 
