@@ -300,6 +300,7 @@ def leg_a(seed: int) -> dict:
 
     items, _power = valset.commit_verification_items(
         chain_id, bid, height, commit)
+    items = list(items)     # triples, to assign the tampered lanes into
     lanes = tampered_lanes(items, rng)
     for lane, (_case, item) in lanes.items():
         items[lane] = item

@@ -24,6 +24,7 @@ from tendermint_tpu import telemetry
 from tendermint_tpu.p2p.base_reactor import Reactor
 from tendermint_tpu.p2p.conn import ChannelDescriptor
 from tendermint_tpu.blockchain.pool import BlockPool
+from tendermint_tpu.types.sigcolumns import SigColumns
 from tendermint_tpu.state.execution import ApplyBlockError
 from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
@@ -332,7 +333,8 @@ class BlockchainReactor(Reactor):
         batch_valset = self.state.validators
         part_size = \
             self.state.consensus_params.block_gossip.block_part_size_bytes
-        all_items = []
+        batches = []
+        lo = 0
         per_block = []  # (block, parts, block_id, commit, power|None, lo, n)
         for i in range(len(blocks) - 1):
             block, commit = blocks[i], blocks[i + 1].last_commit
@@ -348,9 +350,11 @@ class BlockchainReactor(Reactor):
                                   None, 0, 0))
                 continue
             per_block.append((block, parts, block_id, commit, item_power,
-                              len(all_items), len(items)))
-            all_items.extend(items)
-        return per_block, all_items, batch_valset.hash(), part_size
+                              lo, len(items)))
+            lo += len(items)
+            batches.append(items)
+        return (per_block, SigColumns.concat(batches), batch_valset.hash(),
+                part_size)
 
     def _apply_window(self, per_block, ok, batch_valset_hash,
                       part_size) -> int:

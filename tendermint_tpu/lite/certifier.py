@@ -22,6 +22,7 @@ certify_chain     — the TPU batch path: certify a whole run of
 
 from __future__ import annotations
 
+import time
 from typing import List, Optional
 
 from tendermint_tpu.lite.types import (
@@ -30,6 +31,7 @@ from tendermint_tpu.lite.types import (
     SignedHeader,
     ValidatorsChangedError,
 )
+from tendermint_tpu.types.sigcolumns import SigColumns
 from tendermint_tpu.types.validator_set import ValidatorSet
 
 
@@ -260,9 +262,12 @@ class ContinuousCertifier:
 
 def default_window(n_vals: int) -> int:
     """Headers per pooled dispatch window: ~32k signatures in flight
-    (dispatches amortized, chunks fetched in parallel, memory bounded;
-    the figure comes from sweeps on an earlier host and is not
-    re-measured on the attached chip).
+    for a set of up to 512 validators (dispatches amortized, chunks
+    fetched in parallel, memory bounded; the figure comes from sweeps
+    on an earlier host and is not re-measured on the attached chip),
+    and never under 64 headers: a larger set gets 64 * n_vals
+    signatures a window, 640,000 at 10,000 validators (PERF.md
+    section 7 has a probe at that size).
     Exposed so benches can warm the exact tail batch shape a partial
     chain will dispatch."""
     return max(64, 32768 // max(1, n_vals))
@@ -297,14 +302,28 @@ def certify_chain(chain_id: str, fcs: List[FullCommit],
     if window is None:
         window = default_window(len(expect_vals))
 
-    def collect(window_fcs):
-        items_w = []
+    def collect(window_fcs, req):
+        # two passes over the window, each timed once: the headers
+        # (lite.headers), then the commits' signatures as columns
+        # (lite.votes). The first bad header in chain order is the one
+        # reported: the votes stop where the headers did.
+        t0 = time.perf_counter()
+        bad = None
+        for k, fc in enumerate(window_fcs):
+            try:
+                fc.validate_basic(chain_id)
+                if fc.validators.hash() != expect_vals.hash():
+                    raise ValidatorsChangedError(
+                        f"valset discontinuity at height {fc.height}")
+            except CertificationError as e:
+                bad, window_fcs = e, window_fcs[:k]
+                break
+        t1 = time.perf_counter()
+        trace.complete("lite.headers", t0, t1, req=req)
+        batches = []
         spans = []  # (item_power, lo, n, height)
+        lo = 0
         for fc in window_fcs:
-            fc.validate_basic(chain_id)
-            if fc.validators.hash() != expect_vals.hash():
-                raise ValidatorsChangedError(
-                    f"valset discontinuity at height {fc.height}")
             sh = fc.signed_header
             try:
                 items, item_power = expect_vals.commit_verification_items(
@@ -312,12 +331,17 @@ def certify_chain(chain_id: str, fcs: List[FullCommit],
             except ValueError as e:
                 raise CertificationError(
                     f"height {fc.height}: {e}") from e
-            spans.append((item_power, len(items_w), len(items), fc.height))
-            items_w.extend(items)
+            spans.append((item_power, lo, len(items), fc.height))
+            lo += len(items)
+            batches.append(items)
             # constant-valset segments only: when the set changes, the
             # caller splits the chain there and bridges with
             # DynamicCertifier.update (that transition needs
             # verify_commit_any, which can't pool across the boundary)
+        if bad is not None:
+            raise bad
+        items_w = SigColumns.concat(batches)
+        trace.complete("lite.votes", t1, time.perf_counter(), req=req)
         return items_w, spans
 
     def check(spans, ok):
@@ -340,7 +364,7 @@ def certify_chain(chain_id: str, fcs: List[FullCommit],
         for lo in range(0, len(fcs), window):
             req = fcs[lo].height
             with trace.span("lite.collect", req=req):
-                items_w, spans = collect(fcs[lo:lo + window])
+                items_w, spans = collect(fcs[lo:lo + window], req)
             fut = pool.submit(verifier.verify_async(items_w))
             if pending is not None:
                 settle(*pending)

@@ -49,6 +49,7 @@ from tendermint_tpu.telemetry import trace
 # import-light: parallel.mesh only pulls jax inside its kernel builders,
 # so the spec helpers + tm_mesh_* instruments cost nothing at import
 from tendermint_tpu.parallel import mesh as _pmesh
+from tendermint_tpu.types.sigcolumns import SigColumns
 from tendermint_tpu.utils import knobs
 
 # The paper's headline metric is sig-verifies/sec/chip; these families
@@ -97,6 +98,11 @@ _m_predecomp_assembled = telemetry.counter(
     "Device batches that got predecompressed rows, by whether the "
     "arrays were built (per-key lookups, or a fill) or reused from the "
     "memo of whole key sequences", ("how",))
+_m_batch_sigs = telemetry.counter(
+    "verifier_batch_sigs_total",
+    "Signatures dispatched to the device, by the form their batch "
+    "arrived in: columns (a SigColumns, prepared in place) or items "
+    "(triples, walked one by one)", ("form",))
 # the verifier's request id in the span recorder: every span of one
 # dispatch (telemetry/trace.py) carries its number as `req`
 _dispatch_seq = itertools.count(1)
@@ -306,15 +312,22 @@ class BatchVerifier:
         # fast path: the whole host prep (classification, length/s<L
         # checks, SHA-512 + mod-L) in one native call, GIL released —
         # returns None for batches that need the general path below
-        # (secp256k1 keys, non-bytes members, native unavailable)
+        # (secp256k1 keys, non-bytes members, native unavailable). A
+        # batch that arrives as columns is prepared from them in place;
+        # any other Sequence, a SigColumns too, is walked as triples.
         from tendermint_tpu import native
         with trace.span("verify.prep", n=n):
-            prep = native.prep_items(items)
+            prep, form = None, "columns"
+            if isinstance(items, SigColumns):
+                prep = native.prep_columns(items.pk, items.sigs,
+                                           items.msgs, items.idx)
+            if prep is None:
+                prep, form = native.prep_items(items), "items"
         if prep is not None:
             from tendermint_tpu.ops import ed25519
             if not self._mesh_resolved:
                 self._resolve_mesh()
-            self._record_jax_dispatch(n)
+            self._record_jax_dispatch(n, form)
             pk, rb, sb, hb, pre = prep
             pending = []
             occ = telemetry.enabled()
@@ -394,7 +407,7 @@ class BatchVerifier:
                     _pmesh.record_dispatch("verify", hi - lo, b)
         return self._make_resolver(n, pending, t_dispatch, span, t_enqueued)
 
-    def _record_jax_dispatch(self, n: int) -> None:
+    def _record_jax_dispatch(self, n: int, form: str = "items") -> None:
         """Stats + calls/sigs samples for one device dispatch (chunk
         occupancy is observed inside the chunk loops, where lo/hi and
         the ed25519 module are already in hand)."""
@@ -404,6 +417,7 @@ class BatchVerifier:
             return
         _m_calls.labels("jax").inc()
         _m_sigs.labels("jax").inc(n)
+        _m_batch_sigs.labels(form).inc(n)
 
     @staticmethod
     def _make_resolver(n: int, pending, t_dispatch: float, span,

@@ -224,6 +224,17 @@ def kv():
     return _KV.ensure_loaded()
 
 
+def _prep_arrays(out, n: int):
+    """The extension's five byte buffers as numpy views."""
+    if out is None:
+        return None
+    import numpy as np
+    pk_b, rb_b, s_b, h_b, pre_b = out
+    as_mat = lambda b: np.frombuffer(b, np.uint8).reshape(n, 32)
+    pre = np.frombuffer(pre_b, np.uint8).astype(bool)
+    return as_mat(pk_b), as_mat(rb_b), as_mat(s_b), as_mat(h_b), pre
+
+
 def prep_items(items):
     """One-call verify prep: items [(pk, msg, sig), ...] ->
     (pk u8[N,32], R u8[N,32], s u8[N,32], h u8[N,32], pre bool[N])
@@ -232,15 +243,20 @@ def prep_items(items):
     mod = _prep()
     if mod is None:
         return None
-    out = mod.prep_items(items)
-    if out is None:
+    return _prep_arrays(mod.prep_items(items), len(items))
+
+
+def prep_columns(pk, sigs, msgs, idx):
+    """prep_items for a batch held as columns (types/sigcolumns.py):
+    lane i is (pk[i], msgs[idx[i]], sigs[i]). The same five arrays, bit
+    for bit, or None when unavailable / when a member is not bytes."""
+    mod = _prep()
+    if mod is None:
         return None
     import numpy as np
-    n = len(items)
-    pk_b, rb_b, s_b, h_b, pre_b = out
-    as_mat = lambda b: np.frombuffer(b, np.uint8).reshape(n, 32)
-    pre = np.frombuffer(pre_b, np.uint8).astype(bool)
-    return as_mat(pk_b), as_mat(rb_b), as_mat(s_b), as_mat(h_b), pre
+    return _prep_arrays(
+        mod.prep_columns(np.ascontiguousarray(pk, np.uint8), sigs, msgs,
+                         np.ascontiguousarray(idx, np.int32)), len(sigs))
 
 
 def _pack(items: List[bytes]):

@@ -11,10 +11,9 @@
 //
 // The SHA-512 loop runs with the GIL RELEASED over private copies of
 // the inputs, so a node pipelining several commits overlaps hashing
-// with device fetches. SHA-512 itself uses OpenSSL's one-shot SHA512()
-// when libcrypto.so.3 is loadable at runtime (AVX2 assembly, ~3x the
-// portable block function) and falls back to the portable Sha512 from
-// hostops.cpp otherwise.
+// with device fetches. SHA-512 itself is OpenSSL's when libcrypto.so.3
+// is loadable at runtime (AVX2 assembly, ~3x the portable block
+// function) and the portable Sha512 from hostops.cpp otherwise.
 //
 // Returns None for input shapes the fast path does not cover —
 // secp256k1 keys (33-byte SEC1, host-verified by design), non-bytes
@@ -30,32 +29,29 @@
 
 namespace {
 
-typedef unsigned char *(*sha512_oneshot_fn)(const unsigned char *, size_t,
-                                            unsigned char *);
-sha512_oneshot_fn ossl_sha512 = nullptr;
+// OpenSSL's streaming SHA-512 (SHA512_Init/Update/Final), not its
+// one-shot SHA512(): since 3.0 the one-shot fetches the algorithm on
+// every call, which costs as much again as hashing a 346-byte input
+// (32,768 inputs: 43 ms against 25-27 on the sandbox's CPU, PR 27), and
+// the streaming calls take R, A and M where they lie.
+typedef int (*sha512_init_fn)(void *);
+typedef int (*sha512_update_fn)(void *, const void *, size_t);
+typedef int (*sha512_final_fn)(unsigned char *, void *);
+sha512_init_fn ossl_init = nullptr;
+sha512_update_fn ossl_update = nullptr;
+sha512_final_fn ossl_final = nullptr;
 
 inline void sha512_ram(const uint8_t *r, const uint8_t *a,
                        const uint8_t *m, size_t mlen, uint8_t out[64]) {
     // SHA512(r32 || a32 || M); a may be null (32-byte-prefix inputs —
     // the signing nonce hash SHA512(prefix || M))
-    size_t head = (a != nullptr) ? 64 : 32;
-    if (ossl_sha512 != nullptr) {
-        // one-shot wants contiguous input; the head is 32/64 bytes,
-        // messages are vote/header sign-bytes (~100-300B), so a stack
-        // scratch covers the common case without an allocation
-        uint8_t scratch[512];
-        if (head + mlen <= sizeof scratch) {
-            std::memcpy(scratch, r, 32);
-            if (a != nullptr) std::memcpy(scratch + 32, a, 32);
-            std::memcpy(scratch + head, m, mlen);
-            ossl_sha512(scratch, head + mlen, out);
-            return;
-        }
-        std::vector<uint8_t> big(head + mlen);
-        std::memcpy(big.data(), r, 32);
-        if (a != nullptr) std::memcpy(big.data() + 32, a, 32);
-        std::memcpy(big.data() + head, m, mlen);
-        ossl_sha512(big.data(), big.size(), out);
+    if (ossl_final != nullptr) {
+        alignas(16) uint8_t ctx[512];   // SHA512_CTX is 216 bytes
+        ossl_init(ctx);
+        ossl_update(ctx, r, 32);
+        if (a != nullptr) ossl_update(ctx, a, 32);
+        ossl_update(ctx, m, mlen);
+        ossl_final(out, ctx);
         return;
     }
     Sha512 s;
@@ -67,31 +63,68 @@ inline void sha512_ram(const uint8_t *r, const uint8_t *a,
 
 }  // namespace
 
+namespace {
+
+// The five outputs of a verify prep (pk, R, s, h as n*32 bytes, pre as
+// n bytes), zeroed: a lane that fails its precheck stays zero.
+struct PrepOut {
+    PyObject *obj[5] = {nullptr, nullptr, nullptr, nullptr, nullptr};
+    uint8_t *pk = nullptr, *rb = nullptr, *sb = nullptr, *hb = nullptr,
+            *pre = nullptr;
+
+    bool alloc(Py_ssize_t n) {
+        for (int k = 0; k < 5; k++) {
+            Py_ssize_t len = k < 4 ? n * 32 : n;
+            obj[k] = PyBytes_FromStringAndSize(nullptr, len);
+            if (obj[k] == nullptr) return false;
+            std::memset(PyBytes_AS_STRING(obj[k]), 0, (size_t)len);
+        }
+        pk = (uint8_t *)PyBytes_AS_STRING(obj[0]);
+        rb = (uint8_t *)PyBytes_AS_STRING(obj[1]);
+        sb = (uint8_t *)PyBytes_AS_STRING(obj[2]);
+        hb = (uint8_t *)PyBytes_AS_STRING(obj[3]);
+        pre = (uint8_t *)PyBytes_AS_STRING(obj[4]);
+        return true;
+    }
+
+    // Lane i's precheck (key and signature lengths, s < L); a lane that
+    // passes gets its pk, R and s rows and pre = 1.
+    bool admit(Py_ssize_t i, const uint8_t *key, Py_ssize_t klen,
+               const uint8_t *sig, Py_ssize_t slen) {
+        if (klen != 32 || slen != 64 || !scalar_below_l(sig + 32))
+            return false;
+        std::memcpy(pk + 32 * i, key, 32);
+        std::memcpy(rb + 32 * i, sig, 32);
+        std::memcpy(sb + 32 * i, sig + 32, 32);
+        pre[i] = 1;
+        return true;
+    }
+
+    PyObject *pack() {
+        PyObject *out =
+            PyTuple_Pack(5, obj[0], obj[1], obj[2], obj[3], obj[4]);
+        drop();
+        return out;
+    }
+
+    void drop() {
+        for (int k = 0; k < 5; k++) Py_CLEAR(obj[k]);
+    }
+};
+
+}  // namespace
+
 static PyObject *prep_items(PyObject *self, PyObject *arg) {
     PyObject *seq = PySequence_Fast(arg, "prep_items expects a sequence");
     if (seq == nullptr) return nullptr;
     Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
 
-    PyObject *pk_b = PyBytes_FromStringAndSize(nullptr, n * 32);
-    PyObject *rb_b = PyBytes_FromStringAndSize(nullptr, n * 32);
-    PyObject *s_b = PyBytes_FromStringAndSize(nullptr, n * 32);
-    PyObject *h_b = PyBytes_FromStringAndSize(nullptr, n * 32);
-    PyObject *pre_b = PyBytes_FromStringAndSize(nullptr, n);
-    if (!pk_b || !rb_b || !s_b || !h_b || !pre_b) {
-        Py_XDECREF(pk_b); Py_XDECREF(rb_b); Py_XDECREF(s_b);
-        Py_XDECREF(h_b); Py_XDECREF(pre_b); Py_DECREF(seq);
+    PrepOut out;
+    if (!out.alloc(n)) {
+        out.drop();
+        Py_DECREF(seq);
         return nullptr;
     }
-    uint8_t *pk = (uint8_t *)PyBytes_AS_STRING(pk_b);
-    uint8_t *rb = (uint8_t *)PyBytes_AS_STRING(rb_b);
-    uint8_t *sb = (uint8_t *)PyBytes_AS_STRING(s_b);
-    uint8_t *hb = (uint8_t *)PyBytes_AS_STRING(h_b);
-    uint8_t *pre = (uint8_t *)PyBytes_AS_STRING(pre_b);
-    std::memset(pk, 0, (size_t)n * 32);
-    std::memset(rb, 0, (size_t)n * 32);
-    std::memset(sb, 0, (size_t)n * 32);
-    std::memset(hb, 0, (size_t)n * 32);
-    std::memset(pre, 0, (size_t)n);
 
     // Pass 1 (GIL held): copy messages into a private arena and pk/R/s
     // into the output buffers. Copies make the hash loop independent of
@@ -129,49 +162,125 @@ static PyObject *prep_items(PyObject *self, PyObject *arg) {
             fallback = true;  // secp256k1: host-routed, general path
             break;
         }
-        Py_ssize_t slen = PyBytes_GET_SIZE(so);
         moff[i + 1] = moff[i];
-        if (plen != 32 || slen != 64) {
-            Py_DECREF(fast);
-            continue;  // pre stays 0, buffers stay zeroed
+        if (out.admit(i, pp, plen, (const uint8_t *)PyBytes_AS_STRING(so),
+                      PyBytes_GET_SIZE(so))) {
+            Py_ssize_t mlen = PyBytes_GET_SIZE(mo);
+            const uint8_t *mp = (const uint8_t *)PyBytes_AS_STRING(mo);
+            arena.insert(arena.end(), mp, mp + mlen);
+            moff[i + 1] = moff[i] + (uint64_t)mlen;
         }
-        const uint8_t *sp = (const uint8_t *)PyBytes_AS_STRING(so);
-        if (!scalar_below_l(sp + 32)) {
-            Py_DECREF(fast);
-            continue;
-        }
-        std::memcpy(pk + 32 * i, pp, 32);
-        std::memcpy(rb + 32 * i, sp, 32);
-        std::memcpy(sb + 32 * i, sp + 32, 32);
-        Py_ssize_t mlen = PyBytes_GET_SIZE(mo);
-        const uint8_t *mp = (const uint8_t *)PyBytes_AS_STRING(mo);
-        arena.insert(arena.end(), mp, mp + mlen);
-        moff[i + 1] = moff[i] + (uint64_t)mlen;
-        pre[i] = 1;
         Py_DECREF(fast);
     }
     Py_DECREF(seq);
     if (fallback) {
-        Py_DECREF(pk_b); Py_DECREF(rb_b); Py_DECREF(s_b);
-        Py_DECREF(h_b); Py_DECREF(pre_b);
+        out.drop();
         Py_RETURN_NONE;
     }
 
     // Pass 2 (GIL released): h = SHA512(R || A || M) mod L
     Py_BEGIN_ALLOW_THREADS
     for (Py_ssize_t i = 0; i < n; i++) {
-        if (!pre[i]) continue;
+        if (!out.pre[i]) continue;
         uint8_t digest[64];
-        sha512_ram(rb + 32 * i, pk + 32 * i, arena.data() + moff[i],
+        sha512_ram(out.rb + 32 * i, out.pk + 32 * i,
+                   arena.data() + moff[i],
                    (size_t)(moff[i + 1] - moff[i]), digest);
-        reduce512_mod_l(digest, hb + 32 * i);
+        reduce512_mod_l(digest, out.hb + 32 * i);
     }
     Py_END_ALLOW_THREADS
+    return out.pack();
+}
 
-    PyObject *out = PyTuple_Pack(5, pk_b, rb_b, s_b, h_b, pre_b);
-    Py_DECREF(pk_b); Py_DECREF(rb_b); Py_DECREF(s_b);
-    Py_DECREF(h_b); Py_DECREF(pre_b);
-    return out;
+// prep_columns(pk, sigs, msgs, idx): prep_items for a batch held as
+// columns (types/sigcolumns.py) — pk a contiguous n*32-byte buffer, sigs
+// a sequence of n bytes objects, msgs the batch's sign-bytes and idx a
+// contiguous int32[n] buffer naming each lane's. The same prechecks and
+// the same five arrays, bit for bit; the columns are read where they
+// lie (the references taken in pass 1 keep the messages alive while the
+// GIL is released). None where a member is not bytes: general path.
+static PyObject *prep_columns(PyObject *, PyObject *args) {
+    Py_buffer pkv, idxv;
+    PyObject *sigs_o, *msgs_o;
+    if (!PyArg_ParseTuple(args, "y*OOy*", &pkv, &sigs_o, &msgs_o, &idxv))
+        return nullptr;
+    PyObject *sigs = nullptr, *msgs = nullptr, *result = nullptr;
+    PrepOut out;
+    std::vector<PyObject *> held;    // the messages, a reference each
+    Py_ssize_t n = 0, n_msgs = 0;
+    const uint8_t *keys = (const uint8_t *)pkv.buf;
+    const int32_t *idx = (const int32_t *)idxv.buf;
+    bool fallback = false;
+
+    sigs = PySequence_Fast(sigs_o, "prep_columns: sigs must be a sequence");
+    if (sigs == nullptr) goto done;
+    msgs = PySequence_Fast(msgs_o, "prep_columns: msgs must be a sequence");
+    if (msgs == nullptr) goto done;
+    n = PySequence_Fast_GET_SIZE(sigs);
+    n_msgs = PySequence_Fast_GET_SIZE(msgs);
+    if (pkv.len != 32 * n || idxv.len != 4 * n) {
+        PyErr_SetString(PyExc_ValueError,
+                        "prep_columns: pk must be n*32 bytes and idx n "
+                        "int32 for n signatures");
+        goto done;
+    }
+    if (!out.alloc(n)) goto done;
+
+    // Pass 1 (GIL held): the prechecks, pk/R/s into the output buffers
+    held.reserve((size_t)n_msgs);
+    for (Py_ssize_t k = 0; k < n_msgs; k++) {
+        PyObject *mo = PySequence_Fast_GET_ITEM(msgs, k);
+        if (!PyBytes_Check(mo)) {
+            fallback = true;
+            break;
+        }
+        Py_INCREF(mo);
+        held.push_back(mo);
+    }
+    for (Py_ssize_t i = 0; i < n && !fallback; i++) {
+        PyObject *so = PySequence_Fast_GET_ITEM(sigs, i);
+        if (!PyBytes_Check(so)) {
+            fallback = true;
+            break;
+        }
+        if (idx[i] < 0 || idx[i] >= n_msgs) {
+            PyErr_SetString(PyExc_ValueError,
+                            "prep_columns: idx names no message");
+            goto release;
+        }
+        out.admit(i, keys + 32 * i, 32,
+                  (const uint8_t *)PyBytes_AS_STRING(so),
+                  PyBytes_GET_SIZE(so));
+    }
+    if (fallback) {
+        result = Py_None;
+        Py_INCREF(result);
+        goto release;
+    }
+
+    // Pass 2 (GIL released): h = SHA512(R || A || M) mod L
+    Py_BEGIN_ALLOW_THREADS
+    for (Py_ssize_t i = 0; i < n; i++) {
+        if (!out.pre[i]) continue;
+        uint8_t digest[64];
+        PyObject *mo = held[idx[i]];
+        sha512_ram(out.rb + 32 * i, out.pk + 32 * i,
+                   (const uint8_t *)PyBytes_AS_STRING(mo),
+                   (size_t)PyBytes_GET_SIZE(mo), digest);
+        reduce512_mod_l(digest, out.hb + 32 * i);
+    }
+    Py_END_ALLOW_THREADS
+    result = out.pack();
+
+release:
+    for (PyObject *mo : held) Py_DECREF(mo);
+done:
+    out.drop();
+    Py_XDECREF(sigs);
+    Py_XDECREF(msgs);
+    PyBuffer_Release(&pkv);
+    PyBuffer_Release(&idxv);
+    return result;
 }
 
 namespace {
@@ -378,6 +487,9 @@ static PyMethodDef prep_methods[] = {
     {"prep_items", prep_items, METH_O,
      "items [(pk, msg, sig), ...] -> (pk, R, s, h, pre) byte buffers, "
      "or None when the batch needs the general Python path."},
+    {"prep_columns", prep_columns, METH_VARARGS,
+     "(pk n*32, sigs, msgs, idx int32[n]) -> what prep_items returns for "
+     "the triples (pk[i], msgs[idx[i]], sigs[i])."},
     {nullptr, nullptr, 0, nullptr},
 };
 
@@ -389,11 +501,15 @@ static struct PyModuleDef prep_moduledef = {
 
 PyMODINIT_FUNC PyInit__tmprep(void) {
     void *crypto = dlopen("libcrypto.so.3", RTLD_LAZY | RTLD_LOCAL);
-    if (crypto != nullptr)
-        ossl_sha512 = (sha512_oneshot_fn)dlsym(crypto, "SHA512");
+    if (crypto != nullptr) {
+        ossl_init = (sha512_init_fn)dlsym(crypto, "SHA512_Init");
+        ossl_update = (sha512_update_fn)dlsym(crypto, "SHA512_Update");
+        if (ossl_init != nullptr && ossl_update != nullptr)
+            ossl_final = (sha512_final_fn)dlsym(crypto, "SHA512_Final");
+    }
     PyObject *m = PyModule_Create(&prep_moduledef);
     if (m != nullptr)
         PyModule_AddStringConstant(
-            m, "sha512_impl", ossl_sha512 ? "openssl" : "portable");
+            m, "sha512_impl", ossl_final ? "openssl" : "portable");
     return m;
 }

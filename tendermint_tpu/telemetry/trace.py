@@ -70,6 +70,9 @@ SPANS = {
     "commit.wait": "verifier",          # verify.fetch nests in it
     "commit.check": "verifier",
     "lite.collect": "verifier",
+    # its two passes over a window, one event each (not one a header)
+    "lite.headers": "verifier",         # validate_basic, valset hash
+    "lite.votes": "verifier",           # the commits' columns
     "lite.wait": "verifier",
     "lite.check": "verifier",
     "sync.collect": "sync window engine",

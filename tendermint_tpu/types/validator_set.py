@@ -12,13 +12,16 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
+
+import numpy as np
 
 from tendermint_tpu.ops import merkle
 from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
 from tendermint_tpu.types.keys import PubKey, address_of
-from tendermint_tpu.types.vote import VoteType
+from tendermint_tpu.types.sigcolumns import SigColumns
+from tendermint_tpu.types.vote import VoteType, sign_bytes_template
 
 _address_memo = functools.lru_cache(maxsize=65536)(address_of)
 
@@ -64,6 +67,43 @@ class Validator:
         return cls(bytes.fromhex(o["pubkey"]), o["voting_power"], o["accum"])
 
 
+class _SetColumns(NamedTuple):
+    """A set's keys and powers in validator order, built once per set."""
+    pk: Optional[np.ndarray]    # uint8[V,32], read-only; None unless
+    #                             every key is a 32-byte ed25519 key
+    powers: np.ndarray          # int64[V], or Python ints past 2^63
+    total: int
+
+
+class CommitPower:
+    """The `item_power` of commit_verification_items, opaque to its
+    callers: per lane its validator's power and whether its vote is for
+    the block, and `tally`, the exact sum of the powers for the block
+    over all lanes. An invalid lane fails its commit outright, so that
+    sum does not wait for the verdicts; only a lane that HAS a verdict
+    counts, so a verdict vector shorter than the lanes is judged on
+    `tally_of` its length."""
+
+    __slots__ = ("powers", "for_block", "tally")
+
+    def __init__(self, powers: np.ndarray, for_block: np.ndarray,
+                 tally: int):
+        self.powers, self.for_block, self.tally = powers, for_block, tally
+
+    def tally_of(self, lanes: int) -> int:
+        """The power for the block of the first `lanes` lanes, exactly."""
+        return sum(self.powers[:lanes][self.for_block[:lanes]].tolist())
+
+
+def _lane_runs(starts: list, n: int) -> np.ndarray:
+    """int32[n]: for each lane the run it lies in, from the lanes at
+    which the runs start."""
+    if len(starts) == 1:
+        return np.zeros(n, np.int32)
+    return np.repeat(np.arange(len(starts), dtype=np.int32),
+                     np.diff(starts + [n]))
+
+
 class ValidatorSet:
     """Sorted-by-address validator array with accum-based proposer rotation
     (types/validator_set.go:24-71)."""
@@ -83,6 +123,7 @@ class ValidatorSet:
         self._index = {a: i for i, a in enumerate(addrs)}
         self._proposer: Optional[Validator] = None
         self._hash: Optional[bytes] = None
+        self._columns: Optional[_SetColumns] = None
         # NewValidatorSet parity (types/validator_set.go:33-48): a FRESH
         # set runs one accum increment, so the first proposer is the
         # highest-power validator, not the lowest address. Deserialized
@@ -107,6 +148,7 @@ class ValidatorSet:
         vs._index = self._index
         vs._proposer = self._proposer.copy() if self._proposer else None
         vs._hash = self._hash
+        vs._columns = self._columns
         return vs
 
     def total_voting_power(self) -> int:
@@ -166,6 +208,23 @@ class ValidatorSet:
             self._hash = merkle.root_host(leaves)
         return self._hash
 
+    def columns(self) -> _SetColumns:
+        """Keys and powers as columns; cached beside `_hash`, and for
+        its reason."""
+        if self._columns is None:
+            keys = [v.pubkey for v in self.validators]
+            powers = [v.voting_power for v in self.validators]
+            pk = None
+            if all(type(k) is bytes and len(k) == 32 for k in keys):
+                pk = np.frombuffer(b"".join(keys), np.uint8).reshape(-1, 32)
+            try:
+                vec = np.array(powers, np.int64)
+            except OverflowError:
+                vec = np.array(powers, object)
+            vec.flags.writeable = False
+            self._columns = _SetColumns(pk, vec, sum(powers))
+        return self._columns
+
     def to_obj(self):
         o = {"validators": [v.to_obj() for v in self.validators]}
         # The proposer is STATE, not derivable from accums: after an
@@ -197,85 +256,107 @@ class ValidatorSet:
 
     def commit_verification_items(self, chain_id: str, block_id,
                                   height: int, commit):
-        """Collect phase of verify_commit: structural checks + the
-        (pubkey, sign_bytes, sig) triples with per-item power metadata.
-        Split out so fast-sync can pool items from MANY blocks into one
-        device batch (blockchain/reactor.go:286's per-block loop becomes
-        one TPU dispatch per window)."""
-        if len(self.validators) != commit.size():
+        """Collect phase of verify_commit: structural checks, then the
+        commit's signatures as `(items, item_power)`. `items` is a batch
+        for BatchVerifier.verify_async: a SigColumns for an ed25519
+        set, else the list of (pubkey, sign_bytes, sig) triples;
+        `item_power` goes to check_commit_results with the verdicts.
+        Split out so fast-sync and the lite client pool the batches of
+        MANY blocks into one device dispatch (blockchain/reactor.go:286's
+        per-block loop becomes one TPU dispatch per window)."""
+        pcs = commit.precommits
+        if len(self.validators) != len(pcs):
             raise ValueError(
-                f"commit size {commit.size()} != valset size {len(self.validators)}")
+                f"commit size {len(pcs)} != valset size {len(self.validators)}")
         if height != commit.height():
             raise ValueError("commit height mismatch")
-
-        items = []
-        item_power = []
         round_ = commit.round()
-        # sign-bytes template per distinct block_id in this commit:
-        # within one commit the votes differ only in timestamp (and
-        # occasionally block_id for nil votes), so the canonical prefix/
-        # suffix around the timestamp is built once per block_id via the
-        # ONE layout definition (vote.sign_bytes_template) — pinned by
-        # test_commit_items_sign_bytes_match.
-        # Hot-path shape: locally-built commits share ONE BlockID
-        # object and one timestamp across all votes, so an identity
-        # check replaces the per-vote tuple-key memo almost always;
-        # wire-parsed commits (per-vote BlockID objects) fall back to
-        # the content-keyed memo.
-        from tendermint_tpu.types.vote import sign_bytes_template
-        tmpl: dict = {}
-        sb_memo: dict = {}
-        last_bid = last_sb = None
-        last_ts = None
-        last_for = False
-        validators = self.validators
-        append_item = items.append
-        append_power = item_power.append
-        for idx, pc in enumerate(commit.precommits):
+        precommit = VoteType.PRECOMMIT
+        # THE vote walk. Within one commit the votes differ in
+        # timestamp and, for nil votes, in block id: the sign-bytes are
+        # built once per run of votes that signed the same, around the
+        # ONE layout definition (vote.sign_bytes_template, once per
+        # distinct block id; pinned by test_commit_items_sign_bytes_
+        # match), and for_block is decided once per distinct block id.
+        # Locally built commits share one BlockID object; wire-parsed
+        # ones carry one per vote, compared by its fields.
+        sigs, absent = [], []
+        msgs, msg_at = [], []       # a run's sign-bytes, its first lane
+        flags, flag_at = [], []     # for_block of a run of one block id
+        known = {}          # block id fields -> (prefix, suffix, for_block)
+        bid = ts = bhash = ptotal = phash = pre = suf = None
+        for pc in pcs:
             if pc is None:
+                absent.append(len(sigs) + len(absent))
                 continue
-            if pc.type != VoteType.PRECOMMIT:
+            if pc.type != precommit:
                 raise ValueError("commit contains non-precommit")
             if pc.height != height or pc.round != round_:
                 raise ValueError("commit vote height/round mismatch")
-            val = validators[idx]
-            bid = pc.block_id
-            ts = pc.timestamp_ns
-            if bid is last_bid and ts == last_ts:
-                sb = last_sb
-            else:
-                tkey = (bid.hash, bid.parts.total, bid.parts.hash)
-                skey = (tkey, ts)
-                sb = sb_memo.get(skey)
-                if sb is None:
-                    t = tmpl.get(tkey)
+            b = pc.block_id
+            if b is not bid:
+                bid, parts = b, b.parts
+                if b.hash != bhash or parts.total != ptotal \
+                        or parts.hash != phash:
+                    bhash, ptotal, phash = key = \
+                        b.hash, parts.total, parts.hash
+                    t = known.get(key)
                     if t is None:
-                        t = sign_bytes_template(chain_id, bid, height,
-                                                round_, pc.type)
-                        tmpl[tkey] = t
-                    sb = (t[0] + str(ts) + t[1]).encode()
-                    sb_memo[skey] = sb
-                if bid is not last_bid:
-                    last_for = bid == block_id
-                last_bid, last_ts, last_sb = bid, ts, sb
-            append_item((val.pubkey, sb, pc.signature))
-            append_power((val.voting_power, last_for))
-        return items, item_power
+                        t = known[key] = sign_bytes_template(
+                            chain_id, b, height, round_, precommit) \
+                            + (b == block_id,)
+                    pre, suf, for_block = t
+                    flags.append(for_block)
+                    flag_at.append(len(sigs))
+                    ts = None       # other sign-bytes too
+            if pc.timestamp_ns != ts:
+                ts = pc.timestamp_ns
+                msgs.append((pre + str(ts) + suf).encode())
+                msg_at.append(len(sigs))
+            sigs.append(pc.signature)
+
+        n = len(sigs)
+        cols = self.columns()
+        idx = _lane_runs(msg_at, n)
+        if len(flags) == 1:
+            for_block = np.empty(n, np.bool_)
+            for_block.fill(flags[0])
+        else:
+            for_block = np.array(flags, np.bool_)[_lane_runs(flag_at, n)]
+        if absent:
+            rows = np.delete(np.arange(len(pcs)), absent)
+            powers = cols.powers[rows]
+        else:
+            rows, powers = slice(None), cols.powers
+        if not absent and flags == [True]:
+            tally = cols.total
+        else:
+            tally = sum(powers[for_block].tolist())
+        if cols.pk is not None:
+            items = SigColumns(cols.pk[rows], sigs, msgs, idx)
+        else:       # secp256k1 keys: host-verified, as triples
+            keys = [v.pubkey for v, pc in zip(self.validators, pcs)
+                    if pc is not None]
+            items = list(zip(keys, map(msgs.__getitem__, idx.tolist()),
+                             sigs))
+        return items, CommitPower(powers, for_block, tally)
 
     def check_commit_results(self, ok, item_power) -> None:
         """Judge phase of verify_commit: every signature valid and +2/3
-        power on the block. Raises ValueError on failure."""
-        power_for_block = 0
-        for valid, (power, for_block) in zip(ok, item_power):
-            if not valid:
-                raise ValueError("invalid signature in commit")
-            if for_block:
-                power_for_block += power
+        power on the block. `ok`: the verdicts of the commit's lanes, a
+        numpy array or a list. Raises ValueError on failure."""
+        lanes = len(item_power.powers)
+        if len(ok) > lanes or \
+                not (ok.all() if isinstance(ok, np.ndarray) else all(ok)):
+            raise ValueError("invalid signature in commit")
         # (votes for other/nil blocks count toward liveness but not quorum,
         # matching the reference's treatment of nil precommits in commits)
-        if not power_for_block * 3 > self.total_voting_power() * 2:
+        power_for_block = item_power.tally if len(ok) == lanes \
+            else item_power.tally_of(len(ok))
+        total = self.columns().total
+        if not power_for_block * 3 > total * 2:
             raise ValueError(
-                f"insufficient voting power: {power_for_block}/{self.total_voting_power()}")
+                f"insufficient voting power: {power_for_block}/{total}")
 
     def verify_commit_async(self, chain_id: str, block_id, height: int,
                             commit, verifier=None):

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import functools
+import json
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -23,6 +25,12 @@ def now_ns() -> int:
     return clock.now_ns()
 
 
+@functools.lru_cache(maxsize=64)
+def _json_string(s: str) -> str:
+    # a chain id, encoded once: a window of commits asks per block id
+    return json.dumps(s, ensure_ascii=False)
+
+
 def sign_bytes_template(chain_id: str, block_id, height: int, round_: int,
                         type_: int) -> tuple:
     """(prefix, suffix) strings around the timestamp of the canonical
@@ -30,8 +38,7 @@ def sign_bytes_template(chain_id: str, block_id, height: int, round_: int,
     layout. Vote.sign_bytes fills one timestamp; batch verifiers
     (ValidatorSet.commit_verification_items) reuse one template for a
     whole commit, whose votes differ only in timestamp per block_id."""
-    import json
-    cid = json.dumps(chain_id, ensure_ascii=False)
+    cid = _json_string(chain_id)
     return (
         f'{{"@chain_id":{cid},"@type":"vote",'
         f'"block_id":{{"hash":"{block_id.hash.hex()}",'

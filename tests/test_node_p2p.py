@@ -45,8 +45,12 @@ def test_two_full_nodes_reach_consensus_over_tcp(tmp_path):
         nodes[1].switch.dial_peer(nodes[0].switch.listen_address)
         assert wait_for(lambda: all(n.height >= 3 for n in nodes)), \
             [n.height for n in nodes]
-        assert nodes[0].consensus.state.last_block_id == \
-            nodes[1].consensus.state.last_block_id
+        # one node may be a height ahead the instant it is read: the
+        # two agree whenever they stand at the same height
+        assert wait_for(
+            lambda: nodes[0].consensus.state.last_block_id ==
+            nodes[1].consensus.state.last_block_id, timeout=20.0), \
+            [n.height for n in nodes]
     finally:
         for node in nodes:
             node.stop()
