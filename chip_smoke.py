@@ -6,14 +6,16 @@ paths once through the entry points a user calls, at upstream's own
 shapes (BASELINE.json `configs`; only chain length is cut, listed under
 each leg's `reduced`), on data made in-process from --seed:
 
-  A  config 3: a 10,000-validator commit through ValidatorSet.verify_commit
-     and the verifier a node gets (default_verifier()), three times
-     (first sighting -> cache fill -> cache hit), then the same lanes
-     with tampered and leniency-gap cases against the scalar host oracle.
+  A  one 8,192-lane chunk through default_verifier() whose lanes carry
+     the encodings RFC 8032 leaves open or OpenSSL is lenient on (s + L,
+     an off-curve key, y >= p, the small-order keys), lane by lane
+     against the scalar host oracle. The 10,000-validator commit itself
+     is the cell commit_10kv.verify_commit; these lanes are what its
+     `correct` does not send through the kernel.
   B  config 4: fast-sync of 64-validator x 5,000-tx blocks through
      BlockchainReactor at verify_window=256, app-hash chain and store
      compared with the builder's serial apply; a forged precommit stops
-     a second chain exactly below the forgery and punishes the peer.
+     a second sync exactly below the forgery and punishes the peer.
   C  config 5: a 4,096-header x 64-validator lite chain signed ON THE
      DEVICE (ops/ed25519.sign_batch, sample compared with OpenSSL) and
      certified by lite.certify_chain; a forged header is rejected at
@@ -23,6 +25,10 @@ each leg's `reduced`), on data made in-process from --seed:
      through JSONRPCClient; every write is read back from another node.
      Runs last, in a process that has imported JAX and used the device,
      which is what ops/merkle.py routes on.
+
+Legs B and C take their chains from the benchmark's builders
+(benchmark/chain.py) and leg B its reactor and instant peer from
+benchmark/drivers/sync.py: what starts here is what the cells measure.
 
 It refuses to start unless every device JAX reports is a TPU, fails if
 a native extension did not build from the sources in the tree, asserts
@@ -52,9 +58,8 @@ import sys
 import tempfile
 import time
 
-# upstream's shapes (BASELINE.json configs 3, 4, 5, 1); lengths cut
-A_VALIDATORS = 10_000
-B_VALIDATORS, B_TXS, B_WINDOW = 64, 5_000, 256
+# upstream's shapes (BASELINE.json configs 4, 5, 1); lengths cut
+B_VALIDATORS, B_TXS, B_TX_BYTES, B_WINDOW = 64, 5_000, 250, 256
 B_BLOCKS = 2 * B_WINDOW + 32      # two full windows + a bucket-2048 tail
 B_FORGED_BLOCKS, B_FORGED_AT = 32, 21
 C_HEADERS, C_VALIDATORS = 4_096, 64
@@ -183,27 +188,19 @@ def spec_merkle_root(items) -> bytes:
     return sha(b"\x02" + struct.pack("<Q", len(items)) + level[0]).digest()
 
 
-def _openssl_key(seed: bytes):
-    from cryptography.hazmat.primitives.asymmetric.ed25519 import \
-        Ed25519PrivateKey
-    return Ed25519PrivateKey.from_private_bytes(seed)
-
-
 # ---------------------------------------------------------------- leg A
 
-def tampered_lanes(items, rng) -> dict:
-    """lane -> (case, item): adversarial replacements spread over both
-    device chunks. The y >= p and x = 0 encodings are OpenSSL's
+def lenient_lanes(items, rng) -> dict:
+    """lane -> (case, item): the replacements no cell's `correct` sends
+    through the kernel. The y >= p and x = 0 encodings are OpenSSL's
     leniency gap (types/keys._noncanonical_point), which the scalar
     path routes to the RFC 8032 reference so that scalar and batch
     verdicts cannot split; the signatures crafted for them satisfy the
     verification equation for the identity point."""
-    from tendermint_tpu.models.verifier import BATCH_CHUNK
     from tendermint_tpu.ops.ed25519 import L_ORDER
     from tendermint_tpu.utils import ed25519_ref as ref
 
     p255 = (1 << 255) - 19
-    n = len(items)
 
     def small_order_sig():
         # R = s*B: satisfies s*B - h*A == R for A = the identity point,
@@ -223,13 +220,9 @@ def tampered_lanes(items, rng) -> dict:
 
     identity = (1).to_bytes(32, "little")
     cases = [
-        ("flipped_R", lambda p, m, s: (p, m, bytes([s[0] ^ 1]) + s[1:])),
         ("s_plus_L", lambda p, m, s: (p, m, s[:32] + (
             int.from_bytes(s[32:], "little") + L_ORDER).to_bytes(
                 32, "little"))),
-        ("wrong_message", lambda p, m, s: (p, m + b"x", s)),
-        ("flipped_s_bit", lambda p, m, s: (p, m, s[:32] + bytes(
-            [s[32] ^ 1]) + s[33:])),
         ("off_curve_pubkey", lambda p, m, s: (off_curve_pubkey(), m, s)),
         ("noncanonical_R_y_ge_p", lambda p, m, s: (
             p, m, (p255 + 1).to_bytes(32, "little") + s[32:])),
@@ -243,70 +236,33 @@ def tampered_lanes(items, rng) -> dict:
             with_sign((p255 - 1).to_bytes(32, "little")), m,
             small_order_sig())),
     ]
-    split = min(BATCH_CHUNK, n // 2)
-    lanes = sorted(rng.sample(range(split), len(cases)) +
-                   rng.sample(range(split, n), len(cases)))
+    lanes = sorted(rng.sample(range(len(items)), 2 * len(cases)))
     return {lane: (cases[i % len(cases)][0],
                    cases[i % len(cases)][1](*items[lane]))
             for i, lane in enumerate(lanes)}
 
 
 def leg_a(seed: int) -> dict:
+    from benchmark.chain import LiteChain
     from tendermint_tpu.models.verifier import BATCH_CHUNK, default_verifier
-    from tendermint_tpu.ops import ed25519
-    from tendermint_tpu.types import Validator, ValidatorSet
-    from tendermint_tpu.types.block import BlockID, Commit, PartSetHeader
     from tendermint_tpu.types.keys import verify_any
-    from tendermint_tpu.types.vote import Vote, VoteType
 
     rng = random.Random(f"{seed}/A")
-    chain_id, height = f"smoke-commit-{seed}", 7
-    t0 = time.perf_counter()
-    signers = {}
-    for _ in range(A_VALIDATORS):
-        sk = _openssl_key(rng.randbytes(32))
-        signers[sk.public_key().public_bytes_raw()] = sk
-    valset = ValidatorSet([Validator(pk, 10) for pk in signers])
-    bid = BlockID(hashlib.sha256(chain_id.encode()).digest(),
-                  PartSetHeader(1, hashlib.sha256(b"parts").digest()))
-    precommits = []
-    for idx, val in enumerate(valset.validators):
-        v = Vote(val.address, idx, height, 0, 1_000 + idx,
-                 VoteType.PRECOMMIT, bid)
-        v.signature = signers[val.pubkey].sign(v.sign_bytes(chain_id))
-        precommits.append(v)
-    commit = Commit(bid, precommits)
-    build_s = time.perf_counter() - t0
+    # one full chunk of genuine triples, signed on the host
+    chain = LiteChain(seed, BATCH_CHUNK // C_VALIDATORS, C_VALIDATORS,
+                      sign="host")
+    items = [(pub, msg, chain.sigs[i * C_VALIDATORS + j])
+             for i, msg in enumerate(chain.msgs)
+             for j, pub in enumerate(chain.pubkeys)]
+    lanes = lenient_lanes(items, rng)
+    for lane, (_case, item) in lanes.items():
+        items[lane] = item
 
     verifier = default_verifier()
     stats0 = dict(verifier.stats)
-    n_chunks = -(-A_VALIDATORS // BATCH_CHUNK)
-    outcomes, pass_s = [], []
-    prev = ed25519.predecomp_stats()
-    for _ in range(3):
-        t0 = time.perf_counter()
-        valset.verify_commit(chain_id, bid, height, commit,
-                             verifier=verifier)
-        pass_s.append(round(time.perf_counter() - t0, 3))
-        now = ed25519.predecomp_stats()
-        outcomes.append({o: now[o] - prev[o]
-                         for o in ("full", "fill", "hit")})
-        prev = now
-    want = [{"full": n_chunks, "fill": 0, "hit": 0},
-            {"full": 0, "fill": n_chunks, "hit": 0},
-            {"full": 0, "fill": 0, "hit": n_chunks}]
-    require(outcomes == want,
-            f"predecomp cache: want full->fill->hit {want}, got {outcomes}")
-
-    items, _power = valset.commit_verification_items(
-        chain_id, bid, height, commit)
-    items = list(items)     # triples, to assign the tampered lanes into
-    lanes = tampered_lanes(items, rng)
-    for lane, (_case, item) in lanes.items():
-        items[lane] = item
     t0 = time.perf_counter()
     got = verifier.verify(items)
-    tamper_s = time.perf_counter() - t0
+    verify_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     oracle = [verify_any(*it) for it in items]
     oracle_s = time.perf_counter() - t0
@@ -319,65 +275,62 @@ def leg_a(seed: int) -> dict:
     # what RFC 8032 settles is held to it; on the encodings it leaves
     # open (y >= p, small-order keys) the oracle's verdict is the rule,
     # and the lane-by-lane comparison above is the check
-    for case in ("flipped_R", "s_plus_L", "wrong_message", "flipped_s_bit",
-                 "off_curve_pubkey", "identity_pubkey_x0_sign_bit",
+    for case in ("s_plus_L", "off_curve_pubkey",
+                 "identity_pubkey_x0_sign_bit",
                  "minus_identity_x0_sign_bit"):
         require(not any(by_case[case]), f"{case} accepted: {by_case}")
     require(all(oracle[i] for i in range(len(items)) if i not in lanes),
             "untampered lane rejected")
 
     stats = delta(dict(verifier.stats), stats0)
-    require(stats["sigs"] == stats["jax_sigs"] == 4 * A_VALIDATORS,
+    require(stats["sigs"] == stats["jax_sigs"] == len(items) == BATCH_CHUNK,
             f"leg A signatures not all on the device: {stats}")
     return {
-        "config": "BASELINE.json configs[2]: 10000-validator VerifyCommit",
-        "validators": A_VALIDATORS, "reduced": {},
-        "chunks": [min(BATCH_CHUNK, A_VALIDATORS - lo)
-                   for lo in range(0, A_VALIDATORS, BATCH_CHUNK)],
+        "config": "one verifier chunk of lenient and non-canonical lanes "
+                  "(the commit itself: cell commit_10kv.verify_commit)",
+        "lanes": len(items), "reduced": {},
         "verifier": {"backend": verifier.backend,
                      "mesh_devices": verifier.mesh_devices,
                      "stats_delta": stats},
-        "predecomp_per_pass": outcomes,
         "tampered_lanes": {str(k): v[0] for k, v in lanes.items()},
         "tampered_verdicts_by_case": by_case,
         "oracle": "types/keys.verify_any (OpenSSL + RFC 8032 reference)",
-        "smoke_seconds": {"build": round(build_s, 2),
-                          "verify_commit_passes_incl_compiles": pass_s,
-                          "tampered_verify": round(tamper_s, 3),
+        "smoke_seconds": {"verify_incl_compiles": round(verify_s, 3),
                           "scalar_oracle": round(oracle_s, 2)},
     }
 
 
 # ---------------------------------------------------------------- leg B
 
-class _PunishedPeers:
-    """The slice of a p2p Switch the fast-sync reactor punishes through
-    (blockchain/reactor._stop_peer)."""
+def sync_chains(seed: int, n_vals: int, n_txs: int, n_blocks: int,
+                forged_blocks: int, forged_at: int):
+    """(genesis, wire, expect, forged wire): `n_blocks` blocks and the
+    sentinel that lends its LastCommit, each with its (hash, header app
+    hash); and the first `forged_blocks` + 1 of them again with one
+    forged precommit in the commit FOR block `forged_at`."""
+    from benchmark.chain import ChainBuilder, forge_precommit
 
-    def __init__(self):
-        self.peers = self
-        self.stopped = []
-
-    def get(self, peer_id):
-        return peer_id
-
-    def stop_peer_for_error(self, peer, err):
-        self.stopped.append((peer, str(err)))
+    builder = ChainBuilder(seed, n_vals, n_txs, B_TX_BYTES)
+    wire, expect = builder.build_wire(n_blocks)
+    sentinel, after = builder.build_wire(1, with_txs=False)
+    fwire = wire[:forged_blocks + 1]
+    fwire[forged_at] = forge_precommit(fwire[forged_at], seed)
+    return builder.gen, wire + sentinel, expect + after, fwire
 
 
 def leg_b(seed: int) -> dict:
-    import bench_fastsync
+    from benchmark.drivers.sync import PEER_ID, drive, fresh_reactor
+    from benchmark.spans import SpanLog
     from tendermint_tpu.models.verifier import BatchVerifier
 
     t0 = time.perf_counter()
-    builder = bench_fastsync.ChainBuilder(
-        B_VALIDATORS, B_TXS, chain_id=f"smoke-sync-{seed}")
-    blocks = builder.build(B_BLOCKS + 1)    # + the sentinel's LastCommit
+    gen, wire, expect, fwire = sync_chains(
+        seed, B_VALIDATORS, B_TXS, B_BLOCKS, B_FORGED_BLOCKS, B_FORGED_AT)
     build_s = time.perf_counter() - t0
 
     verifier = BatchVerifier("auto")
-    reactor = bench_fastsync.sync_reactor(builder.gen, verifier, B_WINDOW)
-    sync_s = bench_fastsync.drive_sync(reactor, blocks)
+    reactor = fresh_reactor(gen, verifier, B_WINDOW)
+    sync_s = drive(reactor, wire, SpanLog())
     reactor.stop()
     n_sigs = B_BLOCKS * B_VALIDATORS
     require(reactor.state.last_block_height == B_BLOCKS ==
@@ -386,42 +339,35 @@ def leg_b(seed: int) -> dict:
             f"{reactor.block_store.height()}, want {B_BLOCKS}")
     # the builder's serial apply: block h+1's header carries the app
     # hash after block h, and every stored block is the builder's own
-    require(reactor.state.app_hash == blocks[B_BLOCKS].header.app_hash,
+    require(reactor.state.app_hash == expect[B_BLOCKS][1],
             "final app hash differs from the builder's serial apply")
-    for blk in blocks[:B_BLOCKS]:
-        meta = reactor.block_store.load_block_meta(blk.header.height)
-        require(meta.block_id.hash == blk.hash() and
-                meta.header.app_hash == blk.header.app_hash,
-                f"stored block {blk.header.height} differs")
+    for h, (block_hash, app_hash) in enumerate(expect[:B_BLOCKS], 1):
+        meta = reactor.block_store.load_block_meta(h)
+        require(meta.block_id.hash == block_hash and
+                meta.header.app_hash == app_hash,
+                f"stored block {h} differs")
     stats = dict(verifier.stats)
     require(stats["jax_sigs"] == stats["sigs"] == n_sigs,
             f"commit signatures {n_sigs}, verifier saw {stats}")
 
-    # a second, short chain from the same validators with one forged
-    # precommit in the commit FOR block B_FORGED_AT
-    forged = bench_fastsync.ChainBuilder(
-        B_VALIDATORS, B_TXS, chain_id=f"smoke-forged-{seed}")
-    fblocks = forged.build(B_FORGED_BLOCKS + 1)
-    vote = fblocks[B_FORGED_AT].last_commit.precommits[seed % B_VALIDATORS]
-    vote.signature = vote.signature[:40] + bytes(
-        [vote.signature[40] ^ 1]) + vote.signature[41:]
+    # the chain's first blocks again, one precommit of the commit FOR
+    # block B_FORGED_AT forged
     fverifier = BatchVerifier("auto")
-    freactor = bench_fastsync.sync_reactor(forged.gen, fverifier, B_WINDOW)
-    freactor.switch = _PunishedPeers()
-    bench_fastsync.drive_sync(freactor, fblocks)
+    freactor = fresh_reactor(gen, fverifier, B_WINDOW)
+    drive(freactor, fwire, SpanLog())
     freactor.stop()
     require(freactor.state.last_block_height == B_FORGED_AT - 1 ==
             freactor.block_store.height(),
             f"forged commit for block {B_FORGED_AT}: applied up to "
             f"{freactor.state.last_block_height}")
-    require({p for p, _ in freactor.switch.stopped} ==
-            {bench_fastsync.PEER_ID} and
-            bench_fastsync.PEER_ID not in freactor.pool.peers,
+    require({p for p, _ in freactor.switch.stopped} == {PEER_ID} and
+            PEER_ID not in freactor.pool.peers,
             f"serving peer not punished: {freactor.switch.stopped}")
     return {
         "config": "BASELINE.json configs[3]: fast-sync replay, "
                   "64 validators, 5000-tx blocks",
         "validators": B_VALIDATORS, "txs_per_block": B_TXS,
+        "tx_bytes": B_TX_BYTES,
         "verify_window": B_WINDOW, "blocks": B_BLOCKS,
         "reduced": {"blocks": f"{B_BLOCKS} of upstream's 50000"},
         "commit_signatures": n_sigs,
@@ -441,98 +387,52 @@ def leg_b(seed: int) -> dict:
 
 # ---------------------------------------------------------------- leg C
 
-def lite_chain(seed: int, n_headers: int):
-    """([FullCommit], valset, seeds by validator index): one constant
-    validator set, every precommit signed by ops/ed25519.sign_batch."""
-    from tendermint_tpu.lite.types import FullCommit, SignedHeader
-    from tendermint_tpu.ops import ed25519
-    from tendermint_tpu.types.block import (BlockID, Commit, Header,
-                                            PartSetHeader)
-    from tendermint_tpu.types.validator_set import Validator, ValidatorSet
-    from tendermint_tpu.types.vote import Vote, VoteType
+def lite_chains(seed: int, n_headers: int, n_vals: int, forged_headers: int,
+                forged_at: int, sign: str = "device"):
+    """(chain, forged wire): the benchmark's LiteChain, every precommit
+    signed by ops/ed25519.sign_batch; and its first `forged_headers`
+    signed headers again with a header nobody signed at `forged_at`."""
+    from benchmark.chain import LiteChain
 
-    rng = random.Random(f"{seed}/C")
-    chain_id = f"smoke-lite-{seed}"
-    seed_of = {}
-    for _ in range(C_VALIDATORS):
-        s = rng.randbytes(32)
-        seed_of[_openssl_key(s).public_key().public_bytes_raw()] = s
-    valset = ValidatorSet([Validator(pk, 10) for pk in seed_of])
-    vals = valset.validators
-    seeds = [seed_of[v.pubkey] for v in vals]
-    vhash = valset.hash()
-    parts = PartSetHeader(1, hashlib.sha256(b"lite-parts").digest())
-    headers, bids, msgs = [], [], []
-    for h in range(1, n_headers + 1):
-        header = Header(chain_id=chain_id, height=h, time_ns=h,
-                        validators_hash=vhash,
-                        app_hash=h.to_bytes(32, "big"))
-        bid = BlockID(header.hash(), parts)
-        headers.append(header)
-        bids.append(bid)
-        # v0.16 sign bytes carry no validator identity and the votes
-        # share one timestamp: every validator signs the same bytes
-        msgs.append(Vote(vals[0].address, 0, h, 0, h, VoteType.PRECOMMIT,
-                         bid).sign_bytes(chain_id))
-    sigs = ed25519.sign_batch(
-        [s for _ in range(n_headers) for s in seeds],
-        [m for m in msgs for _ in range(C_VALIDATORS)])
-    fcs = []
-    for i, h in enumerate(range(1, n_headers + 1)):
-        precommits = []
-        for j, val in enumerate(vals):
-            v = Vote(val.address, j, h, 0, h, VoteType.PRECOMMIT, bids[i])
-            v.signature = sigs[i * C_VALIDATORS + j]
-            precommits.append(v)
-        fcs.append(FullCommit(
-            SignedHeader(headers[i], Commit(bids[i], precommits), bids[i]),
-            valset))
-    return chain_id, fcs, valset, seeds, msgs, sigs
+    chain = LiteChain(seed, n_headers, n_vals, sign=sign)
+    fwire = chain.wire[:forged_headers]
+    fwire[forged_at - 1] = chain.forged_header(forged_at)
+    return chain, fwire
 
 
 def leg_c(seed: int) -> dict:
+    from benchmark.kvref import openssl_signer
     from tendermint_tpu.lite.certifier import (CertificationError,
                                                certify_chain,
                                                default_window)
-    from tendermint_tpu.lite.types import FullCommit, SignedHeader
     from tendermint_tpu.models.verifier import default_verifier
-    from tendermint_tpu.types.block import BlockID, Commit, Header
-    from tendermint_tpu.types.vote import Vote
 
     t0 = time.perf_counter()
-    chain_id, fcs, valset, seeds, msgs, sigs = lite_chain(seed, C_HEADERS)
+    chain, fwire = lite_chains(seed, C_HEADERS, C_VALIDATORS,
+                               C_FORGED_HEADERS, C_FORGED_AT)
     build_s = time.perf_counter() - t0
+    # a sample of what _sign_kernel made, against OpenSSL's
     n_sigs = C_HEADERS * C_VALIDATORS
     sample = range(0, n_sigs, n_sigs // 256)
-    keys = [_openssl_key(s) for s in seeds]
-    bad = [i for i in sample
-           if sigs[i] != keys[i % C_VALIDATORS].sign(msgs[i // C_VALIDATORS])]
+    keys = [openssl_signer(s) for s in chain.seeds]
+    bad = [i for i in sample if chain.sigs[i] !=
+           keys[i % C_VALIDATORS].sign(chain.msgs[i // C_VALIDATORS])]
     require(not bad, f"device signatures differ from OpenSSL at {bad}")
 
     verifier = default_verifier()
     stats0 = dict(verifier.stats)
+    valset, fcs = chain.decode()
     t0 = time.perf_counter()
-    certify_chain(chain_id, fcs, trusted=valset)
+    certify_chain(chain.chain_id, fcs, trusted=valset)
     certify_s = time.perf_counter() - t0
     stats = delta(dict(verifier.stats), stats0)
     require(stats["sigs"] == stats["jax_sigs"] == n_sigs,
             f"lite chain signatures not all on the device: {stats}")
 
     # a header nobody signed, dressed in the genuine commit's signatures
-    real = fcs[C_FORGED_AT - 1].signed_header
-    header = Header(chain_id=chain_id, height=C_FORGED_AT,
-                    time_ns=C_FORGED_AT,
-                    validators_hash=real.header.validators_hash,
-                    app_hash=b"\xff" * 32)
-    bid = BlockID(header.hash(), real.block_id.parts)
-    votes = [Vote(v.validator_address, v.validator_index, v.height, v.round,
-                  v.timestamp_ns, v.type, bid, v.signature)
-             for v in real.commit.precommits]
-    forged = list(fcs[:C_FORGED_HEADERS])
-    forged[C_FORGED_AT - 1] = FullCommit(
-        SignedHeader(header, Commit(bid, votes), bid), valset)
+    valset, forged = chain.decode(fwire)
     try:
-        certify_chain(chain_id, forged, trusted=valset)
+        certify_chain(chain.chain_id, forged, trusted=valset)
     except CertificationError as e:
         rejected = str(e)
     else:

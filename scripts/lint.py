@@ -80,7 +80,7 @@ def main(argv=None) -> int:
                          "pragmas than this (default 15)")
     ap.add_argument("paths", nargs="*",
                     help="scan set override (default: the package, "
-                         "scripts/, bench*.py, benchmarks/, "
+                         "scripts/, benchmark/, bench_util.py, "
                          "chip_smoke.py)")
     args = ap.parse_args(argv)
 

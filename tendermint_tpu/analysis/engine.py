@@ -34,9 +34,8 @@ PRAGMA_RE = re.compile(
 GUARDED_RE = re.compile(r"#:\s*guarded_by\s+([A-Za-z_]\w*)")
 
 #: the default scan set, relative to the repo root
-DEFAULT_SCAN = ("tendermint_tpu", "scripts", "benchmarks",
-                "bench.py", "bench_lite.py", "bench_util.py",
-                "bench_fastsync.py", "bench_testnet.py", "chip_smoke.py")
+DEFAULT_SCAN = ("tendermint_tpu", "scripts", "benchmark", "bench_util.py",
+                "chip_smoke.py")
 
 
 @dataclass

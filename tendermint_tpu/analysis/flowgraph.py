@@ -111,7 +111,7 @@ class ModuleInfo:
 
 def module_qname(rel: str) -> str:
     """Repo-relative path -> dotted module name (`scripts/lint.py` ->
-    `scripts.lint`, `bench.py` -> `bench`)."""
+    `scripts.lint`, `chip_smoke.py` -> `chip_smoke`)."""
     rel = rel.replace("\\", "/")
     if rel.endswith(".py"):
         rel = rel[:-3]
@@ -456,6 +456,4 @@ def _base_name(expr: ast.AST) -> str:
 
 
 def _is_project(dotted: str) -> bool:
-    head = dotted.split(".")[0]
-    return head in ("tendermint_tpu", "scripts", "benchmarks") or \
-        head.startswith("bench")
+    return dotted.split(".")[0] in {module_qname(p) for p in DEFAULT_SCAN}

@@ -17,8 +17,8 @@ This package is that subsystem:
                    EventBus, asserts agreement/validity/evidence-
                    capture/liveness, dumps replayable violation traces.
   chaos.runner     ChaosNet — in-process N-validator testnet under the
-                   schedule; run_chaos() returns the report bench.py
-                   --chaos-json commits as BENCH_chaos.json.
+                   schedule; run_chaos() returns the run's report
+                   (docs/robustness.md, "The report").
 
 This module holds the knobs + telemetry so the socket path stays
 import-light. Resolution order mirrors burst.py: TM_TPU_CHAOS env wins,
@@ -97,7 +97,7 @@ def parse_spec(s: str) -> dict:
 def resolve() -> tuple[bool, dict, int]:
     """-> (enabled, link_spec, seed). Env TM_TPU_CHAOS wins over the
     configured mode; 'off'/'' disables. Read per call so subprocess
-    harnesses (bench_testnet.run_socket) flip it via child env."""
+    harnesses (serving/deploy.py) flip it via child env."""
     mode = _cfg_mode
     env = knobs.knob_spec("TM_TPU_CHAOS")
     if env:

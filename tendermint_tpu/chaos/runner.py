@@ -21,9 +21,9 @@ per height, and a node behind the committed frontier gets its next
 height's archive re-delivered (votes first, then proposal/parts — the
 same order reactor catch-up produces commits in).
 
-run_chaos() is the entry bench.py --chaos-json and the chaos tests
-share; ACCEPTANCE_SPEC is the full scenario the BENCH_chaos.json
-artifact commits (drop/delay/duplicate/reorder + partition&heal +
+run_chaos() is the entry the chaos tests share; ACCEPTANCE_SPEC is
+the full acceptance scenario of tests/test_chaos.py
+(drop/delay/duplicate/reorder + partition&heal +
 crash-restart + equivocator + clock skew).
 """
 
@@ -84,7 +84,7 @@ SMOKE_SPEC = {
 
 def scale_spec(n: int, full_churn: bool = True) -> dict:
     """The validator-scale adversarial scenario for an n-node ChaosNet
-    (BENCH_chaos.json's scaling curve + the slow acceptance tests):
+    (the slow acceptance tests of tests/test_chaos_scale.py):
     light link faults + the wan3 geo profile + valset churn through
     real EndBlock deltas + one crash-restart. `full_churn=False` trims
     the churn cycle to join+leave (the 128-validator point, where every
@@ -759,7 +759,7 @@ def run_chaos(spec: Optional[dict] = None, seed: int = 42,
               trace_path: Optional[str] = None, lite: bool = True,
               settle_steps: int = 60) -> dict:
     """One seeded chaos run end to end; returns the monitor report
-    (plus fault counts). Used by bench.py --chaos-json and the tests.
+    (plus fault counts).
     On any violation a replayable trace is dumped next to the workdir
     (or at `trace_path`)."""
     import shutil

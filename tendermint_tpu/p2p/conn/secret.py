@@ -65,7 +65,7 @@ _m_open = telemetry.histogram(
     "p2p_open_seconds", "AEAD open wall time per call (burst = 1 call)",
     buckets=_AEAD_BUCKETS)
 # Frames under the calls above: seal µs/frame = seal_seconds_sum /
-# frames_sealed_total (what bench.py --p2p-json reports per arm).
+# frames_sealed_total.
 _m_sealed = telemetry.counter(
     "p2p_frames_sealed_total", "Frames sealed (all paths)")
 _m_opened = telemetry.counter(

@@ -87,8 +87,10 @@ class PEXReactor(Reactor):
         if t == "pex_request":
             # rate limit: one request per period/3 per peer (:193-217)
             now = time.monotonic()
-            last = self._last_received.get(peer.id, 0.0)
-            if now - last < self.period / 3:
+            # None = never asked: the clock's zero is the host's boot,
+            # not "long ago"
+            last = self._last_received.get(peer.id)
+            if last is not None and now - last < self.period / 3:
                 self.switch.stop_peer_for_error(
                     peer, ValueError("pex request flood"))
                 return

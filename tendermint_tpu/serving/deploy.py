@@ -1,7 +1,7 @@
 """Deployment driver (ISSUE 19 tentpole a).
 
-Generalizes the bench_testnet spawn/patch/supervise/teardown pattern
-into a reusable object: materialize a ``Topology`` into per-node
+The spawn/patch/supervise/teardown of a multi-process net as one
+reusable object: materialize a ``Topology`` into per-node
 homes, spawn one OS process per node, supervise them (a crash during
 the run is RESTARTED with the same argv, up to ``max_restarts`` per
 process — the edge tier's processes are cattle), optionally shape the
@@ -25,6 +25,7 @@ from typing import Dict, List, Optional
 
 from tendermint_tpu import telemetry
 from tendermint_tpu.serving.topology import ProcSpec, Topology, materialize
+from tendermint_tpu.utils.procs import free_port_block, node_child_env
 
 _m_restarts = telemetry.counter(
     "deploy_restarts_total",
@@ -46,7 +47,6 @@ class Deployment:
                  child_env: Optional[dict] = None,
                  kind_env: Optional[Dict[str, dict]] = None,
                  max_restarts: int = 3):
-        from bench_util import free_port_block, node_child_env
         repo = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         if topo.base_port <= 0:
@@ -118,7 +118,7 @@ class Deployment:
                 f"{keys[j].id()}@127.0.0.1:{self._proxy.ports[(i, j)]}"
                 for j in range(n) if j != i)
             # PEX would learn the direct addresses and route around
-            # the proxy — the same rule bench_testnet applies
+            # the proxy
             cfg["p2p"]["pex"] = False
             json.dump(cfg, open(cfg_path, "w"))
         self._proxy.start()

@@ -15,7 +15,7 @@ runs with. Two kinds:
   chains behind one front door) — the sharded front-door shape the
   load harness sweeps.
 
-Ports follow the bench_testnet convention: process k gets
+Ports follow one convention: process k gets
 (base+2k, base+2k+1) as (p2p, rpc) so harnesses can derive every
 address from the base alone.
 """
@@ -30,7 +30,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 #: consensus timeouts for 1-core CI hosts (the e2e-test profile —
-#: bench_testnet.py and tests/test_e2e_testnet.py use these numbers)
+#: tests/test_e2e_testnet.py uses these numbers)
 FAST_TIMEOUTS = {
     "timeout_propose": 400, "timeout_propose_delta": 100,
     "timeout_prevote": 200, "timeout_prevote_delta": 100,
@@ -46,7 +46,7 @@ class Topology:
     n_replicas: int = 0
     n_shards: int = 2               # shardset kind only
     chain_id: str = "serving-net"
-    base_port: int = 0              # 0 = caller allocates via bench_util
+    base_port: int = 0              # 0 = caller allocates (utils/procs)
     wire: Optional[dict] = None     # WireProxy fault spec between vals
     wire_seed: int = 0
     fast_timeouts: bool = True
@@ -112,7 +112,7 @@ def _patch_consensus(home: str, timeouts: dict) -> None:
 def materialize(topo: Topology, out: str) -> List[ProcSpec]:
     """Write the file tree for `topo` under `out` and return the
     process specs to spawn. `topo.base_port` must be set (a free
-    block of 2 * n_processes ports — bench_util.free_port_block)."""
+    block of 2 * n_processes ports — utils/procs.free_port_block)."""
     base = topo.base_port
     if base <= 0:
         raise ValueError("materialize needs topo.base_port set")

@@ -1,5 +1,5 @@
 """Shard plane — N independent chains in one process, one shared
-verifier, one front door (ISSUE 15 / ROADMAP item 3).
+verifier, one front door.
 
 Millions of users do not fit through one totally-ordered log; the
 production answer is horizontal sharding. This package runs N
@@ -22,10 +22,10 @@ inside one process:
                    plus a ``ContinuousCertifier``-backed commit proof,
                    so cross-shard reads are certified, not trusted.
 
-The paper's thesis (batch-crypto amortization) predicts the scaling
-property ``bench.py --shard-json`` measures: concurrent sub-threshold
+The paper's thesis (batch-crypto amortization) predicts a scaling
+property: concurrent sub-threshold
 verifies from many chains merge into bigger device batches, so the
-coalesce factor RISES with shard count (BENCH_shard.json).
+coalesce factor should rise with shard count (not measured on the chip).
 
 Knob: ``TM_TPU_SHARDS`` (> ``config.base.shards`` > 0) sets the default
 shard count a ``ShardSet(n_shards=None)`` assembles; 0 keeps the

@@ -14,7 +14,7 @@ One rule, applied by every entry point that may compile for a chip
   "machine type doesn't match ... could lead to SIGILL" warning, and the
   tier-1 suite compiles each CPU shape once per process anyway. Tests
   and CPU child processes strip the variable (tests/conftest.py,
-  bench_util.node_child_env).
+  utils/procs.node_child_env).
 """
 
 from __future__ import annotations

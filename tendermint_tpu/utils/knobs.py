@@ -58,8 +58,8 @@ CATALOG: tuple[Knob, ...] = (
          "models/verifier.py, ops/merkle.py"),
     Knob("TM_TPU_MESH_FORCE_HOST_DEVICES", "int", "0 (off)", "",
          "Force N virtual XLA host (CPU) devices before jax init — "
-         "the bench/CI arm for multi-device runs on few-core hosts.",
-         "bench.py"),
+         "the tests' arm for multi-device runs on few-core hosts.",
+         "tests/conftest.py"),
     Knob("TM_TPU_AUTO_THRESHOLD", "int", "128", "",
          "Batches at or below this size verify scalar on host.",
          "models/verifier.py"),

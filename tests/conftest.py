@@ -4,8 +4,8 @@ Tests must not depend on real TPU hardware; multi-chip sharding paths
 are exercised on a virtual CPU mesh exactly as the driver's dryrun does.
 This is also the CI multi-device story (ISSUE 6): every tier-1 run gets
 `--xla_force_host_platform_device_count=8` (override via
-TM_TPU_MESH_FORCE_HOST_DEVICES, the same knob bench.py's mesh arms
-use), so the shard_map/NamedSharding code paths run on 1-core hosts on
+TM_TPU_MESH_FORCE_HOST_DEVICES, which only this file
+reads), so the shard_map/NamedSharding code paths run on 1-core hosts on
 every push — 8 covers the 2- and 4-wide sub-meshes the mesh tests also
 exercise. Only tests that explicitly build a mesh pay a sharded
 compile; TM_TPU_MESH defaults to "off" below so nothing else does.

@@ -251,7 +251,7 @@ def test_relay_gossip_dedup_skips_redundant_deliveries():
 
 @pytest.mark.slow
 def test_chaos_acceptance_scenario():
-    """The BENCH_chaos.json scenario: drop/delay/duplicate/reorder,
+    """ACCEPTANCE_SPEC, the full scenario: drop/delay/duplicate/reorder,
     partition + heal, crash-restart, equivocating validator, clock
     skew — zero violations, every injected double-sign committed."""
     from tendermint_tpu.chaos.runner import run_chaos

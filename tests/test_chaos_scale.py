@@ -54,6 +54,8 @@ def test_churn_geo_smoke_certifies_every_height():
                   max_steps=600, settle_steps=20)
     assert r["violations"] == []
     assert r["n_genesis_validators"] == 4
+    # the chain kept committing while the set rotated under it
+    assert r["max_height"] >= 6
     churn = r["churn"]
     assert churn["churn_join"] >= 1
     assert churn["churn_leave"] >= 1
@@ -69,21 +71,6 @@ def test_churn_geo_smoke_certifies_every_height():
     f = r["faults_injected"]
     assert f.get("geo_drop", 0) + f.get("geo_throttle", 0) >= 1
     assert r["fault_log_sha256"]
-
-
-def test_bench_testnet_churn_rotates_valset():
-    """bench_testnet's in-process engine under churn (tier-1 smoke):
-    valset rotation flows through EndBlock while blocks keep
-    committing; the final set differs from genesis."""
-    import bench_testnet
-    r = bench_testnet.run(n_blocks=8, n_vals=4, n_txs=5, churn_every=2)
-    assert r["blocks"] >= 8
-    churn = r["churn"]
-    # a full join -> stake -> leave cycle ran (the set may legally be
-    # back at genesis power by the end — the change HEIGHT is the
-    # evidence the deltas flowed through EndBlock mid-run)
-    assert churn["ops_injected"] >= 3
-    assert churn["last_height_validators_changed"] > 2
 
 
 def test_monitor_lite_flags_uncertifiable_commit():

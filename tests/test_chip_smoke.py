@@ -1,9 +1,11 @@
 """The chip smoke's CPU-checkable contract (chip_smoke.py itself only
 runs on a TPU): it refuses a CPU before building anything, the compile
 cache goes where utils/compile_cache says, device dispatches are
-counted by kernel, and a native extension is rebuilt when its sources
-change. No kernel is compiled here."""
+counted by kernel, a native extension is rebuilt when its sources
+change, and legs B and C build their chains with the benchmark's
+builders. No kernel is compiled here."""
 
+import ast
 import ctypes
 import inspect
 import os
@@ -26,6 +28,62 @@ def test_smoke_refuses_cpu_before_building_anything():
     assert proc.stdout == ""            # no result line, no leg output
     assert "needs a TPU" in proc.stderr and "cpu" in proc.stderr
     assert "Nothing was built or run" in proc.stderr
+
+
+def test_smoke_builds_its_chains_with_the_benchmarks_builders():
+    """chip_smoke.py keeps no chain builder of its own: legs B and C take
+    their chains from benchmark/chain.py through the two helpers the legs
+    call, and each forgery sits where its leg looks for the refusal. Two
+    validators, three heights, the verifier on the host."""
+    import chip_smoke
+    from benchmark.drivers.sync import PEER_ID, drive, fresh_reactor
+    from benchmark.spans import SpanLog
+    from tendermint_tpu.lite.certifier import (CertificationError,
+                                               certify_chain)
+    from tendermint_tpu.models.verifier import BatchVerifier
+    from tendermint_tpu.types.block import Block
+
+    assert not hasattr(chip_smoke, "lite_chain")
+    imported = {node.module for node in ast.walk(ast.parse(
+        inspect.getsource(chip_smoke))) if isinstance(node, ast.ImportFrom)}
+    assert {"benchmark.chain", "benchmark.drivers.sync"} <= imported
+    beside = {name[:-3] for name in os.listdir(REPO) if name.endswith(".py")}
+    assert not {m.split(".")[0] for m in imported} & beside
+
+    # leg B: 3 blocks and the sentinel; the commit FOR block 2 forged
+    gen, wire, expect, fwire = chip_smoke.sync_chains(
+        5, n_vals=2, n_txs=3, n_blocks=3, forged_blocks=2, forged_at=2)
+    assert (len(wire), len(expect), len(fwire)) == (4, 4, 3)
+    assert fwire[:2] == wire[:2] and fwire[2] != wire[2]
+    genuine = Block.from_bytes(wire[2]).last_commit.precommits
+    forged = Block.from_bytes(fwire[2]).last_commit.precommits
+    differ = [i for i, (a, b) in enumerate(zip(genuine, forged))
+              if a.signature != b.signature]
+    assert differ == [5 % 2]
+    assert forged[differ[0]].height == 2
+    for chain, applied in ((wire, 3), (fwire, 1)):
+        reactor = fresh_reactor(gen, BatchVerifier("python"), 4)
+        drive(reactor, chain, SpanLog())
+        reactor.stop()
+        assert reactor.state.last_block_height == applied
+        if chain is wire:
+            assert reactor.state.app_hash == expect[3][1]
+            assert not reactor.switch.stopped
+        else:
+            assert {p for p, _ in reactor.switch.stopped} == {PEER_ID}
+
+    # leg C: 3 headers signed on the host; a header nobody signed at 2
+    chain, fwire = chip_smoke.lite_chains(5, 3, 2, 3, 2, sign="host")
+    assert fwire[0] == chain.wire[0] and fwire[2] == chain.wire[2]
+    valset, fcs = chain.decode()
+    certify_chain(chain.chain_id, fcs, trusted=valset)
+    valset, forged = chain.decode(fwire)
+    assert forged[1].signed_header.header.app_hash == b"\xff" * 32
+    assert [v.signature for v in
+            forged[1].signed_header.commit.precommits] == [
+        v.signature for v in fcs[1].signed_header.commit.precommits]
+    with pytest.raises(CertificationError, match="^height 2:"):
+        certify_chain(chain.chain_id, forged, trusted=valset)
 
 
 def test_compile_cache_rule(monkeypatch):

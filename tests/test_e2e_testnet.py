@@ -17,12 +17,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _free_port_block(k):
-    from bench_util import free_port_block
+    from tendermint_tpu.utils.procs import free_port_block
     return free_port_block(k)
 
 
 def _node_env():
-    from bench_util import node_child_env
+    from tendermint_tpu.utils.procs import node_child_env
     return node_child_env(REPO)
 
 

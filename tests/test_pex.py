@@ -111,6 +111,38 @@ def test_pex_request_response_fills_book():
     sw1.stop(); sw2.stop()
 
 
+def test_pex_first_request_is_served_on_a_host_that_just_booted(monkeypatch):
+    """time.monotonic() counts from boot: a peer's FIRST request, on a
+    host up for less than period/3, is no flood; its second is."""
+    import types
+
+    from tendermint_tpu.p2p.pex import pex_reactor
+
+    class Sw:
+        stopped = []
+
+        def stop_peer_for_error(self, peer, err):
+            self.stopped.append(str(err))
+
+    class Peer:
+        id, outbound, sent = "p", False, []
+
+        def try_send_obj(self, ch, obj):
+            self.sent.append(obj["type"])
+
+    monkeypatch.setattr(pex_reactor, "time",
+                        types.SimpleNamespace(monotonic=lambda: 5.0))
+    r = PEXReactor(AddrBook(strict=False, key=b"a" * 24),
+                   ensure_peers_period=1000)
+    r.switch, peer = Sw(), Peer()
+    request = b'{"type":"pex_request"}'
+    r.receive(PEX_CHANNEL, peer, request)
+    assert (peer.sent, r.switch.stopped) == (["pex_addrs"], [])
+    r.receive(PEX_CHANNEL, peer, request)
+    assert (peer.sent, r.switch.stopped) == (["pex_addrs"],
+                                             ["pex request flood"])
+
+
 def test_pex_unsolicited_addrs_disconnects_peer():
     book = AddrBook(strict=False, key=b"a" * 24)
     r1 = PEXReactor(book, ensure_peers_period=1000)

@@ -4,8 +4,8 @@ synthetic busy thread, lock-wait recognition, collapsed-stack caps,
 queue gauge correctness under fill/drain, saturation watchdog
 fires-once-and-re-arms, weakref pruning, /healthz + /debug/pprof over
 HTTP, debug_profile RPC actions, cluster profile merging (the
-scripts/profile_merge.py path), the stall flight recorder's embedded
-profile + queue table, and bench_trend's trajectory gate."""
+scripts/profile_merge.py path) and the stall flight recorder's embedded
+profile + queue table."""
 
 import json
 import os
@@ -16,7 +16,7 @@ import urllib.request
 
 import pytest
 
-# the operational CLIs under test (profile_merge, bench_trend) live in
+# the operational CLI under test (profile_merge) lives in
 # scripts/, which is not a package — importable the way trace_merge's
 # own header does it
 sys.path.insert(0, os.path.join(os.path.dirname(
@@ -522,50 +522,6 @@ def test_node_on_stall_writes_self_diagnosing_dump(tmp_path,
     assert doc["profile"]["running"] is False  # knob off: observed only
     assert isinstance(doc["queues"], dict)
     assert "consensus" in doc
-
-
-# ------------------------------------------------------------ trendline
-
-def test_bench_trend_walk_and_gate(tmp_path):
-    import bench_trend
-    assert bench_trend.walk({"a": {"b": [1, 2, 3]}}, "a.b[-1]") == 3
-    assert bench_trend.walk(
-        {"points": [{"callers": 4, "v": 9}, {"callers": 16, "v": 11}]},
-        "points[callers=16].v") == 11
-    assert bench_trend.walk({"a": 1}, "missing") is None
-
-    pts = [
-        {"metric": "m", "pr": "PR 7", "value": 10.0, "unit": "x",
-         "direction": "up"},
-        {"metric": "m", "pr": "PR 10", "value": 7.0, "unit": "x",
-         "direction": "up"},
-    ]
-    regs = bench_trend.gate([dict(p) for p in pts], threshold=0.20)
-    assert len(regs) == 1 and regs[0]["regression"] == pytest.approx(0.3)
-    # within threshold: clean
-    pts[1]["value"] = 9.0
-    assert bench_trend.gate([dict(p) for p in pts], 0.20) == []
-    # direction-aware: lower-is-better regression
-    down = [
-        {"metric": "lat", "pr": "PR 8", "value": 100.0, "unit": "ms",
-         "direction": "down"},
-        {"metric": "lat", "pr": "PR 10", "value": 130.0, "unit": "ms",
-         "direction": "down"},
-    ]
-    regs = bench_trend.gate(down, 0.20)
-    assert len(regs) == 1
-
-
-def test_bench_trend_runs_on_the_committed_artifacts(tmp_path):
-    """The real repo artifacts parse, attribute to PRs, and pass the
-    gate (committing a regression would fail tier-1 right here)."""
-    import bench_trend
-    points = bench_trend.collect(bench_trend.REPO)
-    assert len(points) >= 8
-    metrics = {p["metric"] for p in points}
-    assert "socket_blocks_per_sec" in metrics
-    regs = bench_trend.gate(points, 0.20)
-    assert regs == [], f"bench trajectory regressed: {regs}"
 
 
 # ------------------------------------------------------------- catalog
