@@ -68,6 +68,7 @@ INSTRUMENTED_MODULES = [
     "tendermint_tpu.serving.loadgen",    # tm_load_* open-loop harness
     "tendermint_tpu.serving.deploy",     # tm_deploy_* process driver
     "tendermint_tpu.ops.ed25519",        # tm_verifier_h2d_bytes_total
+    "tendermint_tpu.types.block",        # tm_verifier_commit_block_ids_total
 ]
 
 # Causal span names follow the same closed-catalog discipline as metric

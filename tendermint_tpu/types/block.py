@@ -12,10 +12,18 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import List, Optional
 
+from tendermint_tpu import telemetry
 from tendermint_tpu.ops import merkle
 from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
 from tendermint_tpu.types.vote import Vote, VoteType
+
+# counted once per commit decoded (Commit.from_obj), with its two totals
+_m_block_ids = telemetry.counter(
+    "verifier_commit_block_ids_total",
+    "BlockID objects of commits decoded from wire objects: built (one "
+    "per distinct block id of a commit, the commit's own included) or "
+    "shared (a vote handed one already built for its commit)", ("how",))
 
 
 @dataclass
@@ -288,8 +296,38 @@ class Commit:
 
     @classmethod
     def from_obj(cls, o):
-        return cls(BlockID.from_obj(o["block_id"]),
-                   [Vote.from_obj(v) if v else None for v in o["precommits"]])
+        """One BlockID per distinct block id of THIS commit: a vote
+        whose block id has the wire fields of one already built (the
+        commit's own first) is handed that object, as a locally built
+        commit's votes are, and no BlockID, PartSetHeader or hex
+        conversion is made for it. The table dies with the call. Its
+        keys are the fields as they stand in `o`, and the type of
+        `total`: 1 == 1.0 == True, and only 1 signs as "total":1."""
+        b = o["block_id"]
+        parts = b["parts"]
+        total = parts["total"]
+        block_id = BlockID.from_obj(b)
+        built = {(b["hash"], parts["hash"], total, type(total)): block_id}
+        precommits = []
+        shared = 0
+        for v in o["precommits"]:
+            if not v:
+                precommits.append(None)
+                continue
+            b = v["block_id"]
+            parts = b["parts"]
+            total = parts["total"]
+            key = (b["hash"], parts["hash"], total, type(total))
+            bid = built.get(key)
+            if bid is None:
+                bid = built[key] = BlockID.from_obj(b)
+            else:
+                shared += 1
+            precommits.append(Vote.from_obj(v, bid))
+        if telemetry.enabled():
+            _m_block_ids.labels("built").inc(len(built))
+            _m_block_ids.labels("shared").inc(shared)
+        return cls(block_id, precommits)
 
 
 @dataclass

@@ -278,8 +278,8 @@ class ValidatorSet:
         # ONE layout definition (vote.sign_bytes_template, once per
         # distinct block id; pinned by test_commit_items_sign_bytes_
         # match), and for_block is decided once per distinct block id.
-        # Locally built commits share one BlockID object; wire-parsed
-        # ones carry one per vote, compared by its fields.
+        # A commit's votes for one block share one BlockID object (as
+        # built, and as Commit.from_obj decodes); others compare fields.
         sigs, absent = [], []
         msgs, msg_at = [], []       # a run's sign-bytes, its first lane
         flags, flag_at = [], []     # for_block of a run of one block id

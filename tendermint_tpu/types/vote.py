@@ -110,14 +110,19 @@ class Vote:
         return o
 
     @classmethod
-    def from_obj(cls, o) -> "Vote":
-        from tendermint_tpu.types.block import BlockID
+    def from_obj(cls, o, block_id=None) -> "Vote":
+        """`block_id`: the BlockID already built for o["block_id"]'s
+        fields (Commit.from_obj hands every vote of one block the same
+        object); a single gossiped vote builds its own."""
+        if block_id is None:
+            from tendermint_tpu.types.block import BlockID
+            block_id = BlockID.from_obj(o["block_id"])
         return cls(
             validator_address=bytes.fromhex(o["validator_address"]),
             validator_index=o["validator_index"],
             height=o["height"], round=o["round"],
             timestamp_ns=o["timestamp_ns"], type=o["type"],
-            block_id=BlockID.from_obj(o["block_id"]),
+            block_id=block_id,
             signature=bytes.fromhex(o["signature"]))
 
     def verify(self, chain_id: str, pubkey: bytes) -> bool:
