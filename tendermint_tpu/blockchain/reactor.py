@@ -485,6 +485,6 @@ class BlockchainReactor(Reactor):
     def _switch_to_consensus(self) -> None:
         """reactor.go:263 SwitchToConsensus."""
         self.fast_sync = False
-        self.synced = True
         if self.consensus_reactor is not None:
             self.consensus_reactor.switch_to_consensus(self.state)
+        self.synced = True      # last: the handoff is done, not begun

@@ -274,6 +274,13 @@ def cmd_shardset(args) -> int:
     return run_shardset(args)
 
 
+def cmd_worker(args) -> int:
+    """Run several validators of a materialized topology in this one
+    process (serving/worker.py)."""
+    from tendermint_tpu.serving.worker import run_worker
+    return run_worker(args)
+
+
 def cmd_testnet(args) -> int:
     """Emit an N-validator testnet file tree (cmd testnet.go:97): a shared
     genesis listing every validator, per-node priv_validator + node_key +
@@ -443,6 +450,18 @@ def main(argv=None) -> int:
                     help="front-door RPC listen address")
     sp.add_argument("--max-seconds", type=float, default=0)
     sp.set_defaults(fn=cmd_shardset)
+
+    sp = sub.add_parser("worker",
+                        help="run several validators of a topology "
+                             "(homes under --home) in this process")
+    sp.add_argument("--nodes", required=True,
+                    help="comma-separated home names under --home")
+    sp.add_argument("--rpc", default="",
+                    help="those of --nodes that serve RPC")
+    sp.add_argument("--in-memory", action="store_true",
+                    help="stores and signer state in memory")
+    sp.add_argument("--max-seconds", type=float, default=0)
+    sp.set_defaults(fn=cmd_worker)
 
     sp = sub.add_parser("lite", help="light-client RPC proxy")
     sp.add_argument("--node-addr", default="http://127.0.0.1:46657")

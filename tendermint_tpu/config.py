@@ -153,6 +153,18 @@ class P2PConfig:
     ban_score: int = 30
     ban_base_s: float = 60.0
     fd_headroom: int = 64
+    # link delay by region (p2p/fuzz.py's delay mode, the reference's
+    # own seam; serving/topology.py writes these): the region this node
+    # advertises in NodeInfo.other, and per region of the peer the
+    # one-way delay in ms this node holds every frame it sends there,
+    # plus up to region_jitter_ms drawn per frame from a generator
+    # seeded per link from region_delay_seed. A peer that names no
+    # region, or one the list gives no delay above 0, keeps its link
+    # untouched. Empty (the default) = off: no link is wrapped.
+    region: int = 0
+    region_delay_ms: list = field(default_factory=list)
+    region_jitter_ms: float = 0.0
+    region_delay_seed: int = 0
 
 
 @dataclass

@@ -45,11 +45,12 @@ class HeightVoteSet:
     MAX_CATCHUP_ROUNDS = 2
 
     def __init__(self, chain_id: str, height: int, valset: ValidatorSet,
-                 verifier=None):
+                 verifier=None, node: str = ""):
         self.chain_id = chain_id
         self.height = height
         self.valset = valset
         self.verifier = verifier
+        self.node = node
         self.round = 0
         self._sets: Dict[tuple, VoteSet] = {}
         self._peer_catchup: Dict[str, list] = {}
@@ -60,7 +61,7 @@ class HeightVoteSet:
             if (round_, t) not in self._sets:
                 self._sets[(round_, t)] = VoteSet(
                     self.chain_id, self.height, round_, t, self.valset,
-                    verifier=self.verifier)
+                    verifier=self.verifier, node=self.node)
 
     def set_round(self, round_: int) -> None:
         # pre-make EVERY round up to round_+1, like the reference's

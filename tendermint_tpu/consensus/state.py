@@ -117,10 +117,11 @@ class ConsensusState:
         # resolved once like the pipeline knob; off = zero per-height
         # span recording and untouched broadcast envelopes
         self._trace = causal.enabled()
-        # tx-lifecycle SLO plane (telemetry/slo.py, TM_TPU_SLO):
-        # resolved once the same way; off = the per-block stamp calls
-        # below never run (not even the hash of a single tx)
-        self._slo = slo_plane.enabled()
+        # tx-lifecycle SLO plane (telemetry/slo.py, TM_TPU_SLO): asked
+        # at each of the two per-block stamps below (one cached flag of
+        # the plane's own), so that a process that turns it on once its
+        # nodes are built is stamped too; off = the stamp calls never
+        # run (not even the hash of a single tx)
         self._pre_lock = threading.Lock()
         # next-proposal precompute handoff (worker -> propose step)
         self._precomputed = None  #: guarded_by _pre_lock
@@ -288,7 +289,8 @@ class ConsensusState:
         vs = VoteSet(self.state.chain_id, self.state.last_block_height,
                      seen.round(), VoteType.PRECOMMIT,
                      self.state.last_validators,
-                     verifier=self.block_exec.verifier)
+                     verifier=self.block_exec.verifier,
+                     node=self._trace_node)
         for pc in seen.precommits:
             if pc is not None:
                 vs.add_vote(pc)
@@ -332,7 +334,8 @@ class ConsensusState:
         rs.locked_block = None
         rs.locked_block_parts = None
         rs.votes = HeightVoteSet(state.chain_id, height, state.validators,
-                                 verifier=self.block_exec.verifier)
+                                 verifier=self.block_exec.verifier,
+                                 node=self._trace_node)
         rs.commit_round = -1
         if last_precommits is not None:
             rs.last_commit = last_precommits
@@ -526,7 +529,7 @@ class ConsensusState:
             if not self.replay_mode:
                 self._log(f"error signing proposal: {e!r}")
             return
-        if self._slo and not self.replay_mode:
+        if slo_plane.enabled() and not self.replay_mode:
             # SLO proposal-inclusion stamp (proposer side; receivers
             # stamp when their part set completes — first wins)
             slo_plane.mark_many(block.data.txs, "propose", height)
@@ -1060,7 +1063,7 @@ class ConsensusState:
             data = rs.proposal_block_parts.get_data()
             block = Block.from_bytes(data)
             rs.proposal_block = block
-            if self._slo and not self.replay_mode:
+            if slo_plane.enabled() and not self.replay_mode:
                 slo_plane.mark_many(block.data.txs, "propose", height)
             if rs.step == Step.PROPOSE and self._is_proposal_complete():
                 self._enter_prevote(height, rs.round)

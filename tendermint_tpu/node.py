@@ -35,7 +35,7 @@ class Node:
                  priv_validator=None, app=None, client_creator=None,
                  mempool=None, evidence_pool=None, in_memory=False,
                  with_p2p=False, fast_sync=False, with_rpc=False,
-                 wal_readonly=False, loop=None):
+                 wal_readonly=False, loop=None, node_key=None):
         from tendermint_tpu.utils.log import get_logger
         # logging is configured once at the CLI entry point; constructing
         # a Node (tests build several in-process) must not reconfigure
@@ -282,6 +282,9 @@ class Node:
         # ------------------------------------------------ p2p reactor stack
         self.switch = None
         self.fast_sync = fast_sync
+        # `node_key=` is the p2p identity where the caller holds it (an
+        # in-memory node whose peers dial it by id: serving/worker.py)
+        self._given_node_key = node_key
         if with_p2p:
             self._build_p2p(state, fast_sync, in_memory)
 
@@ -322,7 +325,9 @@ class Node:
         from tendermint_tpu.mempool import MempoolReactor
         from tendermint_tpu.p2p import NodeInfo, NodeKey, Switch
 
-        if in_memory:
+        if self._given_node_key is not None:
+            node_key = self._given_node_key
+        elif in_memory:
             from tendermint_tpu.types.keys import PrivKey
             node_key = NodeKey(PrivKey.generate())
         else:
@@ -337,6 +342,10 @@ class Node:
             moniker=getattr(self.config.base, "moniker", "node"),
             network=self.gen_doc.chain_id,
             other=_compact.wire_capabilities())
+        if self.config.p2p.region_delay_ms:
+            # link delay by region: peers hold what they send here by
+            # the region this node names
+            node_info.other.append(f"region={self.config.p2p.region}")
         self.switch = Switch(self.config.p2p, node_key, node_info,
                              loop=self._ensure_loop())
 
@@ -423,7 +432,12 @@ class Node:
             self.switch.add_reactor("pex", self.pex_reactor)
             self.switch.addr_book = self.addr_book
 
-    def start(self) -> None:
+    def start(self, dial: bool = True) -> None:
+        """`dial` false leaves the configured peers undialled: a
+        launcher of many nodes on one host (serving/deploy.py) has
+        every node listen first and dial (`dial_configured_peers`)
+        only then, so that no outgoing connection can take a port that
+        a node has yet to bind, and no dial comes too early."""
         self.logger.info("starting node",
                          chain_id=self.gen_doc.chain_id,
                          height=self.consensus.state.last_block_height,
@@ -451,7 +465,8 @@ class Node:
                 self.addr_book.add_our_address(self.switch.listen_address)
             self.switch.start()  # starts all reactors; consensus reactor
             #                      starts the state machine unless fast-sync
-            self._dial_configured_peers()
+            if dial:
+                self._dial_configured_peers()
         else:
             self.consensus.start()
 
@@ -517,6 +532,10 @@ class Node:
                              height=state.last_block_height)
         if self._statesync_gate is not None:
             self._statesync_gate.set()
+
+    def dial_configured_peers(self) -> None:
+        """What `start(dial=False)` left out."""
+        self._dial_configured_peers()
 
     def _dial_configured_peers(self) -> None:
         from tendermint_tpu.p2p import NetAddress

@@ -546,6 +546,11 @@ class LoopMConnection:
             "TM_TPU_P2P_FLUSH_LINGER_MS", default=4.0)) / 1e3
         self._last_flush = 0.0  # written on loop; racy reads benign
         self.drain_listeners: List[Callable[[], None]] = []
+        # a link that delays what it seals (p2p/fuzz.py, a set delay)
+        # hands the bytes back here when they are due
+        attach = getattr(link, "attach_loop", None)
+        if attach is not None:
+            attach(loop, self._write_held)
         self._queue_probes = [
             queue_obs.register(
                 f"mconn.send.{d.id:#04x}", self,
@@ -777,6 +782,14 @@ class LoopMConnection:
                     return
                 if not any(c.has_data() for c in self.channels.values()):
                     return
+
+    def _write_held(self, wire: bytes) -> None:
+        """Loop-thread: bytes a delaying link held back are due."""
+        with self._cond:
+            if self._stopped:
+                return
+        self._outbuf += wire
+        self._write_some()
 
     def _write_some(self) -> None:
         while self._outbuf:

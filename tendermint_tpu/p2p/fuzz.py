@@ -20,6 +20,18 @@ Two extensions over the reference:
   seed. Return None/"pass" to deliver, "drop" to drop, ("delay", s) to
   sleep s seconds first. `on_fault(kind)` observes every injected
   fault (telemetry counting lives in tendermint_tpu.chaos, not here).
+
+- A set delay (serving/topology.py's delay by region, through
+  config.p2p.region_delay_ms): mode "delay" with `delay_s` above 0
+  holds every frame written for `delay_s` plus a draw of up to
+  `jitter_s` from the link's seeded generator, and never lets a frame
+  overtake one written before it (a TCP link keeps its order). On the
+  thread plane the writer sleeps, as the reference's does. On the loop
+  plane a sleep would stop every connection of the node, so the bytes
+  are sealed at once (the nonce order is the wire order) and handed to
+  the connection when they are due (`attach_loop`). Frames sealed
+  together leave together, when the last of them is due. Each frame's
+  hold is observed in `tm_p2p_link_delay_seconds`.
 """
 
 from __future__ import annotations
@@ -27,7 +39,17 @@ from __future__ import annotations
 import random
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass
+
+from tendermint_tpu import telemetry
+
+_m_link_delay = telemetry.histogram(
+    "p2p_link_delay_seconds",
+    "Seconds a delaying link (config.p2p.region_delay_ms) held a frame "
+    "before it went to the socket",
+    buckets=(.005, .01, .02, .03, .04, .05, .06, .08, .1, .12, .15, .2,
+             .3, .5, 1.0))
 
 
 @dataclass
@@ -39,6 +61,8 @@ class FuzzConfig:
     prob_drop_conn: float = 0.0
     prob_sleep: float = 0.0
     seed: int | None = None
+    delay_s: float = 0.0            # mode "delay": hold every write
+    jitter_s: float = 0.0           # ... plus up to this, drawn per frame
 
 
 class FuzzedLink:
@@ -51,6 +75,12 @@ class FuzzedLink:
         self._rng = random.Random(self.config.seed)
         self._lock = threading.Lock()
         self._dead = False
+        # a set delay: the loop and the sink that takes held bytes
+        # (attach_loop), and when the last held frame is due
+        self._loop = None
+        self._sink = None
+        self._last_due = 0.0
+        self._held = deque()    # sealed bytes not yet due, oldest first
 
     def _note(self, kind: str) -> None:
         if self.on_fault is not None:
@@ -92,9 +122,35 @@ class FuzzedLink:
                     time.sleep(self._rng.random() * cfg.max_delay_s)
         return False
 
+    def _hold_s(self, n_frames: int) -> float:
+        """Seconds from now until `n_frames` written together may go
+        out: 0.0 unless the mode is "delay" with a delay set."""
+        cfg = self.config
+        if cfg.mode != "delay" or cfg.delay_s <= 0 or n_frames <= 0:
+            return 0.0
+        now = time.monotonic()
+        with self._lock:
+            jitter = max(self._rng.random() for _ in range(n_frames)) \
+                * cfg.jitter_s
+            due = max(now + cfg.delay_s + jitter, self._last_due + 1e-6)
+            self._last_due = due
+        if telemetry.enabled():
+            for _ in range(n_frames):
+                _m_link_delay.observe(due - now)
+        return due - now
+
+    def attach_loop(self, loop, sink) -> None:
+        """The loop plane's connection (LoopMConnection) takes what a
+        set delay holds back: `sink(wire)` runs on `loop`'s thread when
+        the bytes are due."""
+        self._loop, self._sink = loop, sink
+
     def write(self, data: bytes) -> int:
         if self._fuzz("write"):
             return len(data)  # silently dropped
+        hold = self._hold_s(1)
+        if hold > 0:
+            time.sleep(hold)
         return self.link.write(data)
 
     def write_many(self, chunks) -> int:
@@ -105,6 +161,9 @@ class FuzzedLink:
         chunks = list(chunks)
         kept = [c for c in chunks if not self._fuzz("write")]
         if kept:
+            hold = self._hold_s(len(kept))
+            if hold > 0:
+                time.sleep(hold)
             inner = getattr(self.link, "write_many", None)
             if inner is not None:
                 inner(kept)
@@ -147,7 +206,20 @@ class FuzzedLink:
         kept = [c for c in chunks if not self._fuzz("write")]
         if not kept:
             return b""
-        return self.link.seal_frames(kept)
+        wire = self.link.seal_frames(kept)
+        hold = self._hold_s(len(kept)) if self._sink is not None else 0.0
+        if hold <= 0:
+            return wire
+        # one timer a burst, and each timer releases the OLDEST burst:
+        # two timers a microsecond apart may fire in either order, the
+        # bytes may not
+        self._held.append(wire)
+        self._loop.call_later(hold, self._release, owner="p2p")
+        return b""
+
+    def _release(self) -> None:
+        if self._held:
+            self._sink(self._held.popleft())
 
     def feed_wire(self, data: bytes):
         """Loop-reactor codec surface: inner decode, then per-frame

@@ -4,7 +4,7 @@
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List
+from typing import List, Optional
 
 from tendermint_tpu.p2p.key import pubkey_to_id
 
@@ -24,6 +24,18 @@ class NodeInfo:
     @property
     def id(self) -> str:
         return pubkey_to_id(self.pubkey)
+
+    def region(self) -> Optional[int]:
+        """The region a node advertises as `region=<n>` in `other`
+        (config.p2p.region*, for link delay by region); None where it
+        names none."""
+        for item in self.other:
+            if item.startswith("region="):
+                try:
+                    return int(item[7:])
+                except ValueError:
+                    return None
+        return None
 
     def validate(self) -> None:
         """p2p/node_info.go:40."""

@@ -213,6 +213,10 @@ class Switch:
         self._fd_headroom = knobs.knob_int(
             "TM_TPU_P2P_FD_HEADROOM",
             config=getattr(config, "fd_headroom", None), default=64)
+        # link delay by region (config.p2p.region_delay_ms): read once;
+        # empty, the default, wraps no link
+        self._region_delay_ms = list(
+            getattr(config, "region_delay_ms", None) or ())
 
     # ------------------------------------------------------------ ban plane
 
@@ -547,6 +551,8 @@ class Switch:
         # the frame hot path byte-for-byte on the existing code
         from tendermint_tpu.chaos import maybe_wrap_link
         link = maybe_wrap_link(link, their_info.id or "")
+        if self._region_delay_ms:
+            link = self._delay_link(link, their_info)
         peer = Peer(
             link, their_info, self.channel_descs, outbound=outbound,
             persistent=persistent, dial_addr=dial_addr,
@@ -606,6 +612,26 @@ class Switch:
                                   reactor=name, peer=peer.id,
                                   err=repr(e))
         return peer
+
+    def _delay_link(self, link, their_info: NodeInfo):
+        """What this node sends to a peer of another region is held for
+        the configured one-way delay (p2p/fuzz.py, a set delay). A peer
+        that names no region, or one this node has no delay to, keeps
+        the link as it is."""
+        region = their_info.region()
+        if region is None or not 0 <= region < len(self._region_delay_ms):
+            return link
+        delay_ms = float(self._region_delay_ms[region])
+        if delay_ms <= 0:
+            return link
+        from tendermint_tpu.p2p.fuzz import FuzzConfig, FuzzedLink
+        seed = getattr(self.config, "region_delay_seed", 0)
+        return FuzzedLink(link, FuzzConfig(
+            mode="delay", delay_s=delay_ms / 1e3,
+            jitter_s=float(getattr(self.config, "region_jitter_ms",
+                                   0.0)) / 1e3,
+            seed=zlib.crc32(f"{seed}/{self.node_info.id}/"
+                            f"{their_info.id}".encode())))
 
     # --------------------------------------------------------------- routing
 

@@ -97,6 +97,10 @@ SPANS = {
     "cs:COMMIT": "gossip and consensus rounds",
     "cs:finalize_commit": "gossip and consensus rounds",
     "cs:timeout": "gossip and consensus rounds",
+    # one event per VoteSet.add_vote / add_votes_batch call with its
+    # verify (a gossip message's votes, never one per vote); req = the
+    # height, `sigs` = signatures the call sent to the verifier
+    "cs:vote_ingest": "gossip and consensus rounds",
 }
 
 ANNOTATION_PREFIX = "tm:"   # a span's name in a profiler trace

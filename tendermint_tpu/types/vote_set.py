@@ -10,12 +10,22 @@ feed many votes at once via add_votes_batch.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from tendermint_tpu import telemetry
+from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types.block import BlockID, Commit
 from tendermint_tpu.types.validator_set import ValidatorSet
 from tendermint_tpu.types.vote import Vote, VoteType
+
+
+_m_votes = telemetry.counter(
+    "consensus_votes_total",
+    "Votes offered to a VoteSet, by what became of them: added, "
+    "duplicate (held already: no signature checked), rejected "
+    "(malformed, a bad signature, or a conflict)", ("outcome",))
 
 
 class ConflictingVoteError(Exception):
@@ -40,7 +50,7 @@ class _BlockVotes:
 
 class VoteSet:
     def __init__(self, chain_id: str, height: int, round_: int, type_: int,
-                 valset: ValidatorSet, verifier=None):
+                 valset: ValidatorSet, verifier=None, node: str = ""):
         assert height >= 1 and VoteType.valid(type_)
         self.chain_id = chain_id
         self.height = height
@@ -48,6 +58,9 @@ class VoteSet:
         self.type = type_
         self.valset = valset
         self.verifier = verifier
+        # whose set this is, for the `cs:vote_ingest` event (several
+        # nodes can share one interpreter and one ring)
+        self.node = node
         # votes[i]: the canonical vote from validator i (first non-conflicting)
         self.votes: List[Optional[Vote]] = [None] * len(valset)
         self.power = 0  # total power of all canonical votes
@@ -96,14 +109,36 @@ class VoteSet:
     def _add_votes(self, votes: List[Vote],
                    errors: Optional[List[tuple[int, Exception]]] = None
                    ) -> List[bool]:
-        return self._add_votes_async(votes, errors)()
+        if not telemetry.enabled():
+            return self._add_votes_async(votes, errors)()
+        # one `cs:vote_ingest` event a call, whatever the call's size
+        # (a gossip message's votes), its `sigs` the signatures that
+        # went to the verifier; and the call's votes by outcome
+        t0 = time.perf_counter()
+        tally = {"sigs": 0}
+        results = None
+        try:
+            results = self._add_votes_async(votes, errors, tally)()
+            return results
+        finally:
+            trace.complete("cs:vote_ingest", t0, time.perf_counter(),
+                           req=self.height, node=self.node,
+                           sigs=tally["sigs"])
+            added = sum(results) if results else 0
+            dup = tally.get("duplicate", 0)
+            _m_votes.labels("added").inc(added)
+            _m_votes.labels("duplicate").inc(dup)
+            _m_votes.labels("rejected").inc(len(votes) - added - dup)
 
     def _add_votes_async(self, votes: List[Vote],
-                         errors: Optional[List[tuple[int, Exception]]] = None):
+                         errors: Optional[List[tuple[int, Exception]]] = None,
+                         tally: Optional[dict] = None):
         """Validation now, signature dispatch now (async), application
         in the returned zero-arg finisher — the split that lets callers
         overlap device crypto with host work and lets the coalescer
-        merge concurrent dispatches."""
+        merge concurrent dispatches. `tally`, where given, is told how
+        many votes were `duplicate` and how many `sigs` were sent to
+        the verifier."""
         from tendermint_tpu.models.verifier import default_verifier
         verifier = self.verifier or default_verifier()
 
@@ -113,6 +148,7 @@ class VoteSet:
             errors.append((pos, exc))
 
         to_verify = []   # (vote, val, pos)
+        n_dup = 0
         results = [False] * len(votes)
         for pos, vote in enumerate(votes):
             try:
@@ -142,13 +178,18 @@ class VoteSet:
             # evidence (and re-run crypto) for.
             existing = self.votes[idx]
             if existing is not None and existing.block_id == vote.block_id:
+                n_dup += 1
                 continue  # duplicate; results[pos] stays False
             bv0 = self.votes_by_block.get(vote.block_id.key())
             if bv0 is not None and idx in bv0.votes_by_index:
+                n_dup += 1
                 continue  # already counted for this block (conflict path)
             # (on conflict: still verify the signature before accusing)
             to_verify.append((vote, val, pos))
 
+        if tally is not None:
+            tally["sigs"] = len(to_verify)
+            tally["duplicate"] = n_dup
         resolve_ok = verifier.verify_async([
             (val.pubkey, v.sign_bytes(self.chain_id), v.signature)
             for v, val, _ in to_verify])
