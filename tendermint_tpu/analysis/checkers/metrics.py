@@ -68,7 +68,8 @@ INSTRUMENTED_MODULES = [
     "tendermint_tpu.serving.loadgen",    # tm_load_* open-loop harness
     "tendermint_tpu.serving.deploy",     # tm_deploy_* process driver
     "tendermint_tpu.ops.ed25519",        # tm_verifier_h2d_bytes_total
-    "tendermint_tpu.types.block",        # tm_verifier_commit_block_ids_total
+    "tendermint_tpu.types.block",        # tm_verifier_commit_block_ids_total,
+                                         # tm_wire_block_decodes_total
     "tendermint_tpu.types.vote_set",     # tm_consensus_votes_total
     "tendermint_tpu.p2p.fuzz",           # tm_p2p_link_delay_seconds
 ]

@@ -28,9 +28,11 @@ from tendermint_tpu.types.sigcolumns import SigColumns
 from tendermint_tpu.state.execution import ApplyBlockError
 from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
-from tendermint_tpu.types.block import Block, BlockID
+from tendermint_tpu.types.block import TXS_PATH, Block, BlockID
 
 BLOCKCHAIN_CHANNEL = 0x40
+# the transactions of the block a block_response carries
+_RESPONSE_TXS = ("block",) + TXS_PATH
 SYNC_TICK_S = 0.05                # trySyncTicker (blockchain/reactor.go)
 STATUS_UPDATE_INTERVAL_S = 10.0
 SWITCH_TO_CONSENSUS_INTERVAL_S = 1.0
@@ -177,12 +179,12 @@ class BlockchainReactor(Reactor):
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         t_decode = time.perf_counter() if telemetry.enabled() else 0.0
-        msg = encoding.cloads(msg_bytes)
+        msg, txs = encoding.cloads_hex_array(msg_bytes, _RESPONSE_TXS)
         t = msg.get("type")
         if t == "block_request":
             self._respond_to_block_request(peer, msg["height"])
         elif t == "block_response":
-            block = Block.from_obj(msg["block"])
+            block = Block.from_wire(msg["block"], txs)
             # where a node decodes the blocks it syncs: the message's
             # parse and the block's, once it proves to be a block
             if t_decode:

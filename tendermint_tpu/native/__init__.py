@@ -179,7 +179,8 @@ def _bind_hostops(lib) -> None:
 _HOSTOPS = _Ext("_hostops", _src("hostops.cpp"), opt="-O3", cpython=False,
                 bind=_bind_hostops)
 # A true CPython extension (not ctypes): the canonical-JSON encoder
-# walks Python object graphs, which a C ABI can't.
+# walks Python object graphs, and the decoder of a block's transaction
+# list builds them, which a C ABI can't.
 _CODEC = _Ext("_tmcodec", _src("codec.cpp"))
 # Batched Ed25519 verify-prep + signing phases: takes the verifier's
 # items list and returns the device-bound arrays in one call (GIL
@@ -211,7 +212,8 @@ def available() -> bool:
 
 def codec():
     """The _tmcodec extension module, or None when unavailable.
-    Exposes canonical_dumps(obj)->bytes and the Fallback exception."""
+    Exposes canonical_dumps(obj)->bytes, split_hex_array(data, path)
+    -> (items, rest) and the Fallback exception of both."""
     return _CODEC.ensure_loaded()
 
 

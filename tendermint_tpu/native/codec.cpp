@@ -1,4 +1,5 @@
-// Canonical-JSON encoder — CPython extension.
+// Canonical-JSON encoder, and the decoder of one array of hex strings
+// (a block's transactions; further down) — CPython extension.
 //
 // Byte-for-byte equivalent to types/encoding.py cdumps() (the pure-Python
 // reference path: _canon() + json.dumps(sort_keys=True,
@@ -19,8 +20,13 @@
 #include <Python.h>
 
 #include <algorithm>
+#include <cstring>
 #include <string>
 #include <vector>
+
+#if defined(__SSE2__)
+#include <emmintrin.h>
+#endif
 
 static PyObject *FallbackError;  // wrapper catches this and uses pure path
 
@@ -218,16 +224,314 @@ static PyObject *canonical_dumps(PyObject *self, PyObject *arg) {
     return PyBytes_FromStringAndSize(out.data(), (Py_ssize_t)out.size());
 }
 
+// ---------------------------------------------------------------------------
+// Decoder: one array of hex strings taken out of a canonical-JSON document.
+//
+// split_hex_array(data, path) walks the document's structure (objects,
+// arrays, strings with their quotes and escapes; it never searches for a
+// key's text) to the array at the keys `path` below the root object and
+// returns (items, rest): the array's strings as bytes objects filled
+// straight from their hex digits, and the document with that array
+// emptied ("[]"), which the Python wrapper hands to json.loads. So the
+// JSON grammar, numbers, escapes and UTF-8 outside the array are still
+// judged by the path that judged them before (types/encoding.py cloads),
+// on identical text.
+//
+// Same contract as the encoder's: it accepts only what it is sure of and
+// raises Fallback for everything else, and the wrapper then decodes the
+// whole document by the pure path. Fallback: an item that is not a string
+// of an even number of hex digits (so whitespace, which bytes.fromhex
+// skips, an escape, an odd length and a non-hex character are all ruled
+// by the pure path), anything but "," between items, a key on the way
+// written with an escape or met twice (json.loads keeps the last), a
+// value on the way that is not an object, or at its end not an array,
+// no such array, nesting deeper than MAX_DEPTH, brackets that do not
+// pair, a string or the document left open, bytes after the document.
+
+static const int MAX_DEPTH = 64;
+
+struct HexTable {
+    unsigned short v[256];  // a hex digit's value; 0x100: not a hex digit
+    HexTable() {
+        for (int c = 0; c < 256; c++)
+            v[c] = (c >= '0' && c <= '9')   ? c - '0'
+                   : (c >= 'a' && c <= 'f') ? c - 'a' + 10
+                   : (c >= 'A' && c <= 'F') ? c - 'A' + 10
+                                            : 0x100;
+    }
+};
+static const HexTable UNHEX;
+
+// 2m hex digits at s -> m bytes at dst; false if any is not a hex digit.
+static bool unhex(const unsigned char *s, Py_ssize_t m, unsigned char *dst) {
+    Py_ssize_t i = 0;
+    bool ok = true;
+#if defined(__SSE2__)
+    // 16 digits a step (the decode is most of this pass: 2.5 MB of hex a
+    // block). A digit is c - '0' <= 9 or (c | 0x20) - 'a' <= 5, unsigned;
+    // only 0-9, A-F and a-f pass. A pair (first, second) lies in one
+    // 16-bit lane as second << 8 | first and leaves it as first << 4 |
+    // second.
+    const __m128i c0 = _mm_set1_epi8('0'), n9 = _mm_set1_epi8(9),
+                  lower = _mm_set1_epi8(0x20), ca = _mm_set1_epi8('a'),
+                  n5 = _mm_set1_epi8(5), n10 = _mm_set1_epi8(10),
+                  low = _mm_set1_epi16(0x00ff);
+    int all = 0xffff;
+    for (; i + 8 <= m; i += 8) {
+        __m128i c = _mm_loadu_si128((const __m128i *)(s + 2 * i));
+        __m128i d = _mm_sub_epi8(c, c0);
+        __m128i is_d = _mm_cmpeq_epi8(_mm_min_epu8(d, n9), d);
+        __m128i l = _mm_sub_epi8(_mm_or_si128(c, lower), ca);
+        __m128i is_l = _mm_cmpeq_epi8(_mm_min_epu8(l, n5), l);
+        __m128i v = _mm_or_si128(
+            _mm_and_si128(d, is_d),
+            _mm_and_si128(_mm_add_epi8(l, n10), is_l));
+        all &= _mm_movemask_epi8(_mm_or_si128(is_d, is_l));
+        __m128i r = _mm_and_si128(
+            _mm_or_si128(_mm_slli_epi16(v, 4), _mm_srli_epi16(v, 8)), low);
+        _mm_storel_epi64((__m128i *)(dst + i), _mm_packus_epi16(r, r));
+    }
+    ok = all == 0xffff;
+#endif
+    unsigned bad = 0;
+    for (; i < m; i++) {
+        unsigned v = (unsigned)UNHEX.v[s[2 * i]] << 4 | UNHEX.v[s[2 * i + 1]];
+        dst[i] = (unsigned char)v;
+        bad |= v;
+    }
+    return ok && !(bad & 0x1100);
+}
+
+struct Frame {
+    bool is_obj;    // else an array
+    bool on_path;   // the object at path[0..depth): its keys are compared
+    bool want_key;  // the next string of this object is a key
+    int hits;       // keys of it that equalled path[depth]
+};
+
+static PyObject *fall(const char *why) {
+    PyErr_SetString(FallbackError, why);
+    return nullptr;
+}
+
+// The array whose '[' is at p[at]; on success *end is one past its ']'.
+static PyObject *hex_items(const unsigned char *p, Py_ssize_t n,
+                           Py_ssize_t at, Py_ssize_t *end) {
+    PyObject *items = PyList_New(0);
+    if (items == nullptr) return nullptr;
+    Py_ssize_t q = at + 1;
+    if (q < n && p[q] == ']') {
+        *end = q + 1;
+        return items;
+    }
+    for (;;) {
+        if (q >= n || p[q] != '"') break;
+        const unsigned char *s = p + q + 1;
+        const unsigned char *e =
+            (const unsigned char *)memchr(s, '"', (size_t)(n - q - 1));
+        if (e == nullptr || ((e - s) & 1)) break;
+        Py_ssize_t m = (e - s) / 2;
+        PyObject *b = PyBytes_FromStringAndSize(nullptr, m);
+        if (b == nullptr) {
+            Py_DECREF(items);
+            return nullptr;
+        }
+        int rc = unhex(s, m, (unsigned char *)PyBytes_AS_STRING(b))
+                     ? PyList_Append(items, b)
+                     : 1;
+        Py_DECREF(b);
+        if (rc < 0) {
+            Py_DECREF(items);
+            return nullptr;
+        }
+        if (rc > 0) break;
+        q = (e - p) + 1;
+        if (q >= n) break;
+        if (p[q] == ',') {
+            q++;
+            continue;
+        }
+        if (p[q] != ']') break;
+        *end = q + 1;
+        return items;
+    }
+    Py_DECREF(items);
+    return fall("array item is not a plain hex string");
+}
+
+static PyObject *split_walk(const unsigned char *p, Py_ssize_t n,
+                            const std::vector<std::string> &path) {
+    const size_t L = path.size();
+    Frame stack[MAX_DEPTH];
+    size_t depth = 0;
+    bool pending = true;  // the next value is the one at path[0..depth)
+    bool closed = false;
+    PyObject *items = nullptr;
+    Py_ssize_t cut_from = 0, cut_to = 0;
+    Py_ssize_t i = 0;
+    const char *why = nullptr;
+    while (i < n) {
+        unsigned char c = p[i];
+        if (closed) {
+            why = "bytes after the document";
+            break;
+        }
+        if (c == ' ' || c == '\t' || c == '\n' || c == '\r' || c == ':') {
+            i++;
+            continue;
+        }
+        if (c == '{' || c == '[') {
+            if (pending && c == '[' && depth == L) {
+                items = hex_items(p, n, i, &cut_to);
+                if (items == nullptr) return nullptr;
+                cut_from = i;
+                i = cut_to;
+                pending = false;
+                continue;
+            }
+            if (pending && (c == '[' || depth == L)) {
+                why = "a value on the way is not what the path names";
+                break;
+            }
+            if (depth == MAX_DEPTH) {
+                why = "nested too deep";
+                break;
+            }
+            stack[depth++] = Frame{c == '{', pending, c == '{', 0};
+            pending = false;
+            i++;
+            continue;
+        }
+        if (c == '}' || c == ']') {
+            if (pending || depth == 0 ||
+                stack[depth - 1].is_obj != (c == '}')) {
+                why = "brackets do not pair";
+                break;
+            }
+            if (--depth == 0) closed = true;
+            i++;
+            continue;
+        }
+        if (pending) {
+            why = "a value on the way is not what the path names";
+            break;
+        }
+        if (depth == 0) {
+            why = "the document is not an object";
+            break;
+        }
+        Frame &f = stack[depth - 1];
+        if (c == ',') {
+            if (f.is_obj) f.want_key = true;
+            i++;
+            continue;
+        }
+        if (c != '"') {  // a number's or a literal's character
+            i++;
+            continue;
+        }
+        Py_ssize_t s = ++i;
+        bool escaped = false;
+        while (i < n && p[i] != '"') {
+            if (p[i] == '\\') {
+                escaped = true;
+                i++;
+            }
+            i++;
+        }
+        if (i >= n) {
+            why = "a string is left open";
+            break;
+        }
+        Py_ssize_t len = i - s;
+        i++;
+        if (!f.is_obj || !f.want_key) continue;  // a value
+        f.want_key = false;
+        if (!f.on_path) continue;
+        if (escaped) {
+            why = "a key on the way is written with an escape";
+            break;
+        }
+        const std::string &k = path[depth - 1];
+        if ((size_t)len == k.size() &&
+            memcmp(p + s, k.data(), k.size()) == 0) {
+            if (++f.hits > 1) {
+                why = "a key on the way is met twice";
+                break;
+            }
+            pending = true;
+        }
+    }
+    if (why == nullptr && !closed) why = "the document is left open";
+    if (why == nullptr && items == nullptr) why = "no array at the path";
+    if (why != nullptr) {
+        Py_XDECREF(items);
+        return fall(why);
+    }
+    PyObject *rest =
+        PyBytes_FromStringAndSize(nullptr, cut_from + 2 + (n - cut_to));
+    if (rest == nullptr) {
+        Py_DECREF(items);
+        return nullptr;
+    }
+    char *r = PyBytes_AS_STRING(rest);
+    memcpy(r, p, (size_t)cut_from);
+    r[cut_from] = '[';
+    r[cut_from + 1] = ']';
+    memcpy(r + cut_from + 2, p + cut_to, (size_t)(n - cut_to));
+    PyObject *out = PyTuple_Pack(2, items, rest);
+    Py_DECREF(items);
+    Py_DECREF(rest);
+    return out;
+}
+
+static PyObject *split_hex_array(PyObject *self, PyObject *args) {
+    PyObject *data, *keys;
+    if (!PyArg_ParseTuple(args, "OO!", &data, &PyTuple_Type, &keys))
+        return nullptr;
+    std::vector<std::string> path;
+    for (Py_ssize_t i = 0; i < PyTuple_GET_SIZE(keys); i++) {
+        Py_ssize_t kn;
+        const char *ks = PyUnicode_Check(PyTuple_GET_ITEM(keys, i))
+            ? PyUnicode_AsUTF8AndSize(PyTuple_GET_ITEM(keys, i), &kn)
+            : nullptr;
+        if (ks == nullptr) {
+            PyErr_Clear();
+            PyErr_SetString(PyExc_TypeError, "path: a tuple of str");
+            return nullptr;
+        }
+        path.emplace_back(ks, (size_t)kn);
+    }
+    if (path.empty() || path.size() >= (size_t)MAX_DEPTH) {
+        PyErr_SetString(PyExc_ValueError, "path: 1 to 63 keys");
+        return nullptr;
+    }
+    // bytes and bytearray alone have the .decode() the pure path calls:
+    // what a str, a memoryview or None meets there is for it to say
+    if (!PyBytes_Check(data) && !PyByteArray_Check(data))
+        return fall("the document is neither bytes nor bytearray");
+    Py_buffer view;
+    if (PyObject_GetBuffer(data, &view, PyBUF_SIMPLE) < 0) return nullptr;
+    PyObject *out =
+        split_walk((const unsigned char *)view.buf, view.len, path);
+    PyBuffer_Release(&view);
+    return out;
+}
+
 static PyMethodDef methods[] = {
     {"canonical_dumps", canonical_dumps, METH_O,
      "Canonical JSON bytes (sorted keys, minimal separators, bytes as "
      "lowercase hex); byte-equal to the pure-Python cdumps path."},
+    {"split_hex_array", split_hex_array, METH_VARARGS,
+     "split_hex_array(data, path) -> (items, rest): the array of hex "
+     "strings at the keys `path` of a JSON document as a list of bytes, "
+     "and the document with that array emptied; Fallback when unsure."},
     {nullptr, nullptr, 0, nullptr},
 };
 
 static struct PyModuleDef moduledef = {
     PyModuleDef_HEAD_INIT, "_tmcodec",
-    "Native canonical-JSON encoder for tendermint_tpu", -1, methods,
+    "Native canonical-JSON codec for tendermint_tpu", -1, methods,
 };
 
 PyMODINIT_FUNC PyInit__tmcodec(void) {

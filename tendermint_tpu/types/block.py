@@ -25,6 +25,18 @@ _m_block_ids = telemetry.counter(
     "per distinct block id of a commit, the commit's own included) or "
     "shared (a vote handed one already built for its commit)", ("how",))
 
+# counted once per block decoded from wire bytes (Block.from_wire)
+_m_block_decodes = telemetry.counter(
+    "wire_block_decodes_total",
+    "Blocks decoded from wire bytes: native (the transaction list "
+    "filled from the wire's hex by native/codec.cpp, the rest by "
+    "json.loads) or pure (the whole document by json.loads and "
+    "bytes.fromhex: no extension, or a document it raised Fallback "
+    "for)", ("how",))
+
+# where a block's wire object keeps its transactions
+TXS_PATH = ("data", "txs")
+
 
 @dataclass
 class PartSetHeader:
@@ -417,9 +429,28 @@ class Block:
         return b
 
     @classmethod
+    def from_wire(cls, o, txs: Optional[List[bytes]]) -> "Block":
+        """The block of a wire document as
+        encoding.cloads_hex_array(doc, <keys of the block> + TXS_PATH)
+        left it: `o` the block's object and `txs` its transactions,
+        already bytes, or None where `o` still holds them as hex
+        (from_obj, the specification, decodes them). Every decode of a
+        block from wire bytes comes through here."""
+        if txs is None:
+            blk = cls.from_obj(o)
+        else:
+            blk = cls(Header.from_obj(o["header"]), Data(txs),
+                      EvidenceData.from_obj(o["evidence"]),
+                      Commit.from_obj(o["last_commit"]))
+        if telemetry.enabled():
+            _m_block_decodes.labels(
+                "pure" if txs is None else "native").inc()
+        return blk
+
+    @classmethod
     def from_bytes(cls, b: bytes) -> "Block":
         with trace.span("wire.decode_block", bytes=len(b)):
-            blk = cls.from_obj(encoding.cloads(b))
+            blk = cls.from_wire(*encoding.cloads_hex_array(b, TXS_PATH))
             blk.__dict__["_bytes"] = bytes(b)
             blk.__dict__["_bytes_hh"] = blk.header.hash()
         return blk

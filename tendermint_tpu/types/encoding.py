@@ -36,9 +36,18 @@ def _pure_cdumps(obj: Any) -> bytes:
                       ensure_ascii=False).encode()
 
 
-# resolved lazily on first cdumps: (canonical_dumps, Fallback) once the
-# native codec builds, False when unavailable
+# resolved lazily on first use: (canonical_dumps, Fallback,
+# split_hex_array) once the native codec builds, False when unavailable
 _native_state: Any = None
+
+
+def _resolve_native():
+    global _native_state
+    from tendermint_tpu import native
+    mod = native.codec()
+    _native_state = (mod.canonical_dumps, mod.Fallback,
+                     mod.split_hex_array) if mod else False
+    return _native_state
 
 
 def cdumps(obj: Any) -> bytes:
@@ -46,22 +55,45 @@ def cdumps(obj: Any) -> bytes:
     bytes/None). Uses the native encoder (native/codec.cpp) when built —
     canonical encoding is the single hottest host operation in the sync
     loop — with automatic fallback to the pure path."""
-    global _native_state
-    if _native_state is None:
-        from tendermint_tpu import native
-        mod = native.codec()
-        _native_state = (mod.canonical_dumps, mod.Fallback) if mod else False
-    if _native_state is not False:
-        fn, fallback_exc = _native_state
+    state = _native_state
+    if state is None:
+        state = _resolve_native()
+    if state is not False:
         try:
-            return fn(obj)
-        except fallback_exc:
+            return state[0](obj)
+        except state[1]:
             pass
     return _pure_cdumps(obj)
 
 
 def cloads(data: bytes) -> Any:
     return json.loads(data.decode())
+
+
+def cloads_hex_array(data: bytes, path: tuple) -> tuple:
+    """cloads(data) for a document that holds an array of hex strings
+    at the keys `path` below its root (a block's transactions: 97% of
+    its bytes). -> (tree, items). Where the native decoder
+    (native/codec.cpp split_hex_array) is built and sure of the
+    document, items is that array as a list of bytes, filled straight
+    from the wire's hex digits, and the tree holds [] in its place:
+    only the rest of the document went through json.loads. Otherwise
+    items is None and the tree is cloads(data), whole: the
+    specification path, which also rules on whatever the decoder
+    raised Fallback for (see its list there), so no document decodes
+    to other items, or is accepted or refused, by one way and not the
+    other."""
+    state = _native_state
+    if state is None:
+        state = _resolve_native()
+    if state is not False:
+        try:
+            items, rest = state[2](data, path)
+        except state[1]:
+            pass
+        else:
+            return cloads(rest), items
+    return cloads(data), None
 
 
 def chash(obj: Any) -> bytes:

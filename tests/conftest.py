@@ -105,6 +105,25 @@ def _reset_fail_points():
     fail.reset()
 
 
+@pytest.fixture
+def block_decodes():
+    """`tm_wire_block_decodes_total` (types/block.py), telemetry on and
+    both of its children counting from zero; as found afterwards."""
+    from tendermint_tpu import telemetry
+    from tendermint_tpu.types import block         # declares the family
+    fam = telemetry.REGISTRY.get("wire_block_decodes_total")
+    assert fam is block._m_block_decodes
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    held = {how: fam.labels(how).value for how in ("native", "pure")}
+    for how in held:
+        fam.labels(how).value = 0.0
+    yield fam
+    for how, value in held.items():
+        fam.labels(how).value = value
+    telemetry.set_enabled(was)
+
+
 @pytest.fixture(autouse=True)
 def _no_leaked_tm_threads():
     """Leaktest (the reference runs fortytw2/leaktest on its goroutine

@@ -109,7 +109,19 @@ def test_span_catalog_enforced_at_record():
     assert ev["n"] == "apply" and ev["d"] >= 5_000_000
 
 
-def test_causal_ring_cap_and_drop_counter():
+@pytest.fixture
+def counters_on():
+    """`trace_events_dropped_total` counts only while telemetry is on,
+    a process-wide switch: a file that ran earlier in this worker may
+    have left it off (tests/test_serving_workers.py runs validators in
+    process with telemetry off, as its deployment is designed to)."""
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    yield
+    telemetry.set_enabled(was)
+
+
+def test_causal_ring_cap_and_drop_counter(counters_on):
     causal.configure("on")
     causal.set_capacity(10)
     before = telemetry.value("trace_events_dropped_total") or 0.0
@@ -123,7 +135,7 @@ def test_causal_ring_cap_and_drop_counter():
     assert after - before == 15
 
 
-def test_tracer_ring_cap_regression():
+def test_tracer_ring_cap_regression(counters_on):
     """PR 1 Tracer satellite: explicit cap + drop accounting (was a
     silent deque(maxlen) eviction)."""
     t = ttrace.Tracer(capacity=5)
