@@ -12,12 +12,23 @@ app-defined in both builds. (Additive set-hashing trades collision
 margin for O(1) updates — the known generalized-birthday attacks need
 ~2^80+ work per bucket, acceptable for this demo app.)
 
-TM_TPU_STATE_TREE=on swaps the commit backend for the authenticated
-state tree (tendermint_tpu/statetree/, docs/state.md): app_hash
-becomes a critbit Merkle root, `query(prove=True)` returns per-key
-inclusion/absence proofs bound to it, and snapshot chunks stream
-straight from tree nodes. The two backends produce DIFFERENT app
-hashes by design — every validator of a chain must agree on the knob.
+The commit backend is the chain's choice: the genesis says
+`app_state["kvstore"]["commit_backend"]`, "tree" or "buckets", and
+InitChain honours it before the first DeliverTx. "tree" is the
+authenticated state tree (tendermint_tpu/statetree/, docs/state.md):
+app_hash becomes a critbit Merkle root, `query(prove=True)` returns
+per-key inclusion/absence proofs bound to it, and snapshot chunks
+stream straight from tree nodes. The two backends produce DIFFERENT
+app hashes by design, which is why the genesis states it. A genesis
+that says nothing leaves the choice to TM_TPU_STATE_TREE=on in each
+node's environment, as before; one that speaks wins over it.
+
+A chain may also start with state: `app_state["kvstore"]["records"]`
+names a file of records (abci/apps/records.py: `file`, `count`,
+`sha256`). InitChain checks the digest, loads every record through the
+same loader a snapshot restore uses, and commits the store as version
+0, the one block 1 builds on. A file that is not the one the genesis
+names stops the node.
 
 Validator-change txs (the reference's persistent_dummy surface):
 `val:<pubkey_hex>/<power>` queues a validator update returned from
@@ -45,6 +56,9 @@ from tendermint_tpu.abci.types import (
     ResultQuery, UniformDeliverResults, ValidatorUpdate,
 )
 from tendermint_tpu.ops import merkle
+from tendermint_tpu.telemetry import trace
+
+BACKENDS = ("tree", "buckets")
 
 N_BUCKETS = 256   # app-hash buckets; must be a power of two. Tradeoff:
 #                   bucket re-hash cost grows with state/N_BUCKETS, the
@@ -152,26 +166,40 @@ class _TreeStoreView:
 
 class KVStoreApp(BaseApplication):
     def __init__(self, use_native: bool = True):
-        # commit backend selection (ISSUE 16): TM_TPU_STATE_TREE=on
-        # swaps the bucketed accumulator (below) for the authenticated
-        # state tree — per-key proofs bound to app_hash, at the cost of
-        # O(log n) hashing per touched key. The two backends produce
-        # DIFFERENT app hashes by design (pinned by test); all
-        # validators of one chain must agree on the knob.
+        # commit backend selection (ISSUE 16): the authenticated state
+        # tree gives per-key proofs bound to app_hash, at the cost of
+        # O(log n) hashing per touched key; the bucketed accumulator
+        # (below) gives none. The two produce DIFFERENT app hashes by
+        # design (pinned by test). Until InitChain has read the genesis
+        # (init_chain), the node's environment chooses.
         from tendermint_tpu.utils import knobs
+        self._use_native = use_native
+        self._reset_store(knobs.knob_bool("TM_TPU_STATE_TREE"))
+        self.height = 0
+        self.app_hash = b""
+        self.tx_count = 0
+        self._val_updates: list[ValidatorUpdate] = []
+        # pubkey -> power of the ACTIVE set, as the app knows it: seeded
+        # by init_chain, advanced immediately by its own accepted updates
+        # (persistent_dummy mutates app state at DeliverTx time too, so
+        # several val txs in one block see each other's effects)
+        self._validators: dict[bytes, int] = {}
+        self._val_seeded = False
+
+    def _reset_store(self, tree: bool) -> None:
+        """An empty store on the backend asked for."""
         self._tree = None
-        if knobs.knob_bool("TM_TPU_STATE_TREE"):
+        if tree:
             from tendermint_tpu.statetree import StateTree
             self._tree = StateTree()
-            use_native = False  # the tree IS the store; no C++ kv core
         # native core (kvcore.cpp): the plain-kv DeliverTx path, the
         # bucketed accumulator, and the commit hash in C++ — the pure
         # Python fields below stay authoritative when it is absent
         # (TM_TPU_NO_NATIVE / no compiler / use_native=False), and the
         # two implementations are differential-tested for identical
-        # app hashes
+        # app hashes. The tree IS the store: no C++ kv core beside it
         from tendermint_tpu import native
-        self._kvmod = native.kv() if use_native else None
+        self._kvmod = native.kv() if self._use_native and not tree else None
         if self._kvmod is not None:
             self._core = self._kvmod.kv_new()
             self.store = _NativeStoreView(self._kvmod, self._core)
@@ -181,9 +209,6 @@ class KVStoreApp(BaseApplication):
         else:
             self._core = None
             self.store: dict[bytes, bytes] = {}
-        self.height = 0
-        self.app_hash = b""
-        self.tx_count = 0
         # incremental app-hash state (see commit()): keys spread over
         # fixed buckets; each bucket holds an ADDITIVE accumulator (sum
         # of pair digests mod 2^256) so a key change is O(1) regardless
@@ -195,18 +220,31 @@ class KVStoreApp(BaseApplication):
         self._bucket_digest = bytearray(_EMPTY_BUCKET * N_BUCKETS)
         self._pair_digest: dict[bytes, bytes] = {}
         self._dirty: set[bytes] = set()
-        self._val_updates: list[ValidatorUpdate] = []
-        # pubkey -> power of the ACTIVE set, as the app knows it: seeded
-        # by init_chain, advanced immediately by its own accepted updates
-        # (persistent_dummy mutates app state at DeliverTx time too, so
-        # several val txs in one block see each other's effects)
-        self._validators: dict[bytes, int] = {}
-        self._val_seeded = False
 
     def init_chain(self, validators, chain_id: str = "",
                    app_state=None) -> None:
         self._validators = {v.pubkey: v.power for v in validators}
         self._val_seeded = True
+        mine = (app_state or {}).get("kvstore") or {}
+        backend = mine.get("commit_backend")
+        if backend is not None:
+            if backend not in BACKENDS:
+                raise ValueError(f"genesis app_state.kvstore.commit_backend "
+                                 f"{backend!r} is none of {BACKENDS}")
+            if (backend == "tree") != (self._tree is not None):
+                # the chain's choice, not this node's
+                from tendermint_tpu.utils.log import get_logger
+                get_logger("kvstore", chain=chain_id).info(
+                    "the genesis chooses the commit backend; this node's "
+                    "environment said otherwise", genesis=backend,
+                    environment="tree" if self._tree is not None
+                    else "buckets")
+                self._reset_store(backend == "tree")
+        if mine.get("records") is not None:
+            from tendermint_tpu.abci.apps.records import read_records
+            # version 0: what block 1 builds on, so that block 1's
+            # commit rehashes block 1's records and not the store
+            self._load_items(read_records(mine["records"]), 0)
 
     def info(self) -> ResultInfo:
         return ResultInfo(data=f"kvstore:{len(self.store)}",
@@ -358,45 +396,36 @@ class KVStoreApp(BaseApplication):
         return sorted(self.store.items())
 
     def restore_items(self, items, height: int, validators=None) -> bytes:
-        """Install a snapshot's kv state wholesale: reset every core
-        structure, replay the pairs through the normal set path, and
-        compute the app hash via the ordinary commit() machinery (the
-        height bookkeeping lands on exactly `height`). The resulting
-        hash MUST match the snapshot state's app_hash — the caller
-        verifies and aborts on mismatch."""
-        if self._tree is not None:
-            # a fresh tree, replayed through the normal set path; the
-            # commit() below registers version `height` so proofs work
-            # immediately after a state-sync join. A snapshot taken by
-            # a BUCKET-mode chain recomputes to a different app_hash
-            # here and the caller's verify aborts — restoring across
-            # commit backends is a config error, not a silent adopt.
-            from tendermint_tpu.statetree import StateTree
-            self._tree = StateTree()
-            self.store = _TreeStoreView(self._tree)
-            for k, v in items:
-                self.store[bytes(k)] = bytes(v)
-        elif self._core is not None:
-            # a fresh native core is cheaper and simpler than clearing
-            self._core = self._kvmod.kv_new()
-            self.store = _NativeStoreView(self._kvmod, self._core)
-            for k, v in items:
-                self._kvmod.set_one(self._core, bytes(k), bytes(v))
-        else:
-            self.store = {}
-            self._bucket_acc = [0] * N_BUCKETS
-            self._bucket_count = [0] * N_BUCKETS
-            self._bucket_digest = bytearray(_EMPTY_BUCKET * N_BUCKETS)
-            self._pair_digest = {}
-            self._dirty = set()
-            for k, v in items:
-                self.store[bytes(k)] = bytes(v)
-                self._dirty.add(bytes(k))
+        """Install a snapshot's kv state wholesale through the one
+        loader (`_load_items`); the height bookkeeping lands on exactly
+        `height`. The resulting hash MUST match the snapshot state's
+        app_hash — the caller verifies and aborts on mismatch. A
+        snapshot taken by a BUCKET-mode chain recomputes to a different
+        app_hash on the tree and the caller's verify aborts: restoring
+        across commit backends is a config error, not a silent adopt."""
         if validators is not None:
             self._validators = {bytes(pk): int(power)
                                 for pk, power in validators}
             self._val_seeded = True
         self._val_updates = []
+        return self._load_items(items, height)
+
+    def _load_items(self, items, height: int) -> bytes:
+        """The one bulk path into the store, for a snapshot restore and
+        for the genesis' records alike: an empty store on the backend in
+        use, every pair loaded (the tree's one-pass build; the bucket
+        cores' ordinary set path), and the app hash computed by the
+        ordinary commit() machinery as version `height`."""
+        self._reset_store(self._tree is not None)
+        if self._tree is not None:
+            self._tree.load(items)
+        elif self._core is not None:
+            for k, v in items:
+                self._kvmod.set_one(self._core, bytes(k), bytes(v))
+        else:
+            for k, v in items:
+                self.store[bytes(k)] = bytes(v)
+                self._dirty.add(bytes(k))
         self.height = height - 1
         return self.commit()  # height -> `height`, app_hash recomputed
 
@@ -409,22 +438,30 @@ class KVStoreApp(BaseApplication):
             # version's app_hash — the hash the header at height+1
             # carries, which a lite client can certify.
             version = int(height) if height else self.height
-            try:
-                if prove:
-                    value, pf = self._tree.prove(data, version)
-                else:
-                    value, pf = self._tree.get(data, version), None
-            except KeyError as e:
-                return ResultQuery(code=1, key=data, height=version,
-                                   log=str(e))
-            proof_bytes = b""
-            if pf is not None:
-                from tendermint_tpu.statetree import proof_to_bytes
-                proof_bytes = proof_to_bytes(pf)
-            return ResultQuery(
-                key=data, value=value or b"", proof=proof_bytes,
-                height=version,
-                log="exists" if value is not None else "does not exist")
-        value = self.store.get(data, b"")
-        return ResultQuery(key=data, value=value, height=self.height,
-                           log="exists" if value else "does not exist")
+            with trace.span("app.query", req=version, prove=int(prove)):
+                return self._query_version(data, version, prove)
+        with trace.span("app.query", req=self.height, prove=0):
+            value = self.store.get(data, b"")
+            return ResultQuery(key=data, value=value, height=self.height,
+                               log="exists" if value else "does not exist")
+
+    def _query_version(self, data: bytes, version: int,
+                       prove: bool) -> ResultQuery:
+        try:
+            if prove:
+                value, pf = self._tree.prove(data, version)
+            else:
+                value, pf = self._tree.get(data, version), None
+        except KeyError as e:
+            return ResultQuery(code=1, key=data, height=version,
+                               log=str(e))
+        proof_bytes = b""
+        if pf is not None:
+            from tendermint_tpu.statetree import proof_to_bytes
+            proof_bytes = proof_to_bytes(pf)
+        # a present key whose value is empty and an absent key both
+        # carry b"" here: `log` and the proof's `present` tell them apart
+        return ResultQuery(
+            key=data, value=value or b"", proof=proof_bytes,
+            height=version,
+            log="exists" if value is not None else "does not exist")

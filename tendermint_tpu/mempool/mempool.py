@@ -340,7 +340,11 @@ class Mempool:
                 self.txs.remove(el)
                 self._by_hash.pop(hashlib.sha256(tx).digest(), None)
                 _m_removed.labels("committed").inc()
-            # committed txs stay in cache: re-submission is a dup
+            # a committed tx is in the cache from here on, whether this
+            # node had it pending or first saw it in the block: gossip
+            # that delivers it after its block is a dup, not a tx to
+            # propose again over a later write of its key
+            self.cache.push(tx)
         if self.recheck and len(self.txs) > 0:
             self._recheck_txs()
         if telemetry.enabled():

@@ -294,30 +294,32 @@ def tree_proofs_host(items: list[bytes]):
     return root, proofs
 
 
-_SHA_DEVICE_MIN = 512  # payloads below this never pay a device dispatch
+# a longer wave is cut into dispatches of this many payloads: the packed
+# copy of one (4 MB of 1 KB values) stays in the cache where a whole
+# level of a bulk load (a million values) faulted in a gigabyte twice
+_SHA_WAVE_MAX = 4096
 _m_sha_batches = telemetry.counter(
     "merkle_sha_batches_total", "Batched SHA-256 dispatches", ("impl",))
 
 
 def sha256_many_host(payloads: list) -> list[bytes]:
-    """One SHA-256 digest per payload, batched — the statetree's
-    dirty-node rehash plane (every commit hands its dirty leaf and
-    inner payloads here in level-sized waves). Dispatch policy mirrors
-    root_host: a device batch only when jax is ALREADY imported in this
-    process, the payloads share one static length, and the batch is big
-    enough to amortize a dispatch (a device failure there raises); else
-    the native C++ batch kernel when present; else a hashlib loop."""
+    """One SHA-256 digest per payload, batched: the statetree's rehash
+    plane (a commit hands its dirty leaf and inner payloads here in
+    level-sized waves, a bulk load its whole levels, cut into
+    dispatches of at most _SHA_WAVE_MAX). Every wave goes to the native
+    C++ batch kernel when present, else a hashlib loop; never to the
+    device, whatever the process has imported: on a TPU v5e
+    `sha256_many_device` lost to the native kernel at every payload
+    length and wave size a store has, 1.4 to 8.6 times, plus 0.3-5 s a
+    shape for its first call (PERF.md, PR 35; scripts/sha_waves.py)."""
     n = len(payloads)
     if n == 0:
         return []
-    if n >= _SHA_DEVICE_MIN:
-        import sys
-        if "jax" in sys.modules:
-            length = len(payloads[0])
-            if all(len(p) == length for p in payloads):
-                if telemetry.enabled():
-                    _m_sha_batches.labels("device").inc()
-                return _sha256_many_device(payloads, n, length)
+    if n > _SHA_WAVE_MAX:
+        out = []
+        for i in range(0, n, _SHA_WAVE_MAX):
+            out += sha256_many_host(payloads[i:i + _SHA_WAVE_MAX])
+        return out
     from tendermint_tpu import native
     out = native.sha256_batch([bytes(p) for p in payloads])
     if out is not None:
@@ -330,14 +332,20 @@ def sha256_many_host(payloads: list) -> list[bytes]:
     return [sha(p).digest() for p in payloads]
 
 
-def _sha256_many_device(payloads, n: int, length: int):
-    """uint8[n, L] batch through the jitted ops.sha256.hash_fixed. Rows
+def sha256_many_device(payloads: list) -> list[bytes]:
+    """`sha256_many_host`'s digests of payloads of one length, through
+    the jitted ops.sha256.hash_fixed on the device. Nothing in the
+    program sends a wave here (see above); chip_smoke.py and
+    scripts/sha_waves.py call it to check and to time the plane. Rows
     are padded to a power of two so the compiled shapes stay bounded
     (one per bucket and payload length, not one per wave)."""
     from tendermint_tpu.ops import sha256
+    n, length = len(payloads), len(payloads[0])
     rows = np.zeros((_padded_size(n), length), np.uint8)
     rows[:n] = np.frombuffer(b"".join(payloads), np.uint8).reshape(
         n, length)
+    if telemetry.enabled():
+        _m_sha_batches.labels("device").inc()
     out = np.asarray(sha256.hash_fixed_jit(rows))
     return [out[i].tobytes() for i in range(n)]
 

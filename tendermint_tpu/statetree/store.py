@@ -48,6 +48,9 @@ _m_dirty_leaves = telemetry.histogram(
 _m_refresh = telemetry.histogram(
     "statetree_root_refresh_seconds",
     "Dirty-subtree rehash + root recompute per commit")
+_m_proofs = telemetry.counter(
+    "statetree_proofs_total",
+    "State proofs built by StateTree.prove", ("kind",))
 _m_proof_bytes = telemetry.histogram(
     "statetree_proof_bytes",
     "Encoded state-proof size", buckets=telemetry.POW2_BUCKETS)

@@ -27,12 +27,15 @@ consensus thread builds the next block.
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import threading
 import time
-from typing import Iterator, Optional, Tuple
+from typing import Iterable, Iterator, Optional, Tuple
 
+from tendermint_tpu import telemetry
 from tendermint_tpu.ops import merkle
+from tendermint_tpu.telemetry import trace
 from tendermint_tpu.statetree.proof import ProofError, StateProof
 from tendermint_tpu.statetree.store import (
     EMPTY_SUBROOT,
@@ -41,6 +44,7 @@ from tendermint_tpu.statetree.store import (
     NodeStore,
     _m_dirty_leaves,
     _m_nodes,
+    _m_proofs,
     _m_refresh,
     final_hash,
 )
@@ -58,6 +62,10 @@ def _first_diff_bit(a: bytes, b: bytes) -> int:
         if x:
             return (i << 3) + 8 - x.bit_length()
     raise ValueError("identical key hashes")
+
+
+# an inner node's payload starts with its tag and its split bit
+_INNER_HEAD = [b"\x01" + bit.to_bytes(2, "big") for bit in range(256)]
 
 
 class StateTree:
@@ -133,6 +141,94 @@ class StateTree:
         else:
             node.left = self._splice(node.left, kh, key, value, d)
         return node
+
+    def load(self, items: Iterable[Tuple[bytes, bytes]]) -> int:
+        """Build the tree of `items` in one pass, into a tree that
+        holds nothing: the same nodes and hashes, bit for bit, as
+        `set` on every pair and a `commit` give (a key given twice
+        keeps its last value, as `set` would), at a few microseconds a
+        record where those cost tens. The structure is a function of
+        the key set alone, so it is read off the SORTED key hashes: the
+        inner node between two neighbours splits at their first
+        differing bit, and an inner node is the parent of every
+        neighbouring split with a larger bit (a Cartesian tree, built
+        with one stack). Leaves, then inner nodes by height, are hashed
+        in whole waves through ops/merkle's sha256_many_host. Every
+        node comes out hashed, so the `commit` that follows only
+        registers the version. Returns the number of keys."""
+        with self._lock:
+            if self._root is not None or self._fresh:
+                raise ValueError("load() needs an empty tree")
+            t0 = time.perf_counter()
+            pairs = {bytes(k): bytes(v) for k, v in items}
+            n = len(pairs)
+            if not n:
+                return 0
+            # the collector would walk a million new nodes again and
+            # again while they are made, and find nothing
+            collecting = gc.isenabled()
+            gc.disable()
+            try:
+                self._root = self._build(pairs)
+            finally:
+                if collecting:
+                    gc.enable()
+            self._n = n
+            if telemetry.enabled():
+                trace.complete("tree.load", t0, time.perf_counter(),
+                               records=n,
+                               bytes=sum(map(len, pairs.values())))
+            return n
+
+    @staticmethod
+    def _build(pairs: dict):
+        keys = list(pairs)
+        values = list(pairs.values())
+        khs = merkle.sha256_many_host(keys)
+        vhs = merkle.sha256_many_host(values)
+        order = sorted(range(len(keys)), key=khs.__getitem__)
+        leaf_hashes = merkle.sha256_many_host(
+            [b"\x00" + khs[i] + vhs[i] for i in order])
+        del vhs
+        leaves = [Leaf(khs[i], keys[i], values[i], h)
+                  for i, h in zip(order, leaf_hashes)]
+        del keys, values, leaf_hashes, order, khs
+        # (inner awaiting its right subtree, height of its left one),
+        # bits increasing from the bottom of the stack to its top
+        spine: list = []
+        waves: list = []            # waves[h - 1]: the inners of height h
+
+        def close(node, height, down_to):
+            # `node` is whole: it is the right subtree of every open
+            # inner that splits past `down_to`
+            while spine and spine[-1][0].bit > down_to:
+                inner, left_height = spine.pop()
+                inner.right = node
+                height = 1 + max(left_height, height)
+                if height > len(waves):
+                    waves.append([])
+                waves[height - 1].append(inner)
+                node = inner
+            return node, height
+
+        from_bytes = int.from_bytes
+        prev = from_bytes(leaves[0].kh, "big")
+        for i in range(1, len(leaves)):
+            cur = from_bytes(leaves[i].kh, "big")
+            if cur == prev:
+                raise ValueError("identical key hashes")
+            bit = 256 - (prev ^ cur).bit_length()
+            node, height = close(leaves[i - 1], 0, bit)
+            spine.append((Inner(bit, node, None), height))
+            prev = cur
+        root, _ = close(leaves[-1], 0, -1)
+        del leaves
+        for wave in waves:
+            for nd, h in zip(wave, merkle.sha256_many_host(
+                    [_INNER_HEAD[nd.bit] + nd.left.hash + nd.right.hash
+                     for nd in wave])):
+                nd.hash = h
+        return root
 
     def delete(self, key: bytes) -> bool:
         key = bytes(key)
@@ -216,7 +312,7 @@ class StateTree:
                     lf.hash = h
             for height in sorted(k for k in by_height if k > 0):
                 nodes = by_height[height]
-                payloads = [b"\x01" + nd.bit.to_bytes(2, "big")
+                payloads = [_INNER_HEAD[nd.bit]
                             + nd.left.hash + nd.right.hash
                             for nd in nodes]
                 for nd, h in zip(nodes,
@@ -228,9 +324,12 @@ class StateTree:
             app_hash = final_hash(self._n, sub)
             self._fresh.clear()
             self.store.put(version, self._root, self._n, app_hash)
-            _m_refresh.observe(time.perf_counter() - t0)
+            t1 = time.perf_counter()
+            _m_refresh.observe(t1 - t0)
             _m_dirty_leaves.observe(len(leaves))
             _m_nodes.set(max(0, 2 * self._n - 1))
+            trace.complete("tree.commit", t0, t1, req=int(version),
+                           dirty_leaves=len(leaves))
             return app_hash
 
     def _collect_dirty(self, node, by_height: dict) -> int:
@@ -261,6 +360,7 @@ class StateTree:
         with self._lock:
             v = self._version(version)
             if v.root is None:
+                _m_proofs.labels("absence").inc()
                 return None, StateProof(kh, 0, [], present=False)
             steps = []
             node = v.root
@@ -275,8 +375,10 @@ class StateTree:
                     steps.append((node.bit, node.right.hash))
                     node = node.left
             if node.kh == kh:
+                _m_proofs.labels("inclusion").inc()
                 return node.value, StateProof(
                     kh, v.n_keys, steps, present=True)
+            _m_proofs.labels("absence").inc()
             return None, StateProof(
                 kh, v.n_keys, steps, present=False,
                 other_key_hash=node.kh,

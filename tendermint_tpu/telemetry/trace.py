@@ -85,6 +85,14 @@ SPANS = {
     "apply.exec": "apply and Merkle",
     "apply.commit": "apply and Merkle",
     "apply.save": "apply and Merkle",
+    # the authenticated state tree and the read path: one event per
+    # StateTree.commit (nests in apply.commit; req = the version), one
+    # per bulk load of a store (InitChain's records, a snapshot
+    # restore), one per abci_query that reaches the KVStore (req = the
+    # version served)
+    "tree.commit": "state tree and read path",
+    "tree.load": "state tree and read path",
+    "app.query": "state tree and read path",
     # one interval per consensus step (consensus/state._new_step), one
     # instant per committed block and per timeout that moved the state
     "cs:NEW_HEIGHT": "gossip and consensus rounds",

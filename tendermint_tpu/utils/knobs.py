@@ -226,8 +226,10 @@ CATALOG: tuple[Knob, ...] = (
          "(statetree/, docs/state.md) — app_hash is a critbit Merkle "
          "root, per-key inclusion/absence proofs bind values to "
          "certified headers; off = bucketed accumulator (no proofs). "
-         "Chain-level: every validator must agree, the two backends "
-         "hash differently by design.",
+         "Chain-level: the two backends hash differently by design, so "
+         "a genesis that states app_state.kvstore.commit_backend wins "
+         "over this knob on every node; the knob decides only where "
+         "the genesis says nothing.",
          "abci/apps/kvstore.py"),
     # -- shard plane -------------------------------------------------------
     Knob("TM_TPU_SHARDS", "int", "0 (off)", "base.shards",

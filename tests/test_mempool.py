@@ -109,6 +109,18 @@ def test_update_removes_committed_and_keeps_cache():
         mp.check_tx(txs[0])
 
 
+def test_a_tx_first_seen_in_its_block_is_a_dup_when_gossip_brings_it():
+    mp, _ = make_mempool()
+    late = b"\x00\x07"
+    # another node proposed it: this one never had it pending
+    mp.lock()
+    mp.update(1, [late])
+    mp.unlock()
+    with pytest.raises(TxAlreadyInCache):
+        mp.check_tx(late)
+    assert mp.size() == 0 and mp.reap(-1) == []
+
+
 def test_update_recheck_drops_newly_invalid():
     app = CounterApp(serial=True)
     conns = AppConns(local_client_creator(app))

@@ -56,8 +56,10 @@ def test_deployment_smoke_boot_certify_read_crash_restart(tmp_path):
         # the replica (every replica-served read is verifiable)
         val = d.clients(kind="validator")[0]
         val.call("broadcast_tx_commit", tx=b"dk=dv".hex())
-        assert _wait(lambda: certified(
-            val.call("status")["latest_block_height"])), \
+        # the header AFTER the write's block binds its value: a read
+        # served at the write's own height proves the state before it
+        wrote_by = val.call("status")["latest_block_height"]
+        assert _wait(lambda: certified(wrote_by + 1)), \
             d.log_tail("replica0")
         doc = rep.call("replica_read", key=b"dk".hex())
         assert bytes.fromhex(doc["value"]) == b"dv"
