@@ -37,7 +37,9 @@ process-wide in ops/ed25519.predecomp_stats().
 
 from __future__ import annotations
 
+import functools
 import itertools
+import os
 import threading
 import time
 from typing import Sequence
@@ -103,6 +105,11 @@ _m_batch_sigs = telemetry.counter(
     "Signatures dispatched to the device, by the form their batch "
     "arrived in: columns (a SigColumns, prepared in place) or items "
     "(triples, walked one by one)", ("form",))
+_m_prep_lanes = telemetry.counter(
+    "verifier_prep_lanes_total",
+    "Lanes of natively prepared device batches, by where their "
+    "SHA-512 ran: sharded (the batch cut over several threads of the "
+    "native prep) or inline (the caller's thread alone)", ("how",))
 # the verifier's request id in the span recorder: every span of one
 # dispatch (telemetry/trace.py) carries its number as `req`
 _dispatch_seq = itertools.count(1)
@@ -112,6 +119,29 @@ _dispatch_seq = itertools.count(1)
 # came from a sweep on an earlier host; not re-measured on the attached
 # chip.
 BATCH_CHUNK = 8192
+
+# The native prep's SHA-512 loop runs on several threads once a batch
+# gives each this many lanes, and on no more than the cap. Both from
+# scripts/prep_threads.py on the chip's host (PERF.md section 6, PR 36).
+MIN_LANES_A_THREAD = 2048
+MAX_PREP_THREADS = 8
+
+
+@functools.cache
+def _usable_cores() -> int:
+    """Cores this process may run on, read once."""
+    return len(os.sched_getaffinity(0))
+
+
+def prep_threads(n: int, cores: int | None = None) -> int:
+    """Threads for the native prep of an n-lane batch: from the lanes
+    and the cores the process may run on (one is left to the rest of
+    the process), never from a knob. 1 = the caller's thread alone,
+    which is what a small batch and a one-core host get."""
+    if cores is None:
+        cores = _usable_cores()
+    return max(1, min(cores - 1, n // MIN_LANES_A_THREAD,
+                      MAX_PREP_THREADS))
 
 
 _pool_lock = threading.Lock()
@@ -318,11 +348,15 @@ class BatchVerifier:
         from tendermint_tpu import native
         with trace.span("verify.prep", n=n):
             prep, form = None, "columns"
+            threads = prep_threads(n)
             if isinstance(items, SigColumns):
                 prep = native.prep_columns(items.pk, items.sigs,
-                                           items.msgs, items.idx)
+                                           items.msgs, items.idx, threads)
             if prep is None:
-                prep, form = native.prep_items(items), "items"
+                prep, form = native.prep_items(items, threads), "items"
+            if prep is not None and telemetry.enabled():
+                _m_prep_lanes.labels(
+                    "sharded" if threads > 1 else "inline").inc(n)
         if prep is not None:
             from tendermint_tpu.ops import ed25519
             if not self._mesh_resolved:

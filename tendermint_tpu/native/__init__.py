@@ -42,10 +42,11 @@ class _Ext:
 
     def __init__(self, name: str, src: str, deps: tuple = (),
                  opt: str = "-O2", std: str = "c++17",
-                 cpython: bool = True, bind=None):
+                 cpython: bool = True, bind=None, extra: tuple = ()):
         # deps: sources the src #includes. std: per extension — only
         # kvcore needs c++20 (transparent unordered_map lookup). bind:
         # declares a ctypes library's signatures once it is open.
+        # extra: further flags (prep's -pthread).
         self.name = name
         self.src = src
         self.deps = deps
@@ -53,13 +54,15 @@ class _Ext:
         self.std = std
         self.cpython = cpython
         self.bind = bind
+        self.extra = extra
         self.handle = None
         self.path: Optional[str] = None
         self._tried = False
         self._error: Optional[BaseException] = None
 
     def _flags(self) -> list:
-        return [self.opt, "-shared", "-fPIC", f"-std={self.std}"]
+        return [self.opt, "-shared", "-fPIC", f"-std={self.std}",
+                *self.extra]
 
     def lib_path(self) -> str:
         """<dir>/<name>.<hash of flags + source bytes>.so"""
@@ -184,9 +187,10 @@ _HOSTOPS = _Ext("_hostops", _src("hostops.cpp"), opt="-O3", cpython=False,
 _CODEC = _Ext("_tmcodec", _src("codec.cpp"))
 # Batched Ed25519 verify-prep + signing phases: takes the verifier's
 # items list and returns the device-bound arrays in one call (GIL
-# released for the SHA-512 loop). prep.cpp #includes hostops.cpp.
+# released for the SHA-512 loop, which may run on several std::threads).
+# prep.cpp #includes hostops.cpp.
 _PREP = _Ext("_tmprep", _src("prep.cpp"), deps=(_src("hostops.cpp"),),
-             opt="-O3")
+             opt="-O3", extra=("-pthread",))
 # Native KVStore core.
 _KV = _Ext("_tmkv", _src("kvcore.cpp"), deps=(_src("hostops.cpp"),),
            opt="-O3", std="c++20")
@@ -237,18 +241,21 @@ def _prep_arrays(out, n: int):
     return as_mat(pk_b), as_mat(rb_b), as_mat(s_b), as_mat(h_b), pre
 
 
-def prep_items(items):
+def prep_items(items, threads: int = 1):
     """One-call verify prep: items [(pk, msg, sig), ...] ->
     (pk u8[N,32], R u8[N,32], s u8[N,32], h u8[N,32], pre bool[N])
     numpy views, or None when unavailable / when the batch needs the
-    general path (secp256k1 keys, non-bytes members)."""
+    general path (secp256k1 keys, non-bytes members). With `threads`
+    > 1 the SHA-512 loop runs over that many contiguous shards of the
+    batch at once, on threads that end before the call returns; the
+    arrays are the same, bit for bit."""
     mod = _prep()
     if mod is None:
         return None
-    return _prep_arrays(mod.prep_items(items), len(items))
+    return _prep_arrays(mod.prep_items(items, threads), len(items))
 
 
-def prep_columns(pk, sigs, msgs, idx):
+def prep_columns(pk, sigs, msgs, idx, threads: int = 1):
     """prep_items for a batch held as columns (types/sigcolumns.py):
     lane i is (pk[i], msgs[idx[i]], sigs[i]). The same five arrays, bit
     for bit, or None when unavailable / when a member is not bytes."""
@@ -258,7 +265,8 @@ def prep_columns(pk, sigs, msgs, idx):
     import numpy as np
     return _prep_arrays(
         mod.prep_columns(np.ascontiguousarray(pk, np.uint8), sigs, msgs,
-                         np.ascontiguousarray(idx, np.int32)), len(sigs))
+                         np.ascontiguousarray(idx, np.int32), threads),
+        len(sigs))
 
 
 def _pack(items: List[bytes]):

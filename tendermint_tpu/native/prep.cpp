@@ -11,9 +11,12 @@
 //
 // The SHA-512 loop runs with the GIL RELEASED over private copies of
 // the inputs, so a node pipelining several commits overlaps hashing
-// with device fetches. SHA-512 itself is OpenSSL's when libcrypto.so.3
-// is loadable at runtime (AVX2 assembly, ~3x the portable block
-// function) and the portable Sha512 from hostops.cpp otherwise.
+// with device fetches; given `threads` > 1 it runs over that many
+// contiguous shards of the batch at once (hash_lanes: threads that
+// live inside the one call). SHA-512 itself is OpenSSL's when
+// libcrypto.so.3 is loadable at runtime (AVX2 assembly, ~3x the
+// portable block function) and the portable Sha512 from hostops.cpp
+// otherwise.
 //
 // Returns None for input shapes the fast path does not cover —
 // secp256k1 keys (33-byte SEC1, host-verified by design), non-bytes
@@ -24,6 +27,9 @@
 #include <Python.h>
 
 #include <dlfcn.h>
+
+#include <system_error>
+#include <thread>
 
 #include "hostops.cpp"
 
@@ -112,9 +118,52 @@ struct PrepOut {
     }
 };
 
+// Pass 2 of a verify prep, GIL released: h = SHA512(R || A || M) mod L
+// for every lane that passed its precheck; msg_of(i) gives lane i's
+// message where it lies. With threads > 1, [0, n) is cut into that many
+// equal contiguous shards, each hashed by a std::thread of its own, and
+// all are joined before this returns: no pool and no thread that
+// outlives the call, so the process forks as safely after a prep as
+// before it. A lane reads its own inputs and writes its own row of hb;
+// the shards share nothing else. A shard whose thread cannot be started
+// is hashed here, on the calling thread.
+template <class MsgOf>
+void hash_lanes(const PrepOut &out, Py_ssize_t n, long threads,
+                const MsgOf &msg_of) {
+    auto shard = [&](Py_ssize_t lo, Py_ssize_t hi) {
+        for (Py_ssize_t i = lo; i < hi; i++) {
+            if (!out.pre[i]) continue;
+            uint8_t digest[64];
+            size_t mlen;
+            const uint8_t *m = msg_of(i, &mlen);
+            sha512_ram(out.rb + 32 * i, out.pk + 32 * i, m, mlen, digest);
+            reduce512_mod_l(digest, out.hb + 32 * i);
+        }
+    };
+    if (threads > n) threads = (long)n;
+    if (threads <= 1) {
+        shard(0, n);
+        return;
+    }
+    std::vector<std::thread> workers;
+    workers.reserve((size_t)threads);
+    for (long t = 0; t < threads; t++) {
+        Py_ssize_t lo = n * t / threads, hi = n * (t + 1) / threads;
+        try {
+            workers.emplace_back(shard, lo, hi);
+        } catch (const std::system_error &) {
+            shard(lo, hi);
+        }
+    }
+    for (std::thread &w : workers) w.join();
+}
+
 }  // namespace
 
-static PyObject *prep_items(PyObject *self, PyObject *arg) {
+static PyObject *prep_items(PyObject *self, PyObject *args) {
+    PyObject *arg;
+    long threads = 1;
+    if (!PyArg_ParseTuple(args, "O|l", &arg, &threads)) return nullptr;
     PyObject *seq = PySequence_Fast(arg, "prep_items expects a sequence");
     if (seq == nullptr) return nullptr;
     Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
@@ -178,22 +227,17 @@ static PyObject *prep_items(PyObject *self, PyObject *arg) {
         Py_RETURN_NONE;
     }
 
-    // Pass 2 (GIL released): h = SHA512(R || A || M) mod L
     Py_BEGIN_ALLOW_THREADS
-    for (Py_ssize_t i = 0; i < n; i++) {
-        if (!out.pre[i]) continue;
-        uint8_t digest[64];
-        sha512_ram(out.rb + 32 * i, out.pk + 32 * i,
-                   arena.data() + moff[i],
-                   (size_t)(moff[i + 1] - moff[i]), digest);
-        reduce512_mod_l(digest, out.hb + 32 * i);
-    }
+    hash_lanes(out, n, threads, [&](Py_ssize_t i, size_t *mlen) {
+        *mlen = (size_t)(moff[i + 1] - moff[i]);
+        return (const uint8_t *)arena.data() + moff[i];
+    });
     Py_END_ALLOW_THREADS
     return out.pack();
 }
 
-// prep_columns(pk, sigs, msgs, idx): prep_items for a batch held as
-// columns (types/sigcolumns.py) — pk a contiguous n*32-byte buffer, sigs
+// prep_columns(pk, sigs, msgs, idx[, threads]): prep_items for a batch
+// held as columns (types/sigcolumns.py) — pk a contiguous n*32-byte buffer, sigs
 // a sequence of n bytes objects, msgs the batch's sign-bytes and idx a
 // contiguous int32[n] buffer naming each lane's. The same prechecks and
 // the same five arrays, bit for bit; the columns are read where they
@@ -202,7 +246,9 @@ static PyObject *prep_items(PyObject *self, PyObject *arg) {
 static PyObject *prep_columns(PyObject *, PyObject *args) {
     Py_buffer pkv, idxv;
     PyObject *sigs_o, *msgs_o;
-    if (!PyArg_ParseTuple(args, "y*OOy*", &pkv, &sigs_o, &msgs_o, &idxv))
+    long threads = 1;
+    if (!PyArg_ParseTuple(args, "y*OOy*|l", &pkv, &sigs_o, &msgs_o, &idxv,
+                          &threads))
         return nullptr;
     PyObject *sigs = nullptr, *msgs = nullptr, *result = nullptr;
     PrepOut out;
@@ -258,17 +304,12 @@ static PyObject *prep_columns(PyObject *, PyObject *args) {
         goto release;
     }
 
-    // Pass 2 (GIL released): h = SHA512(R || A || M) mod L
     Py_BEGIN_ALLOW_THREADS
-    for (Py_ssize_t i = 0; i < n; i++) {
-        if (!out.pre[i]) continue;
-        uint8_t digest[64];
+    hash_lanes(out, n, threads, [&](Py_ssize_t i, size_t *mlen) {
         PyObject *mo = held[idx[i]];
-        sha512_ram(out.rb + 32 * i, out.pk + 32 * i,
-                   (const uint8_t *)PyBytes_AS_STRING(mo),
-                   (size_t)PyBytes_GET_SIZE(mo), digest);
-        reduce512_mod_l(digest, out.hb + 32 * i);
-    }
+        *mlen = (size_t)PyBytes_GET_SIZE(mo);
+        return (const uint8_t *)PyBytes_AS_STRING(mo);
+    });
     Py_END_ALLOW_THREADS
     result = out.pack();
 
@@ -484,12 +525,13 @@ static PyMethodDef prep_methods[] = {
      "(renc n*32, pks n*32, msgs, r n*32, a n*32) -> signatures n*64"},
     {"merkle_root_items", merkle_root_items, METH_O,
      "list[bytes] -> 32-byte merkle root (same spec as ops/merkle)"},
-    {"prep_items", prep_items, METH_O,
-     "items [(pk, msg, sig), ...] -> (pk, R, s, h, pre) byte buffers, "
-     "or None when the batch needs the general Python path."},
+    {"prep_items", prep_items, METH_VARARGS,
+     "(items [(pk, msg, sig), ...], threads=1) -> (pk, R, s, h, pre) byte "
+     "buffers, or None when the batch needs the general Python path; the "
+     "hashing runs on `threads` threads that end with the call."},
     {"prep_columns", prep_columns, METH_VARARGS,
-     "(pk n*32, sigs, msgs, idx int32[n]) -> what prep_items returns for "
-     "the triples (pk[i], msgs[idx[i]], sigs[i])."},
+     "(pk n*32, sigs, msgs, idx int32[n], threads=1) -> what prep_items "
+     "returns for the triples (pk[i], msgs[idx[i]], sigs[i])."},
     {nullptr, nullptr, 0, nullptr},
 };
 

@@ -215,6 +215,144 @@ def test_prep_items_differential_vs_python():
     assert empty is not None and empty[4].shape == (0,)
 
 
+# ----------------------------------- the prep's hashing on several threads --
+
+L_ORDER = 2 ** 252 + 27742317777372353535851937790883648493
+PREP_SIZES = (0, 1, 1_023, 2_047, 2_048, 2_049, 10_000)
+PREP_THREADS = (1, 2, 3, 8)
+
+
+def shard_edges(n):
+    """Both lanes at every boundary between shards of [0, n) for each
+    thread count the tests use, and the batch's two ends."""
+    edges = {0, n - 1}
+    for threads in PREP_THREADS:
+        for t in range(1, threads):
+            edges.update((n * t // threads - 1, n * t // threads))
+    return sorted(i for i in edges if 0 <= i < n)
+
+
+_prep_batches = {}
+
+
+def prep_batch(n, form, messages):
+    """n lanes of random keys and signatures with s < L, a 118-byte
+    message each (`distinct`) or one per 64 lanes (`shared`), and at the
+    shards' edges lanes that fail each precheck in turn. -> (the call's
+    arguments in `form`, the five arrays by a plain reference)."""
+    key = (n, form, messages)
+    if key in _prep_batches:
+        return _prep_batches[key]
+    import random
+
+    import numpy as np
+    rng = random.Random(n)
+    lanes_a_msg = 1 if messages == "distinct" else 64
+    msgs = [rng.randbytes(118) for _ in range(-(-n // lanes_a_msg) or 1)]
+    idx = [i // lanes_a_msg for i in range(n)]
+    pks = [rng.randbytes(32) for _ in range(n)]
+    sigs = [rng.randbytes(63) + b"\x00" for _ in range(n)]
+    # columns hold keys as an n x 32 array: only a triple's can be short
+    faults = ("short_sig", "s_ge_L", "long_sig") + (
+        ("short_key",) if form == "items" else ())
+    for k, i in enumerate(shard_edges(n) if n >= 16 else ()):
+        fault = faults[k % len(faults)]
+        if fault == "short_sig":
+            sigs[i] = sigs[i][:63]
+        elif fault == "long_sig":
+            sigs[i] = sigs[i] + b"\x00"
+        elif fault == "s_ge_L":
+            sigs[i] = sigs[i][:32] + L_ORDER.to_bytes(32, "little")
+        else:
+            pks[i] = pks[i][:31]
+    want = [bytearray(32 * n) for _ in range(4)] + [bytearray(n)]
+    for i in range(n):
+        pk, sig, m = pks[i], sigs[i], msgs[idx[i]]
+        if len(pk) != 32 or len(sig) != 64 or \
+                int.from_bytes(sig[32:], "little") >= L_ORDER:
+            continue
+        h = int.from_bytes(hashlib.sha512(sig[:32] + pk + m).digest(),
+                           "little") % L_ORDER
+        for row, val in zip(want, (pk, sig[:32], sig[32:],
+                                   h.to_bytes(32, "little"))):
+            row[32 * i:32 * i + 32] = val
+        want[4][i] = 1
+    if form == "items":
+        args = ([(pks[i], msgs[idx[i]], sigs[i]) for i in range(n)],)
+    else:
+        args = (np.frombuffer(b"".join(pks), np.uint8).reshape(n, 32),
+                sigs, msgs, np.array(idx, np.int32))
+    _prep_batches[key] = args, [bytes(w) for w in want]
+    return _prep_batches[key]
+
+
+def prep_call(form):
+    return native.prep_items if form == "items" else native.prep_columns
+
+
+@pytest.mark.parametrize("messages", ["distinct", "shared"])
+@pytest.mark.parametrize("n", PREP_SIZES)
+@pytest.mark.parametrize("threads", PREP_THREADS)
+@pytest.mark.parametrize("form", ["items", "columns"])
+def test_prep_on_several_threads_is_the_prep_on_one(form, threads, n,
+                                                    messages):
+    """Whatever the thread count and wherever the shards' edges fall,
+    the five arrays are one thread's byte for byte, and what a plain
+    reference gives: hashlib's SHA-512(R || A || M) as an integer mod L
+    for a lane that passes the three length checks and s < L, zeros and
+    pre = 0 for one that does not."""
+    if native._prep() is None:
+        pytest.skip("prep extension unavailable")
+    args, want = prep_batch(n, form, messages)
+    got = prep_call(form)(*args, threads)
+    one = prep_call(form)(*args, 1)
+    assert got is not None and one is not None
+    for g, o in zip(got, one):
+        assert g.dtype == o.dtype and g.shape == o.shape
+        assert g.tobytes() == o.tobytes()
+    for g, w in zip(got[:4], want):
+        assert g.tobytes() == w
+    assert got[4].tolist() == [bool(b) for b in want[4]]
+    if n >= 16:
+        assert not got[4][shard_edges(n)].any()
+        assert got[4].sum() == n - len(shard_edges(n))
+
+
+@pytest.mark.parametrize("form", ["items", "columns"])
+def test_prep_on_several_threads_still_hands_back_what_it_does_not_cover(
+        form):
+    """A member that is no bytes object, in the last shard's last lane:
+    None, as on one thread, for the general path to take."""
+    if native._prep() is None:
+        pytest.skip("prep extension unavailable")
+    args, _ = prep_batch(2_049, form, "shared")
+    if form == "items":
+        items = list(args[0])
+        items[-1] = (items[-1][0], items[-1][1], bytearray(items[-1][2]))
+        args = (items,)
+    else:
+        sigs = list(args[1])
+        sigs[-1] = bytearray(sigs[-1])
+        args = (args[0], sigs, args[2], args[3])
+    for threads in (1, 2, 8):
+        assert prep_call(form)(*args, threads) is None
+
+
+@pytest.mark.parametrize("form", ["items", "columns"])
+def test_no_thread_of_a_prep_outlives_its_call(form):
+    """The hashing threads are joined inside the call: the process has
+    as many tasks after it as before, so it forks as safely."""
+    if native._prep() is None:
+        pytest.skip("prep extension unavailable")
+    args, want = prep_batch(10_000, form, "distinct")
+    tasks = lambda: len(os.listdir("/proc/self/task"))
+    before = tasks()
+    for threads in (8, 3, 8):
+        got = prep_call(form)(*args, threads)
+        assert got[3].tobytes() == want[3]
+        assert tasks() == before
+
+
 def test_kvcore_differential_vs_python_app():
     """Native KV core vs the pure-Python KVStoreApp: identical app
     hashes, store contents, and results hashes across mixed batches,

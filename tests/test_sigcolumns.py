@@ -12,7 +12,9 @@ import pytest
 
 from tendermint_tpu import native, telemetry
 from tendermint_tpu.types.sigcolumns import SigColumns
-from tendermint_tpu.models.verifier import BatchVerifier
+from tendermint_tpu.models.verifier import (
+    MAX_PREP_THREADS as MAX_T, MIN_LANES_A_THREAD as MIN_LANES,
+    BatchVerifier)
 from tendermint_tpu.types import (BlockID, Commit, PartSetHeader, PrivKey,
                                   Validator, ValidatorSet, Vote)
 from tendermint_tpu.types.keys import verify_any
@@ -316,6 +318,72 @@ def test_verify_async_on_columns_and_on_their_list(net, n, route):
         valset.check_commit_results(from_columns, power)
 
 
+def test_a_prep_forced_onto_two_threads_gives_the_same_verdicts(
+        net, monkeypatch):
+    """The thread count is a rule over lanes and cores; forced to 2 on a
+    toy batch (which the rule would hash inline), the jnp kernels return
+    the verdicts of the verifier left alone, on both forms."""
+    from tendermint_tpu.models import verifier as verifier_mod
+    tampered = {0, 5, N_DEVICE - 1}
+    valset, commit = signed_commit(net, tampered=tampered)
+    items, _ = valset.commit_verification_items(CHAIN, BLOCK, HEIGHT,
+                                                commit)
+    seen = []
+    real = native.prep_columns, native.prep_items
+
+    def watch(fn):
+        def call(*args):
+            seen.append(args[-1])
+            return fn(*args)
+        return call
+    monkeypatch.setattr(native, "prep_columns", watch(real[0]))
+    monkeypatch.setattr(native, "prep_items", watch(real[1]))
+    verifier = BatchVerifier("jax", mesh="off")
+    try:
+        assert verifier_mod.prep_threads(N_DEVICE) == 1
+        alone = [verifier.verify_async(form)()
+                 for form in (items, list(items))]
+        monkeypatch.setattr(verifier_mod, "prep_threads", lambda n: 2)
+        forced = [verifier.verify_async(form)()
+                  for form in (items, list(items))]
+    finally:
+        verifier.close()
+    assert seen == [1, 1, 2, 2]
+    for got in alone + forced:
+        assert got.tolist() == [i not in tampered for i in range(N_DEVICE)]
+
+
+# lanes, usable cores -> threads of the native prep. One core is left to
+# the rest of the process, so two cores hash inline as one does.
+PREP_THREADS_TABLE = [
+    (0, 13, 1), (1, 13, 1), (256, 13, 1), (2_000, 13, 1),
+    (2 * MIN_LANES - 1, 13, 1), (2 * MIN_LANES, 13, 2),
+    (3 * MIN_LANES, 13, 3), (8_192, 13, min(8_192 // MIN_LANES, MAX_T)),
+    (10_000, 13, min(10_000 // MIN_LANES, MAX_T)), (32_768, 13, MAX_T),
+    (10 ** 7, 13, MAX_T), (10 ** 7, 64, MAX_T),
+    (32_768, 4, 3), (32_768, 3, 2), (10_000, 2, 1), (32_768, 2, 1),
+] + [(n, 1, 1) for n in (0, 1, 2_048, 10_000, 32_768, 10 ** 7)]
+
+
+@pytest.mark.parametrize("lanes, cores, threads", PREP_THREADS_TABLE)
+def test_the_preps_thread_count_follows_lanes_and_cores(lanes, cores,
+                                                        threads):
+    from tendermint_tpu.models.verifier import prep_threads
+    assert prep_threads(lanes, cores) == threads
+    assert 1 <= threads <= max(1, cores - 1)
+
+
+def test_the_cores_are_the_processs_own_and_read_once(monkeypatch):
+    import os
+    from tendermint_tpu.models import verifier as verifier_mod
+    cores = len(os.sched_getaffinity(0))
+    assert verifier_mod._usable_cores() == cores
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: {0})
+    assert verifier_mod._usable_cores() == cores
+    assert verifier_mod.prep_threads(10 ** 7) == min(MAX_T,
+                                                     max(1, cores - 1))
+
+
 @pytest.mark.parametrize("control", [None, "accept_all", "truncate"])
 def test_the_harness_tap_on_verify_async_sees_columns(net, control):
     """benchmark/probe.VerifierTap replaces `verify_async` on the
@@ -544,6 +612,66 @@ def test_one_lite_window_counts_its_signatures_under_columns(
     assert verifier.verify(items).all()
     assert batch_sigs()["items"] - before["items"] == 256
     assert batch_sigs()["columns"] == after["columns"]
+
+
+PREP_FAMILY = "verifier_prep_lanes_total"
+
+
+def prep_lanes():
+    return {how: telemetry.value(PREP_FAMILY, {"how": how}) or 0
+            for how in ("sharded", "inline")}
+
+
+def random_columns(n):
+    """n lanes that pass their prechecks and verify as nothing: for a
+    device call that is replaced."""
+    rng = np.random.default_rng(n)
+    raw = rng.integers(0, 256, (n, 64), dtype=np.uint8)
+    raw[:, 63] = 0
+    return SigColumns(rng.integers(0, 256, (n, 32), dtype=np.uint8),
+                      [row.tobytes() for row in raw], [b"sign-bytes"],
+                      np.zeros(n, np.int32))
+
+
+def test_prepared_lanes_count_as_sharded_or_inline(monkeypatch):
+    """A batch of two threads' worth of lanes on a host with cores to
+    spare counts under `sharded`, a smaller one and any batch on a
+    one-core host under `inline`, as columns or as triples; with
+    telemetry off nothing is counted; a batch under auto_threshold is
+    never prepared and counts under neither."""
+    from tendermint_tpu.models import verifier as verifier_mod
+    from tendermint_tpu.ops import ed25519
+    monkeypatch.setattr(
+        ed25519, "verify_prepared_async",
+        lambda pk, rb, sb, hb, mesh=None: np.ones(len(pk), np.bool_))
+    monkeypatch.setattr(verifier_mod, "_usable_cores", lambda: 13)
+    big, small = random_columns(2 * MIN_LANES + 5), random_columns(200)
+    verifier = BatchVerifier("auto", mesh="off")
+    was = telemetry.enabled()
+    try:
+        telemetry.configure(enabled=False)
+        before = prep_lanes()
+        assert verifier.verify(big).all() and verifier.verify(small).all()
+        assert prep_lanes() == before
+        telemetry.configure(enabled=True)
+        jax_sigs = telemetry.value("verifier_sigs_total",
+                                   {"backend": "jax"}) or 0
+        assert verifier.verify(big).all()
+        assert verifier.verify(list(big)).all()
+        assert verifier.verify(small).all()
+        assert not verifier.verify(list(small)[:100]).any()  # the host's
+        after = prep_lanes()
+        assert after["sharded"] - before["sharded"] == 2 * len(big)
+        assert after["inline"] - before["inline"] == len(small)
+        assert telemetry.value("verifier_sigs_total", {"backend": "jax"}) \
+            - jax_sigs == 2 * len(big) + len(small)
+        monkeypatch.setattr(verifier_mod, "_usable_cores", lambda: 1)
+        assert verifier.verify(big).all()
+        assert prep_lanes()["sharded"] == after["sharded"]
+        assert prep_lanes()["inline"] - after["inline"] == len(big)
+    finally:
+        telemetry.configure(enabled=was)
+        verifier.close()
 
 
 def test_a_four_vote_commit_counts_under_neither(net, telemetry_on):
