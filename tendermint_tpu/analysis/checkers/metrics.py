@@ -32,7 +32,7 @@ KNOWN_SUBSYSTEMS = {
     "chaos", "mesh", "pipeline", "partset", "trace",
     "snapshot", "sync", "prune", "prof", "queue", "loop", "wire",
     "slo", "shard", "statetree", "compact", "voteagg",
-    "edge", "load", "deploy", "divergence",
+    "edge", "load", "deploy", "divergence", "gc",
 }
 
 INSTRUMENTED_MODULES = [
@@ -51,7 +51,8 @@ INSTRUMENTED_MODULES = [
     "tendermint_tpu.chaos",              # tm_chaos_* fault/invariant plane
     "tendermint_tpu.pipeline",           # tm_pipeline_* hot-path stages
     "tendermint_tpu.types.part_set",     # tm_partset_build_seconds
-    "tendermint_tpu.telemetry.trace",    # tm_trace_events_dropped_total
+    "tendermint_tpu.telemetry.trace",    # tm_trace_events_dropped_total,
+                                         # tm_gc_* (the collector)
     "tendermint_tpu.storage.snapshot",   # tm_snapshot_* / tm_prune_*
     "tendermint_tpu.statesync.reactor",  # tm_sync_* chunk/restore plane
     "tendermint_tpu.telemetry.profile",  # tm_prof_* sampling profiler
@@ -79,9 +80,9 @@ INSTRUMENTED_MODULES = [
 # declared in telemetry.causal.SPAN_CATALOG, or dashboards and the
 # trace merger silently miss it. The regex covers the three call
 # shapes in the tree: causal.span/point/record(...) and the consensus
-# state machine's _cspan/_cpoint helpers.
+# state machine's _cspan/_cpoint/_cwait helpers.
 _SPAN_NAME_RE = re.compile(
-    r'(?:causal\.(?:span|point|record)|_cspan|_cpoint)\(\s*'
+    r'(?:causal\.(?:span|point|record)|_cspan|_cpoint|_cwait)\(\s*'
     r'[\'"]([a-z0-9_.]+)[\'"]')
 
 # The tracer's spans (telemetry/trace.py) are a closed catalogue too:
@@ -91,6 +92,11 @@ _SPAN_NAME_RE = re.compile(
 _TRACER_NAME_RE = re.compile(
     r'(?:trace|telemetry|TRACER)\.(?:span|complete|instant)\(\s*'
     r'[\'"]([A-Za-z0-9_.:]+)[\'"]')
+# the consensus state machine's helpers write both timelines from one
+# call: a mark that only the tracer takes is given by the tracer's own
+# name, and those names all begin "cs:"
+_CS_MARK_RE = re.compile(
+    r'(?:_cspan|_cpoint|_cwait)\(\s*[\'"](cs:[A-Za-z0-9_.]+)[\'"]')
 
 _LINE_RE = re.compile(
     r'^[a-z_][a-z0-9_]*(\{[a-z0-9_]+="(?:[^"\\]|\\.)*"'
@@ -148,6 +154,18 @@ def run() -> List[Finding]:
 
     findings.extend(span_findings())
 
+    # the table that takes a causal name to the tracer's, both sides
+    from tendermint_tpu.consensus.state import _RECORDER_NAME
+    from tendermint_tpu.telemetry.causal import SPAN_CATALOG
+    from tendermint_tpu.telemetry.trace import SPANS
+    for causal_name, span_name in _RECORDER_NAME.items():
+        if causal_name not in SPAN_CATALOG:
+            problem(f"consensus/state._RECORDER_NAME: {causal_name!r} not "
+                    f"declared in telemetry.causal.SPAN_CATALOG")
+        if span_name is not None and span_name not in SPANS:
+            problem(f"consensus/state._RECORDER_NAME: {span_name!r} not "
+                    f"declared in telemetry.trace.SPANS")
+
     run.summary = (f"{len(names)} families, {len(exposed)} "
                    f"exposed series names")
     return findings
@@ -161,7 +179,8 @@ def span_findings(root: str = "") -> List[Finding]:
     from tendermint_tpu.telemetry.causal import SPAN_CATALOG
     from tendermint_tpu.telemetry.trace import SPANS
     rules = ((_SPAN_NAME_RE, SPAN_CATALOG, "telemetry.causal.SPAN_CATALOG"),
-             (_TRACER_NAME_RE, SPANS, "telemetry.trace.SPANS"))
+             (_TRACER_NAME_RE, SPANS, "telemetry.trace.SPANS"),
+             (_CS_MARK_RE, SPANS, "telemetry.trace.SPANS"))
     if not root:
         import tendermint_tpu
         pkg = os.path.dirname(os.path.abspath(tendermint_tpu.__file__))

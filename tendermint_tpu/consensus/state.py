@@ -48,6 +48,54 @@ class ConsensusFailure(Exception):
     """Unrecoverable consensus fault (reference panics / kills process)."""
 
 
+# The marks of a height (_cpoint, _cwait, _cspan) go to two timelines
+# from one call: causal's ring under TM_TPU_TRACE, by the name given,
+# and the recorder (telemetry/trace.py) while telemetry is on, by this
+# table. None: a point the recorder has no reader for. A mark that
+# only the recorder takes is given by the recorder's own name ("cs:...").
+_RECORDER_NAME = {
+    "height.begin": None,
+    "propose": None,            # = cs:propose.build + cs:propose.send
+    "proposal.recv": "cs:propose.await_proposal",
+    "part.first": None,
+    "block.full": "cs:propose.await_block",
+    "quorum.prevote": None,     # where cs:PREVOTE(_WAIT) ends
+    "quorum.precommit": None,   # where cs:PRECOMMIT(_WAIT) ends
+    "flush": None,              # both inside cs:commit.persist
+    "wal.fsync": None,
+    "commit": "cs:finalize_commit",
+    "votes.agg": None,
+    "transition.digest": None,
+}
+
+
+class _Marks:
+    """The spans one _cspan call opened: none, the recorder's, causal's
+    or both."""
+    __slots__ = ("spans",)
+
+    def __init__(self, spans=()):
+        self.spans = spans
+
+    def __enter__(self):
+        for sp in self.spans:
+            sp.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        for sp in reversed(self.spans):
+            sp.__exit__(*exc)
+        return False
+
+    def note(self, **args) -> None:
+        """Args known only once the block is under way."""
+        for sp in self.spans:
+            sp.args.update(args)
+
+
+_NO_MARKS = _Marks()
+
+
 # The consensus timeline the paper's block-rate numbers decompose into:
 # where heights/rounds sit now, how long rounds take end to end, and how
 # often each step fires (a precommit-wait-heavy profile means votes are
@@ -134,6 +182,9 @@ class ConsensusState:
         # _new_step closes as one Chrome-trace complete event
         self._round_t0 = 0.0
         self._step_open = None  # (step_name, height, round, t0)
+        # when this round's proposal was accepted from a peer; None:
+        # not yet, or it is our own
+        self._proposal_at: Optional[float] = None
         # which node's step an event of the shared ring belongs to
         # (several nodes can live in one interpreter)
         self._trace_node = (priv_validator.address.hex()[:8]
@@ -249,16 +300,56 @@ class ConsensusState:
 
     def _cpoint(self, name: str, height: int, round_: int = -1,
                 **args) -> None:
-        """One causal timeline point — never during replay (a replayed
-        step is not new cluster progress; the live run already recorded
-        it, and a catchup replay would re-stamp old heights with NOW)."""
-        if self._trace and not self.replay_mode:
+        """One point of a height on both timelines (_RECORDER_NAME) —
+        never during replay (a replayed step is not new cluster
+        progress; the live run already recorded it, and a catchup
+        replay would re-stamp old heights with NOW)."""
+        if self.replay_mode:
+            return
+        if self._trace and name in _RECORDER_NAME:
             causal.point(name, height, round_, **args)
+        rec = _RECORDER_NAME.get(name, name)
+        if rec is not None and telemetry.enabled():
+            telemetry.instant(rec, req=height, round=round_,
+                              node=self._trace_node, **args)
+
+    def _cwait(self, name: str, height: int, round_: int,
+               since: Optional[float], **args) -> None:
+        """The end of a wait of the PROPOSE step: a point on causal's
+        timeline; on the recorder's the whole wait, from `since` but
+        not from before the step began, so that a node's waits add up
+        to its step. What came before the step began is a wait of 0 s.
+        With `since` None (no such wait was open: the proposal is our
+        own), or once the step has ended in its timeout, the recorder
+        gets nothing: `cs:timeout` and `cs:nil_vote` speak for that
+        round."""
+        if self.replay_mode:
+            return
+        if self._trace:
+            causal.point(name, height, round_, **args)
+        if since is None or self._step_open is None or \
+                self.rs.step > Step.PROPOSE or not telemetry.enabled():
+            return
+        step, h, r, t0 = self._step_open
+        now = time.perf_counter()
+        start = max(since, t0) if (step, h, r) == (
+            "PROPOSE", height, round_) else now
+        telemetry.complete(_RECORDER_NAME[name], start, now, req=height,
+                           round=round_, node=self._trace_node, **args)
 
     def _cspan(self, name: str, height: int, round_: int = -1, **args):
-        if self._trace and not self.replay_mode:
-            return causal.span(name, height, round_, **args)
-        return causal.null_span()
+        """One timed block of a height on both timelines, as _cpoint;
+        `as` gives the marks, whose `note` adds args."""
+        if self.replay_mode:
+            return _NO_MARKS
+        spans = []
+        rec = _RECORDER_NAME.get(name, name)
+        if rec is not None and telemetry.enabled():
+            spans.append(telemetry.span(rec, req=height, round=round_,
+                                        node=self._trace_node, **args))
+        if self._trace and name in _RECORDER_NAME:
+            spans.append(causal.span(name, height, round_, **args))
+        return _Marks(spans) if spans else _NO_MARKS
 
     def _point_transition_digest(self, height: int, round_: int) -> None:
         """Stamp the height's transition digest on the causal timeline
@@ -328,6 +419,7 @@ class ConsensusState:
                 self.config.commit_timeout_s() * 1e9)
         rs.validators = state.validators
         rs.proposal = None
+        self._proposal_at = None
         rs.proposal_block = None
         rs.proposal_block_parts = None
         rs.locked_round = 0
@@ -429,6 +521,7 @@ class ConsensusState:
         rs.validators = validators
         if round_ != 0:
             rs.proposal = None
+            self._proposal_at = None
             rs.proposal_block = None
             rs.proposal_block_parts = None
         rs.votes.set_round(round_ + 1)  # room for round-skip votes
@@ -510,25 +603,28 @@ class ConsensusState:
     def _decide_proposal(self, height: int, round_: int) -> None:
         rs = self.rs
         parts_iter = None
-        if rs.locked_block is not None:
-            block, parts = rs.locked_block, rs.locked_block_parts
-        else:
-            made = self._create_proposal_block()
-            if made is None:
-                return
-            block, parts, parts_iter = made
+        with self._cspan("cs:propose.build", height, round_) as built:
+            if rs.locked_block is not None:
+                block, parts = rs.locked_block, rs.locked_block_parts
+            else:
+                made = self._create_proposal_block()
+                if made is None:
+                    return
+                block, parts, parts_iter = made
+            built.note(txs=len(block.data.txs))
 
-        pol = rs.votes.pol_info()
-        pol_round = pol.round if pol else -1
-        pol_block_id = pol.block_id if pol else BlockID()
-        proposal = Proposal(height, round_, parts.header(), pol_round,
-                            pol_block_id, timestamp_ns=clock.now_ns())
-        try:
-            self.priv_validator.sign_proposal(self.state.chain_id, proposal)
-        except Exception as e:
-            if not self.replay_mode:
-                self._log(f"error signing proposal: {e!r}")
-            return
+            pol = rs.votes.pol_info()
+            pol_round = pol.round if pol else -1
+            pol_block_id = pol.block_id if pol else BlockID()
+            proposal = Proposal(height, round_, parts.header(), pol_round,
+                                pol_block_id, timestamp_ns=clock.now_ns())
+            try:
+                self.priv_validator.sign_proposal(self.state.chain_id,
+                                                  proposal)
+            except Exception as e:
+                if not self.replay_mode:
+                    self._log(f"error signing proposal: {e!r}")
+                return
         if slo_plane.enabled() and not self.replay_mode:
             # SLO proposal-inclusion stamp (proposer side; receivers
             # stamp when their part set completes — first wins)
@@ -543,7 +639,8 @@ class ConsensusState:
             # overlaps materialization of part i+1, and each part is
             # encoded exactly once instead of once per loop.
             self._broadcast(proposal_msg)
-            with pipeline.stage_timer("gossip") as t:
+            with pipeline.stage_timer("gossip") as t, self._cspan(
+                    "cs:propose.send", height, round_, parts=parts.total):
                 for part in parts_iter:
                     part_msg = {"type": "block_part", "height": height,
                                 "round": round_, "part": part.to_obj()}
@@ -554,14 +651,17 @@ class ConsensusState:
         # serial path: today's two full loops, with the part message
         # objects built ONCE (parts.get_part(i)/to_obj used to run twice
         # per part — own-queue loop, then broadcast loop)
-        part_msgs = [{"type": "block_part", "height": height,
-                      "round": round_, "part": parts.get_part(i).to_obj()}
-                     for i in range(parts.total)]
-        for part_msg in part_msgs:
-            self._enqueue_own(part_msg)
-        self._broadcast(proposal_msg)
-        for part_msg in part_msgs:
-            self._broadcast(part_msg)
+        with self._cspan("cs:propose.send", height, round_,
+                         parts=parts.total):
+            part_msgs = [{"type": "block_part", "height": height,
+                          "round": round_,
+                          "part": parts.get_part(i).to_obj()}
+                         for i in range(parts.total)]
+            for part_msg in part_msgs:
+                self._enqueue_own(part_msg)
+            self._broadcast(proposal_msg)
+            for part_msg in part_msgs:
+                self._broadcast(part_msg)
 
     def _create_proposal_block(self):
         """consensus/state.go:854 createProposalBlock. Returns
@@ -716,13 +816,16 @@ class ConsensusState:
                                 rs.locked_block_parts.header())
             return
         if rs.proposal_block is None:
-            self._sign_add_vote(VoteType.PREVOTE, b"", PartSetHeader())
+            self._sign_add_vote(
+                VoteType.PREVOTE, b"", PartSetHeader(),
+                why="no_proposal" if rs.proposal is None else "no_block")
             return
         try:
             self.block_exec.validate_block(self.state, rs.proposal_block)
         except BlockValidationError as e:
             self._log(f"prevote nil: invalid proposal block: {e}")
-            self._sign_add_vote(VoteType.PREVOTE, b"", PartSetHeader())
+            self._sign_add_vote(VoteType.PREVOTE, b"", PartSetHeader(),
+                                why="invalid_block")
             return
         self._sign_add_vote(VoteType.PREVOTE, rs.proposal_block.hash(),
                             rs.proposal_block_parts.header())
@@ -758,7 +861,8 @@ class ConsensusState:
 
         if maj is None:
             # no polka: precommit nil
-            self._sign_add_vote(VoteType.PRECOMMIT, b"", PartSetHeader())
+            self._sign_add_vote(VoteType.PRECOMMIT, b"", PartSetHeader(),
+                                why="no_polka")
             done()
             return
 
@@ -773,7 +877,8 @@ class ConsensusState:
                 rs.locked_block = None
                 rs.locked_block_parts = None
                 self._publish("Unlock")
-            self._sign_add_vote(VoteType.PRECOMMIT, b"", PartSetHeader())
+            self._sign_add_vote(VoteType.PRECOMMIT, b"", PartSetHeader(),
+                                why="polka_nil")
             done()
             return
 
@@ -811,7 +916,8 @@ class ConsensusState:
             rs.proposal_block = None
             rs.proposal_block_parts = PartSet.from_header(maj.parts)
         self._publish("Unlock")
-        self._sign_add_vote(VoteType.PRECOMMIT, b"", PartSetHeader())
+        self._sign_add_vote(VoteType.PRECOMMIT, b"", PartSetHeader(),
+                            why="no_block")
         done()
 
     def _enter_precommit_wait(self, height: int, round_: int) -> None:
@@ -883,7 +989,8 @@ class ConsensusState:
                          hash=block.hash(), round=rs.commit_round,
                          txs=len(block.data.txs))
         try:
-            self.block_exec.validate_block(self.state, block)
+            with self._cspan("cs:commit.validate", height, rs.commit_round):
+                self.block_exec.validate_block(self.state, block)
         except BlockValidationError as e:
             raise ConsensusFailure(f"+2/3 committed invalid block: {e}") from e
 
@@ -892,16 +999,18 @@ class ConsensusState:
             self._finalize_commit_pipelined(height, block, parts, pc)
             return
         fail.fail_point("consensus.before_save_block")
-        if self.block_store.height() < block.header.height:
-            seen_commit = pc.make_commit()
-            with self._cspan("flush", height):
-                self.block_store.save_block(block, parts, seen_commit)
+        with self._cspan("cs:commit.persist", height, rs.commit_round):
+            if self.block_store.height() < block.header.height:
+                seen_commit = pc.make_commit()
+                with self._cspan("flush", height):
+                    self.block_store.save_block(block, parts, seen_commit)
 
-        fail.fail_point("consensus.before_wal_end_height")
-        # ENDHEIGHT marks the WAL before ApplyBlock: if we crash between
-        # the two, handshake replay redoes ApplyBlock (consensus/replay.go)
-        with self._cspan("wal.fsync", height):
-            self.wal.save_end_height(height)
+            fail.fail_point("consensus.before_wal_end_height")
+            # ENDHEIGHT marks the WAL before ApplyBlock: if we crash
+            # between the two, handshake replay redoes ApplyBlock
+            # (consensus/replay.go)
+            with self._cspan("wal.fsync", height):
+                self.wal.save_end_height(height)
         fail.fail_point("consensus.after_wal_end_height")
 
         block_id = BlockID(block.hash(), parts.header())
@@ -916,10 +1025,6 @@ class ConsensusState:
         if telemetry.enabled() and not self.replay_mode:
             _m_commits.inc()
             _m_block_txs.observe(len(block.data.txs))
-            telemetry.instant("cs:finalize_commit", req=height,
-                              height=height, round=rs.commit_round,
-                              txs=len(block.data.txs),
-                              node=self._trace_node)
         self._cpoint("commit", height, rs.commit_round,
                      txs=len(block.data.txs))
         self._point_transition_digest(height, rs.commit_round)
@@ -982,7 +1087,8 @@ class ConsensusState:
                 self.state.copy(), block_id, block, group=group,
                 pre_validated=True)
         fail.fail_point("consensus.before_group_flush")
-        with pipeline.stage_timer("persist") as t_persist:
+        with pipeline.stage_timer("persist") as t_persist, self._cspan(
+                "cs:commit.persist", height, rs.commit_round):
             with self._cspan("flush", height):
                 group.flush()
             fail.fail_point("consensus.after_group_flush")
@@ -1000,10 +1106,6 @@ class ConsensusState:
         if telemetry.enabled() and not self.replay_mode:
             _m_commits.inc()
             _m_block_txs.observe(len(block.data.txs))
-            telemetry.instant("cs:finalize_commit", req=height,
-                              height=height, round=rs.commit_round,
-                              txs=len(block.data.txs),
-                              node=self._trace_node)
             pipeline.observe_overlap(self._overlap_s,
                                      self._overlap_s + self._serial_s)
         self._cpoint("commit", height, rs.commit_round,
@@ -1038,7 +1140,13 @@ class ConsensusState:
                 proposer.pubkey, proposal.sign_bytes(self.state.chain_id),
                 proposal.signature):
             raise ValueError("invalid proposal signature")
-        self._cpoint("proposal.recv", proposal.height, proposal.round)
+        own = self.priv_validator is not None and \
+            proposer.address == self.priv_validator.address
+        # a peer's proposal ends this node's wait for it, which began
+        # with its PROPOSE step; our own came back through the queue
+        self._cwait("proposal.recv", proposal.height, proposal.round,
+                    since=None if own else 0.0)
+        self._proposal_at = None if own else time.perf_counter()
         rs.proposal = proposal
         if rs.proposal_block_parts is None or \
                 not rs.proposal_block_parts.has_header(
@@ -1053,13 +1161,12 @@ class ConsensusState:
         if rs.proposal_block_parts is None:
             return
         added = rs.proposal_block_parts.add_part(part)
-        if added and self._trace:
-            if rs.proposal_block_parts.count == 1:
-                self._cpoint("part.first", height, rs.round)
-            if rs.proposal_block_parts.is_complete():
-                self._cpoint("block.full", height, rs.round,
-                             parts=rs.proposal_block_parts.total)
+        if added and rs.proposal_block_parts.count == 1:
+            self._cpoint("part.first", height, rs.round)
         if added and rs.proposal_block_parts.is_complete():
+            self._cwait("block.full", height, rs.round,
+                        since=self._proposal_at,
+                        parts=rs.proposal_block_parts.total)
             data = rs.proposal_block_parts.get_data()
             block = Block.from_bytes(data)
             rs.proposal_block = block
@@ -1259,7 +1366,9 @@ class ConsensusState:
                          "index": vote.validator_index})
 
     def _sign_add_vote(self, type_: int, hash_: bytes,
-                       parts_header: PartSetHeader) -> None:
+                       parts_header: PartSetHeader, why: str = "") -> None:
+        """Sign our vote, take it in and send it out. A nil vote (no
+        `hash_`) says `why`: what the node was short of."""
         rs = self.rs
         if self.priv_validator is None:
             return
@@ -1275,5 +1384,9 @@ class ConsensusState:
             if not self.replay_mode:
                 self._log(f"error signing vote: {e!r}")
             return
+        if not hash_:
+            self._cpoint("cs:nil_vote", rs.height, rs.round, why=why,
+                         type="prevote" if type_ == VoteType.PREVOTE
+                         else "precommit")
         self._enqueue_own({"type": "vote", "vote": vote.to_obj()})
         self._broadcast({"type": "vote", "vote": vote.to_obj()})

@@ -85,6 +85,20 @@ _m_call_seconds = telemetry.histogram(
     "front doors) chain",
     ("route", "chain"), buckets=(1e-4, 1e-3, 5e-3, 2.5e-2, 1e-1, 1.0,
                                  10.0))
+# the two waits around the handler, by the same route label: for one of
+# the pool's workers, then for the loop thread to take the reply.
+# Histograms and no spans: hundreds of calls a second would take the
+# trace ring from the consensus steps.
+_WAIT_BUCKETS = (1e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2, 2.5e-2, 5e-2, 1e-1,
+                 2.5e-1, 1.0, 10.0)
+_m_queue_seconds = telemetry.histogram(
+    "rpc_queue_seconds",
+    "Wait of an admitted JSON-RPC call for a worker thread, by route",
+    ("route",), buckets=_WAIT_BUCKETS)
+_m_reply_seconds = telemetry.histogram(
+    "rpc_reply_seconds",
+    "Wait of a finished JSON-RPC call for the loop thread to send its "
+    "reply, by route", ("route",), buckets=_WAIT_BUCKETS)
 
 DEFAULT_MAX_CONNS = 4096
 WORKERS = 6
@@ -303,25 +317,37 @@ class AsyncRPCServer:
             except Exception:
                 chain = ""   # label resolution must never fail a call
 
+        t_in = time.perf_counter() if tele else 0.0
+
         def work():
-            t0 = time.perf_counter() if tele else 0.0
+            t0 = t_done = 0.0
+            if tele:
+                t0 = time.perf_counter()
+                _m_queue_seconds.labels(route).observe(t0 - t_in)
             try:
                 result = self.call(method, params, ws=ws)
                 resp = _rpc_response(id_, result)
             except RPCError as e:
                 resp = _rpc_response(id_, error=e)
             if tele:
-                _m_call_seconds.labels(route, chain).observe(
-                    time.perf_counter() - t0)
-            self.loop.call_soon(lambda: self._complete(send, resp),
-                                owner="rpc")
+                t_done = time.perf_counter()
+                _m_call_seconds.labels(route, chain).observe(t_done - t0)
+            self.loop.call_soon(
+                lambda: self._complete(send, resp, route, t_done),
+                owner="rpc")
 
         try:
             self._pool.submit(work)
         except RuntimeError:   # pool shut down under us
             self._inflight -= 1
 
-    def _complete(self, send: Callable[[dict], None], resp: dict) -> None:
+    def _complete(self, send: Callable[[dict], None], resp: dict,
+                  route: str = "", t_done: float = 0.0) -> None:
+        """Loop-thread: `t_done` is when the handler returned on its
+        worker (0 with telemetry off)."""
+        if t_done:
+            _m_reply_seconds.labels(route).observe(
+                time.perf_counter() - t_done)
         self._inflight -= 1
         send(resp)
 

@@ -259,7 +259,9 @@ def _fire(kind: str, sat: float, depth: int) -> None:
     get_logger("telemetry").error(
         "queue saturated", queue=kind, depth=depth,
         saturation=round(sat, 3))
-    from tendermint_tpu.telemetry import causal
+    from tendermint_tpu.telemetry import causal, trace
+    # on the recorder's clock too, beside the round it may have cost
+    trace.instant("queue.saturated", queue=kind, depth=depth)
     causal.point("queue.saturated", 0, queue=kind, depth=depth,
                  saturation=round(sat, 3))
     for cb in list(_callbacks):

@@ -25,7 +25,8 @@ import bisect
 import math
 import re
 import threading
-from typing import Dict, Iterable, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 from tendermint_tpu.utils import knobs
 
@@ -68,6 +69,11 @@ class _TelemetryState:
 
 _state = _TelemetryState()
 
+# called with the flag each time it is set: what must not run at all
+# while telemetry is off (the collector's callback, telemetry/trace.py)
+# is put in and taken out here
+_on_enabled: List[Callable[[bool], None]] = []
+
 
 def enabled() -> bool:
     return _state.enabled
@@ -76,6 +82,8 @@ def enabled() -> bool:
 def set_enabled(on: bool) -> None:
     """Hard override (tests / tooling) — ignores the env pin."""
     _state.enabled = bool(on)
+    for hook in _on_enabled:
+        hook(_state.enabled)
 
 
 def namespace() -> str:
@@ -95,7 +103,7 @@ def configure(enabled: Optional[bool] = None,
                 f"got {namespace!r}")
         _state.namespace = namespace
     if enabled is not None and not _state.env_forced:
-        _state.enabled = bool(enabled)
+        set_enabled(enabled)
 
 
 # --------------------------------------------------------------------------

@@ -23,6 +23,7 @@ merged by concatenating traceEvents.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -32,7 +33,7 @@ import time
 from array import array
 from typing import List, Tuple
 
-from tendermint_tpu.telemetry.registry import _state
+from tendermint_tpu.telemetry.registry import _on_enabled, _state
 
 # Default ring capacity: one consensus step is ~5 events; 65536 holds a
 # few thousand heights of timeline before the oldest roll off.
@@ -109,12 +110,36 @@ SPANS = {
     # verify (a gossip message's votes, never one per vote); req = the
     # height, `sigs` = signatures the call sent to the verifier
     "cs:vote_ingest": "gossip and consensus rounds",
+    # what PROPOSE and COMMIT are made of (consensus/state._cspan and
+    # _cwait; req = the height, args `round` and `node`): at most one
+    # event of each per node, height and round, never one per part.
+    # The proposer's two run before its PROPOSE step opens
+    # (_enter_propose changes the step last); a receiver's two waits
+    # add up to its PROPOSE step, and a step that ends in its timeout
+    # records neither
+    "cs:propose.build": "gossip and consensus rounds",   # reap .. signed
+    "cs:propose.send": "gossip and consensus rounds",    # own queue, peers
+    "cs:propose.await_proposal": "gossip and consensus rounds",
+    "cs:propose.await_block": "gossip and consensus rounds",
+    "cs:commit.validate": "gossip and consensus rounds",
+    "cs:commit.persist": "gossip and consensus rounds",  # flush, WAL fsync
+    # why a round is lost: one instant per nil vote the node signs
+    # (args `type`, `round`, `node`, `why`), one per episode of a queue
+    # over 80% full (telemetry/queues._fire; args `queue`, `depth`)
+    "cs:nil_vote": "gossip and consensus rounds",
+    "queue.saturated": "gossip and consensus rounds",
+    # one event per collection of the cyclic collector that took
+    # GC_EVENT_MIN_S or more, on the thread it stopped (args `gen`,
+    # `collected`); every collection moves tm_gc_*_total
+    "gc.collect": "host runtime",
 }
 
 ANNOTATION_PREFIX = "tm:"   # a span's name in a profiler trace
 
 _ids = itertools.count(1)       # next() is atomic under the GIL
-_local = threading.local()      # .stack: the spans open on this thread
+# .stack: the spans open on this thread; .gc_started: when the
+# collection it is in began
+_local = threading.local()
 
 
 def _open_spans() -> list:
@@ -221,41 +246,56 @@ class Tracer:
         # written since clear()
         self._lost_ends = array("d", bytes(8 * n))
         self._n_lost = 0
+        # events whose writer could not wait for _lock (the collector's
+        # callback, on a thread that may hold it), as _write_locked's
+        # arguments: appended without the lock, written by its next
+        # holder
+        self._late: list = []
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
 
     def _write(self, name, ts, dur, id_, parent, cause, req, args) -> None:
-        tid = threading.get_ident()
         with self._lock:
-            i = self._written % self._capacity
-            if self._written >= self._capacity:     # slot i: the oldest
-                self.dropped += 1
-                self._lost_ends[self._n_lost % self._capacity] = \
-                    self._ts[i] + max(0.0, self._dur[i])
-                self._n_lost += 1
-                self._objs.pop(i, None)
-                note_dropped()
-            self._written += 1
-            self._name[i] = _NAMES.get(name, name)
-            self._ts[i], self._dur[i] = ts, dur
-            k = _INTS * i
-            ints = self._ints
-            ints[k + _TID], ints[k + _ID] = tid, id_
-            ints[k + _PARENT], ints[k + _CAUSE] = parent, cause or 0
-            if _fits(req):
-                ints[k + _REQ], req = req, None
+            self._write_locked(threading.get_ident(), name, ts, dur, id_,
+                               parent, cause, req, args)
+            if self._late:
+                self._take_late_locked()
+
+    def _write_locked(self, tid, name, ts, dur, id_, parent, cause, req,
+                      args) -> None:
+        i = self._written % self._capacity
+        if self._written >= self._capacity:     # slot i: the oldest
+            self.dropped += 1
+            self._lost_ends[self._n_lost % self._capacity] = \
+                self._ts[i] + max(0.0, self._dur[i])
+            self._n_lost += 1
+            self._objs.pop(i, None)
+            note_dropped()
+        self._written += 1
+        self._name[i] = _NAMES.get(name, name)
+        self._ts[i], self._dur[i] = ts, dur
+        k = _INTS * i
+        ints = self._ints
+        ints[k + _TID], ints[k + _ID] = tid, id_
+        ints[k + _PARENT], ints[k + _CAUSE] = parent, cause or 0
+        if _fits(req):
+            ints[k + _REQ], req = req, None
+        else:
+            ints[k + _REQ] = _NO_REQ
+        key = None
+        if args and len(args) == 1:
+            (key, value), = args.items()
+            if _fits(value):
+                ints[k + _ARG], args = value, None
             else:
-                ints[k + _REQ] = _NO_REQ
-            key = None
-            if args and len(args) == 1:
-                (key, value), = args.items()
-                if _fits(value):
-                    ints[k + _ARG], args = value, None
-                else:
-                    key = None
-            self._argkey[i] = key
-            if req is not None or args:
-                self._objs[i] = (req, args)
+                key = None
+        self._argkey[i] = key
+        if req is not None or args:
+            self._objs[i] = (req, args)
+
+    def _take_late_locked(self) -> None:
+        while self._late:
+            self._write_locked(*self._late.pop(0))
 
     def _slots_locked(self) -> range:
         """The live slots' positions, oldest first (slot = pos % n)."""
@@ -317,6 +357,7 @@ class Tracer:
         pid = os.getpid()
         out = []
         with self._lock:
+            self._take_late_locked()
             for pos in self._slots_locked():
                 i = pos % self._capacity
                 tid, id_, parent, cause, req, args = self._fields_locked(i)
@@ -341,18 +382,21 @@ class Tracer:
         with self._lock:
             self._written = self._n_lost = 0
             self._objs.clear()
+            del self._late[:]
 
     def between(self, name: str, t0: float,
                 t1: float) -> Tuple[List[dict], int]:
         """What a reader with a window on time.perf_counter needs:
         (the events called `name` that overlap [t0, t1], how many
-        events the ring displaced that ended at or after t0). Each row
-        has `start` and `end` (perf_counter seconds, not clipped; an
-        instant has both equal), `tid`, `id`, `parent`, `cause`, `req`
-        and `args`. A count above 0 says the rows may be incomplete."""
+        events the ring displaced that ended at or after t0).
+        Each row has `start` and `end` (perf_counter seconds, not
+        clipped; an instant has both equal), `tid`, `id`, `parent`,
+        `cause`, `req` and `args`. A count above 0 says the rows may be
+        incomplete."""
         lo, hi = self._ts_us(t0), self._ts_us(t1)
         rows = []
         with self._lock:
+            self._take_late_locked()
             lost = self._lost_ends[:min(self._n_lost, self._capacity)]
             dropped = sum(1 for end in lost if end >= lo)
             for pos in self._slots_locked():
@@ -401,3 +445,67 @@ def instant(name: str, req=None, **args) -> None:
 
 def dump_trace(path: str) -> str:
     return TRACER.dump(path)
+
+
+# ---------------------------------------------------- the cyclic collector
+
+# A collection shorter than this moves the counter and gets no event: a
+# node under load runs hundreds of generation-0 collections a second,
+# and the ring is for the steps.
+GC_EVENT_MIN_S = 1e-3
+
+_m_gc_pause = _REGISTRY.counter(
+    "gc_pause_seconds_total",
+    "Seconds the cyclic collector held the interpreter, by the "
+    "generation collected", ("gen",))
+# the family's three children, made here and not by labels() in the
+# callback: that may take the family's lock, which the thread a
+# collection stopped may hold (_Family.children allocates under it)
+with _m_gc_pause._lock:
+    _gc_pause_of_gen = tuple(
+        _m_gc_pause._children.setdefault((str(gen),),
+                                         _m_gc_pause._new_child())
+        for gen in range(3))
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks entry. The event lies on the thread the collection
+    stopped, inside whatever span was open there."""
+    if phase == "start":
+        _local.gc_started = time.perf_counter()
+        return
+    t1 = time.perf_counter()
+    t0 = _local.__dict__.pop("gc_started", None)
+    if t0 is None:      # switched on while this collection ran
+        return
+    _gc_pause_of_gen[info["generation"]].inc(t1 - t0)
+    if t1 - t0 < GC_EVENT_MIN_S:
+        return
+    stack = _open_spans()
+    event = (threading.get_ident(), "gc.collect", TRACER._ts_us(t0),
+             (t1 - t0) * 1e6, next(_ids), stack[-1].id if stack else 0,
+             None, stack[-1].req if stack else None,
+             {"gen": info["generation"], "collected": info["collected"]})
+    # never wait for the ring in here: the thread that holds its lock
+    # may be the one this collection stopped. Its next holder writes
+    # what is left for it
+    if not TRACER._lock.acquire(False):
+        TRACER._late.append(event)
+        return
+    try:
+        TRACER._write_locked(*event)
+    finally:
+        TRACER._lock.release()
+
+
+def _watch_gc(on: bool) -> None:
+    """The callback is in gc.callbacks while telemetry is on, and only
+    then: switched off, the interpreter calls nothing of ours."""
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+_on_enabled.append(_watch_gc)
+_watch_gc(_state.enabled)
