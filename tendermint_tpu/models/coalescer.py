@@ -17,9 +17,21 @@ the verdicts back to each caller in submission order.
 Batching policy (the knobs are TM_TPU_COALESCE / TM_TPU_COALESCE_WAIT_MS
 / TM_TPU_COALESCE_MAX_BATCH and config.base.verifier_coalesce_*):
 
-  - The dispatcher wakes on the first arrival and then LINGERS only
-    while traffic is dense: it keeps collecting until no new call has
-    arrived for ~4x the EWMA inter-arrival gap, capped at max_wait
+  - Only backend `jax` merges. The one thing a merge can change is
+    whether a batch crosses the verifier's auto_threshold and runs on
+    the device: under it the merged batch is verified on the host
+    signature by signature, as its calls would have been alone, and
+    live votes never come near it (a handful a burst, some 150 calls a
+    second in a four-validator process). So the verifier `submit`s a
+    sub-threshold call only where every call is the device's and fewer
+    dispatches are always worth a hand-over; for `auto` and `python` it
+    asks for an `inline` resolver, which runs the direct path on the
+    thread that resolves it (`verify` is `verify_async(...)()`: the
+    caller's own, at once). Nothing is queued, the dispatcher thread
+    is not started, and an exception is the caller's own.
+  - The dispatcher wakes on the first queued arrival and then LINGERS
+    only while traffic is dense: it keeps collecting until no new call
+    has arrived for ~4x the EWMA inter-arrival gap, capped at max_wait
     (default 2ms) from the first drain, or until max_batch items
     (default BATCH_CHUNK) are queued. A solo sequential caller —
     whose inter-arrival gap is its own verify latency, necessarily
@@ -51,6 +63,10 @@ from tendermint_tpu.telemetry import queues as queue_obs
 _m_calls = telemetry.counter(
     "verifier_coalesce_calls_total",
     "verify calls routed through the dispatch coalescer")
+_m_inline = telemetry.counter(
+    "verifier_coalesce_inline_total",
+    "verify calls left to their caller's thread: nothing a merge could "
+    "lift onto the device")
 _m_dispatches = telemetry.counter(
     "verifier_coalesce_dispatches_total",
     "Merged dispatches formed by the coalescer")
@@ -143,6 +159,25 @@ class _Call:
         return self.merged.result_for(self)
 
 
+class _Inline:
+    """A call that no merge could lift onto the device: dispatched and
+    resolved by the thread that asks for its verdicts, and kept for one
+    that asks again. Two threads that ask at once both verify, to the
+    same verdicts."""
+
+    __slots__ = ("_dispatch", "_items", "_value")
+
+    def __init__(self, dispatch: Callable, items: list):
+        self._dispatch = dispatch
+        self._items = items
+        self._value = None
+
+    def resolve(self) -> np.ndarray:
+        if self._value is None:
+            self._value = np.asarray(self._dispatch(self._items)())
+        return self._value
+
+
 class DispatchCoalescer:
     """Merge concurrent verify calls into batched dispatches.
 
@@ -209,6 +244,15 @@ class DispatchCoalescer:
             self._cond.notify()
         _m_calls.inc()
         return call.resolve
+
+    def inline(self, items: Sequence) -> Callable[[], np.ndarray]:
+        """The resolver of a call that is not worth a hand-over: its
+        dispatch runs on the thread that resolves it, its exception is
+        that thread's own, and the dispatcher never hears of it.
+        Counted among the calls, and as inline."""
+        _m_calls.inc()
+        _m_inline.inc()
+        return _Inline(self._dispatch, list(items)).resolve
 
     def close(self, timeout: float = 5.0) -> None:
         """Stop the dispatcher; queued calls are still dispatched."""

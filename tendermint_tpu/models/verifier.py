@@ -269,11 +269,19 @@ class BatchVerifier:
         fast-sync loop applies window k-1 while window k verifies
         on-device); every chunk is enqueued up front.
 
-        Sub-threshold calls route through the dispatch coalescer
-        (models/coalescer.py) unless coalesce='off': concurrent
+        Sub-threshold calls go to the dispatch coalescer
+        (models/coalescer.py) unless coalesce='off'. Backend 'jax',
+        where every call is the device's, queues them: concurrent
         single-vote callers merge into one batched dispatch, each
-        getting back exactly its own verdicts. Calls already above the
-        threshold are efficient as-is and dispatch directly."""
+        getting back exactly its own verdicts. For 'auto' and 'python'
+        a merge changes nothing unless it lifts a batch over the
+        threshold, which takes arrivals far denser than live consensus
+        sends; under it the merged batch is verified on the host
+        signature by signature all the same. So a live vote, a proposal
+        or a small set's LastCommit stays on its caller's thread: the
+        resolver runs _verify_async_direct where it is called, as 'off'
+        would have at dispatch. Calls already above the threshold are
+        efficient as-is and dispatch directly."""
         n = len(items)
         if self.coalesce != "off" and 0 < n <= self.auto_threshold:
             with self._stats_lock:
@@ -293,7 +301,9 @@ class BatchVerifier:
                             max_batch=self._coalesce_max_batch,
                             max_wait_s=self._coalesce_wait_s)
                     c = self._coalescer
-            return c.submit(items)
+            if self.backend == "jax":
+                return c.submit(items)
+            return c.inline(items)
         return self._verify_async_direct(items)
 
     def close(self) -> None:

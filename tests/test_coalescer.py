@@ -116,6 +116,187 @@ def test_coalescer_close_drains_queue():
         c.submit([1])
 
 
+# ------------------------------------------ sparse calls stay inline
+#
+# Only backend 'jax' queues a sub-threshold call. For 'auto' and
+# 'python' the verifier asks the coalescer for an inline resolver: the
+# direct path, on the thread that resolves it.
+
+
+def _dispatcher_threads():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("tm-verify-coalesce")]
+
+
+def _recording_dispatch(log, poison=None):
+    """A stub of the verifier's direct path: logs (thread, size), and
+    holds every item that is an even number to be valid."""
+    def dispatch(items):
+        log.append((threading.current_thread(), len(items)))
+        if poison is not None and poison in items:
+            raise TypeError("bad item")
+        arr = np.array([x % 2 == 0 for x in items], np.bool_)
+        return lambda: arr
+    return dispatch
+
+
+def test_solo_caller_stays_on_its_thread_with_the_off_paths_verdicts():
+    """A caller of BatchVerifier('auto') never starts the dispatcher
+    thread, and its verdicts are byte for byte those of coalesce='off'
+    and of the queued path, on valid, invalid and secp256k1 items."""
+    before = set(_dispatcher_threads())
+    batches = [[_ed_item(0)], [_ed_item(1, valid=False)], [_secp_item(0)],
+               [_secp_item(1, valid=False)],
+               [_ed_item(2), _ed_item(3, valid=False), _secp_item(2),
+                _ed_item(4)]]
+    v_off = BatchVerifier("auto", coalesce="off")
+    v = BatchVerifier("auto")
+    queued = DispatchCoalescer(v_off._verify_async_direct)
+    try:
+        for items in batches:
+            want = v_off.verify(items)
+            got = v.verify(items)
+            merged = queued.submit(items)()
+            assert got.dtype == want.dtype == merged.dtype
+            assert got.tobytes() == want.tobytes() == merged.tobytes()
+        assert v.verify(batches[-1]).tolist() == [True, False, True, True]
+        assert v._coalescer._thread is None
+        assert set(_dispatcher_threads()) - before == {queued._thread}
+        assert v.stats["coalesced_calls"] == v.stats["calls"] == \
+            len(batches) + 1
+    finally:
+        v.close()
+        queued.close()
+
+
+def test_a_burst_of_single_votes_goes_inline_on_its_callers_threads():
+    """Eight threads, one vote each, at once: four nodes of a process
+    receiving the same prevotes. Nothing a device would ever see, so
+    each verifies where it was received."""
+    v = BatchVerifier("auto")
+    log = []
+    v._verify_async_direct = _recording_dispatch(log)
+    start = threading.Barrier(8)
+    got = {}
+
+    def caller(i):
+        start.wait(10)
+        got[i] = (threading.current_thread(), v.verify([i]).tolist())
+
+    ths = [threading.Thread(target=caller, args=(i,)) for i in range(8)]
+    try:
+        for t in ths:
+            t.start()
+        for t in ths:
+            t.join(10)
+        assert {i: r for i, (_, r) in got.items()} == \
+            {i: [i % 2 == 0] for i in range(8)}
+        assert v._coalescer._thread is None
+        assert [n for _, n in log] == [1] * 8
+        assert {t for t, _ in log} == {t for t, _ in got.values()} == set(ths)
+    finally:
+        v.close()
+
+
+@pytest.mark.parametrize("backend, queued", [("auto", False),
+                                             ("python", False),
+                                             ("jax", True)])
+def test_the_backend_says_who_dispatches(backend, queued):
+    """Where every call is the device's, 'jax', a solo call is queued
+    too and four threads of them merge. Where a merged batch under the
+    threshold is the host's all the same, 'auto' and 'python', nothing
+    is queued however dense the callers."""
+    v = BatchVerifier(backend)
+    log = []
+    v._verify_async_direct = _recording_dispatch(log)
+    try:
+        ths = [threading.Thread(
+            target=lambda: [v.verify(list(range(100))) for _ in range(20)])
+            for _ in range(4)]
+        for t in ths:
+            t.start()
+        for t in ths:
+            t.join(60)
+        assert v.verify([2]).tolist() == [True]
+        c = v._coalescer
+        assert sum(n for _, n in log) == 8001
+        if queued:
+            assert {t for t, _ in log} == {c._thread}
+        else:
+            assert c._thread is None
+            assert {t for t, _ in log} == \
+                set(ths) | {threading.current_thread()}
+            assert len(log) == 81
+    finally:
+        v.close()
+
+
+def test_an_inline_dispatchs_exception_is_its_callers_alone():
+    log = []
+    c = DispatchCoalescer(_recording_dispatch(log, poison=-1))
+    start = threading.Barrier(4)
+    got = {}
+
+    def caller(i):
+        start.wait(10)
+        try:
+            got[i] = c.inline([-1 if i == 2 else i])().tolist()
+        except TypeError as e:
+            got[i] = e
+
+    ths = [threading.Thread(target=caller, args=(i,)) for i in range(4)]
+    try:
+        for t in ths:
+            t.start()
+        for t in ths:
+            t.join(10)
+        assert isinstance(got.pop(2), TypeError)
+        assert got == {0: [True], 1: [False], 3: [False]}
+        assert c._thread is None
+        assert c.inline([6])().tolist() == [True]
+    finally:
+        c.close()
+
+
+def test_an_inline_resolver_asked_twice_verifies_once():
+    log = []
+    c = DispatchCoalescer(_recording_dispatch(log))
+    try:
+        r = c.inline([1, 2])
+        assert log == []            # nothing runs before it is asked
+        assert r().tolist() == r().tolist() == [False, True]
+        assert log == [(threading.current_thread(), 2)]
+    finally:
+        c.close()
+
+
+def test_inline_and_queued_add_up_to_the_calls_counter():
+    """`tm_verifier_coalesce_calls_total` counts every call that reached
+    a coalescer, `tm_verifier_coalesce_inline_total` those that stayed
+    on their caller's thread."""
+    from tendermint_tpu import telemetry
+    from tendermint_tpu.models import coalescer
+    assert telemetry.REGISTRY.get("verifier_coalesce_inline_total") \
+        is coalescer._m_inline
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    calls0 = telemetry.value("verifier_coalesce_calls_total") or 0.0
+    inline0 = telemetry.value("verifier_coalesce_inline_total") or 0.0
+    c = DispatchCoalescer(_recording_dispatch([]))
+    try:
+        rs = [c.inline([i]) for i in range(5)]
+        rs += [c.submit([i]) for i in range(3)]
+        assert [r().tolist() for r in rs] == \
+            [[i % 2 == 0] for i in (0, 1, 2, 3, 4, 0, 1, 2)]
+        assert telemetry.value("verifier_coalesce_calls_total") \
+            - calls0 == 8
+        assert telemetry.value("verifier_coalesce_inline_total") \
+            - inline0 == 5
+    finally:
+        telemetry.set_enabled(was)
+        c.close()
+
+
 # ------------------------------------------------- verifier + threads
 
 
