@@ -1,0 +1,9 @@
+"""`lite_check_share`'s reading in `chain_100v_churn.lite_follow`: the
+share of the passes' time judging a window's verdicts (`lite.check`:
+every header's quorum, then `lite.transition`). An entry of its own
+because a test holds the `lite_` entry's `workloads` to the constant-set
+cell alone."""
+
+from benchmark.metrics.lite_check_share import LAYER, read  # noqa: F401
+
+MOVES = "headers_per_s"

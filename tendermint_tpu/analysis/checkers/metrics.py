@@ -73,6 +73,8 @@ INSTRUMENTED_MODULES = [
                                          # tm_wire_block_decodes_total
     "tendermint_tpu.types.vote_set",     # tm_consensus_votes_total
     "tendermint_tpu.p2p.fuzz",           # tm_p2p_link_delay_seconds
+    "tendermint_tpu.lite.certifier",     # tm_lite_windows_total,
+                                         # tm_lite_transitions_total
 ]
 
 # Causal span names follow the same closed-catalog discipline as metric

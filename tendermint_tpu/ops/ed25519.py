@@ -259,10 +259,10 @@ _PREDECOMP_MIN_BATCH = 64
 # pubkey -> (xneg_bytes u8[32], y_bytes u8[32], ok bool)
 _predecomp: "OrderedDict[bytes, tuple]" = OrderedDict()
 # a padded batch's key bytes -> [its distinct keys (None until the first
-# reuse), (xneg u8[m,32], y u8[m,32], ok bool[m]) read-only]. A
-# dispatch window is at most 4 chunks, each window starts on a commit,
-# so a chain repeats 4 sequences: hold 8, least recently used out
-# (8 x 8,192 rows x (32 key + 65 row bytes) = 6.4 MB at most)
+# reuse), (xneg u8[m,32], y u8[m,32], ok bool[m]) read-only]. Hold 8,
+# least recently used out (6.4 MB at most): a chain of ONE set repeats
+# 4 sequences (99.0% of chunks reused); where the key list moves every
+# 63 headers a pass is 50 new ones and none is reused (PERF.md, PR 40)
 _PREDECOMP_MEMO_MAX = 8
 _predecomp_memo: "OrderedDict[bytes, list]" = OrderedDict()
 # pubkeys sighted once (first sighting stays on the fused full kernel:

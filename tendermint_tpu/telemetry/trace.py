@@ -76,6 +76,13 @@ SPANS = {
     "lite.votes": "verifier",           # the commits' columns
     "lite.wait": "verifier",
     "lite.check": "verifier",
+    # one event each a window, as lite.headers: the validator sets the
+    # window's headers hand over, hashed (the first part of
+    # lite.headers; `sets` = distinct set objects), and a follower's
+    # boundaries judged by the adjacent-height rule with the switch of
+    # trust (last in lite.check; ContinuousCertifier.advance_many alone)
+    "lite.sethash": "verifier",
+    "lite.transition": "verifier",
     "sync.collect": "sync window engine",
     "sync.parts": "sync window engine",
     "sync.wait": "sync window engine",

@@ -358,6 +358,59 @@ class ValidatorSet:
             raise ValueError(
                 f"insufficient voting power: {power_for_block}/{total}")
 
+    def endorsement(self, signing: "ValidatorSet", chain_id: str,
+                    block_id, commit):
+        """This (trusted) set's side of a change of set between adjacent
+        heights: the second tally over a commit that `signing`, the set
+        the header names, has been given to verify. Returns `(power,
+        extra)`: the stake THIS set holds among the commit's votes for
+        `block_id`, each validator it knows (by the vote's address)
+        counted once, and `extra`, the triples that the lanes of
+        commit_verification_items under `signing` do not cover.
+
+        A vote in slot i is verified there under `signing`'s key i; the
+        trust-level rule (later Tendermint's light client, trust level
+        1/3) verifies it under the key THIS set holds for the vote's
+        address. Wherever the two keys are one, which is every vote of a
+        well-formed commit, the lane's verdict serves both and nothing
+        is verified twice; a vote that claims another validator's
+        address is verified once more, under that key, as a triple of
+        `extra`. The caller judges with check_endorsement once every
+        lane of the commit has verified."""
+        known, vals, slots = self._index.get, self.validators, \
+            signing.validators
+        power, extra, seen = 0, [], set()
+        for i, pc in enumerate(commit.precommits):
+            if pc is None:
+                continue
+            b = pc.block_id
+            if b is not block_id and b != block_id:
+                continue        # counts for nothing, as in verify_commit
+            oi = known(pc.validator_address, -1)
+            if oi < 0 or oi in seen:
+                continue        # unknown to this set, or a duplicate
+            seen.add(oi)
+            ov = vals[oi]
+            if slots[i].pubkey != ov.pubkey:
+                extra.append((ov.pubkey, pc.sign_bytes(chain_id),
+                              pc.signature))
+            power += ov.voting_power
+        return power, extra
+
+    def check_endorsement(self, power: int, extra_ok=()) -> None:
+        """Judge phase of `endorsement`: every extra triple valid and
+        STRICTLY more than 1/3 of this set's stake behind the block, so
+        that under the <1/3-byzantine assumption at least one honest
+        validator of this set vouches for the set that follows. Raises
+        ValueError."""
+        if not all(extra_ok):
+            raise ValueError("invalid signature in commit")
+        total = self.columns().total
+        if not power * 3 > total:
+            raise ValueError(
+                f"insufficient trusted-set endorsement: got {power}, "
+                f"need > {total / 3:g} (1/3 of trusted power)")
+
     def verify_commit_async(self, chain_id: str, block_id, height: int,
                             commit, verifier=None):
         """Dispatch phase of verify_commit WITHOUT blocking: structural
