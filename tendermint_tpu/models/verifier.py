@@ -80,26 +80,34 @@ _m_mesh_devices = telemetry.gauge(
 # ed25519 predecompression cache (ops/ed25519): registered HERE so the
 # import-light lint can see the families without importing jax; the
 # ops module increments them lazily. hit = batch fully served from
-# cached rows (pre kernel, no sqrt); fill = repeat-traffic batch
-# decompressed once + rows stored; full = mostly-unseen batch routed
-# to the fused full kernel (the churn signature: every valset rotation
-# shows up as full->fill->hit over the next batches).
+# the key table's rows, by index (pre kernel, no sqrt); fill =
+# repeat-traffic batch decompressed once + rows stored in the table;
+# full = mostly-unseen batch routed to the fused full kernel (the churn
+# signature: every valset rotation shows up as full->fill->hit over the
+# next batches).
 _m_predecomp = telemetry.counter(
     "verifier_predecomp_batches_total",
     "Device batches through the ed25519 predecompressed-pubkey cache, "
     "by outcome", ("outcome",))
 _m_predecomp_evictions = telemetry.counter(
     "verifier_predecomp_evictions_total",
-    "Per-pubkey rows evicted from the ed25519 predecompression LRU "
-    "(valset churn beyond cache capacity)")
+    "Per-pubkey rows put out of the ed25519 key table, least recently "
+    "used first (valset churn beyond its capacity)")
 _m_predecomp_keys = telemetry.gauge(
     "verifier_predecomp_keys",
-    "Pubkey rows currently resident in the predecompression LRU")
+    "Pubkey rows currently resident in the ed25519 key table")
 _m_predecomp_assembled = telemetry.counter(
     "verifier_predecomp_assembled_total",
-    "Device batches that got predecompressed rows, by whether the "
-    "arrays were built (per-key lookups, or a fill) or reused from the "
-    "memo of whole key sequences", ("how",))
+    "Device batches that got predecompressed rows, by whether their "
+    "slots in the key table were built (resolved by the table's "
+    "lookup, after a fill too) or reused from the memo of whole key "
+    "sequences", ("how",))
+_m_predecomp_lanes = telemetry.counter(
+    "verifier_predecomp_lanes_total",
+    "Lanes the key table's lookup resolved, by what settled them: "
+    "index (the sorted key prefixes and one compare of all 32 bytes) "
+    "or second (the lane's prefix is resident under another key, so "
+    "the keys that share it were walked)", ("how",))
 _m_batch_sigs = telemetry.counter(
     "verifier_batch_sigs_total",
     "Signatures dispatched to the device, by the form their batch "

@@ -234,46 +234,165 @@ def verify_from_bytes_best(pk, rb, s_bytes, h_bytes):
 # a significant slice of the verify kernel — yet consensus workloads
 # verify the SAME validator set's keys over and over (every commit,
 # every fast-sync window, every lite header). The cache keys PER
-# 32-BYTE PUBKEY (it used to key on the content hash of the whole
-# padded batch, which coalesced mixed-validator batches — arbitrary
-# vote compositions merged by models/coalescer.py — would never hit):
-# once a validator's key has been decompressed once, EVERY later batch
-# containing it hits, regardless of batch composition or order. Rows
-# are the canonical field bytes of (-A).x / A.y plus the validity flag
-# (65 bytes each) — host-resident and uploaded with every batch (m x
-# 65B, trivial next to the sqrt the *_pre kernels skip). Looking up and
-# stacking 8,192 rows in Python costs most of a kernel's time, and a
-# commit's keys arrive in validator-set order every time, so the rows
-# are ASSEMBLED once per key sequence: _predecomp_memo keeps the last
-# few batches' arrays under their whole key bytes, and a batch whose
-# keys arrive in a sequence seen before is handed those arrays again.
-# The per-pubkey cache stays the source of truth: a memo entry is used
-# only while every one of its keys is still resident, and a use
-# refreshes their recency and counts as the `hit` it is.
+# 32-BYTE PUBKEY, so once a validator's key has been decompressed once,
+# EVERY later batch containing it hits, whatever the batch's
+# composition or order (models/coalescer.py merges arbitrary votes).
+# A key's row is the canonical field bytes of (-A).x and A.y plus the
+# validity flag: 65 bytes, (-A).x | A.y | ok.
+#
+# The rows live in ONE table (_KeyTable: arrays of _PREDECOMP_MAX_KEYS
+# slots) and a batch gets its rows BY INDEX: the lookup turns the
+# batch's keys u8[m,32] into slots int32[m] with array operations alone
+# (no Python statement runs once a lane: stacking 8,192 rows from a
+# dict of tuples cost 12.7 ms a chunk on the chip's host, a third of a
+# follower's pass; the lookup costs the same whether the batch's key
+# sequence is new or not). The table keeps a mirror of its rows on the
+# device, replaced whole after a fill, and the *_pre programs gather
+# rows[idx] there: a dispatch sends 4 bytes a lane of indices where it
+# sent the 65 of the rows. With a mesh the rows are taken on the host,
+# before the batch axis is split: the table is never sharded.
+#
+# _predecomp_memo keeps the resolved indices of the last few batches
+# under their whole key bytes (a commit's keys arrive in validator-set
+# order every time), so a key sequence seen before costs one dict
+# lookup. The table stays the source of truth: an entry is used only
+# while no slot has changed its key since it was resolved
+# (_KeyTable.epoch), and a use stamps its slots and counts as the `hit`
+# it is.
 
-_PREDECOMP_MAX_KEYS = 16384  # rows, ~1MB — covers a 10k-validator set
+_PREDECOMP_MAX_KEYS = 16384  # slots, 1.7 MB — covers a 10k-validator set
 # batches below this padded size skip the cache: one-shot small batches
 # must not pay the extra decompress dispatch (tests lower it to drive
 # the cache logic on already-compiled small shapes)
 _PREDECOMP_MIN_BATCH = 64
-# pubkey -> (xneg_bytes u8[32], y_bytes u8[32], ok bool)
-_predecomp: "OrderedDict[bytes, tuple]" = OrderedDict()
-# a padded batch's key bytes -> [its distinct keys (None until the first
-# reuse), (xneg u8[m,32], y u8[m,32], ok bool[m]) read-only]. Hold 8,
-# least recently used out (6.4 MB at most): a chain of ONE set repeats
-# 4 sequences (99.0% of chunks reused); where the key list moves every
-# 63 headers a pass is 50 new ones and none is reused (PERF.md, PR 40)
+# a padded batch's key bytes -> (the table's epoch when resolved, its
+# slots int32[m], read-only). Hold 8, least recently used out (256 KB of
+# indices at most, and the keys' 2 MB): a chain of ONE set repeats 4
+# sequences (99.0% of chunks reused); where the key list moves every 63
+# headers a pass is 50 new ones and none is reused (PERF.md, PR 40),
+# which is fine: resolving a sequence anew costs about what keeping it
+# saves. Not sized to any benchmark's passes: a real follower sees a
+# chunk once.
 _PREDECOMP_MEMO_MAX = 8
-_predecomp_memo: "OrderedDict[bytes, list]" = OrderedDict()
+_predecomp_memo: "OrderedDict[bytes, tuple]" = OrderedDict()
 # pubkeys sighted once (first sighting stays on the fused full kernel:
 # a one-shot batch must not pay a separate decompress dispatch)
 _predecomp_seen: "OrderedDict[bytes, bool]" = OrderedDict()
-# hit   = batch fully served from cached rows (pre kernel, no sqrt)
+
+
+class _KeyTable:
+    """The predecompressed rows of up to _PREDECOMP_MAX_KEYS pubkeys
+    (the value when the table was last cleared), a slot each: `keys`
+    u8[cap,32], `rows` u8[cap,65] ((-A).x | A.y | ok) and `stamp`, the
+    tick of the slot's last use. Slots 0..used-1 hold a key. The index
+    is the keys' first eight bytes as u64, sorted, beside the slot of
+    each: a prefix PROPOSES a slot and all 32 bytes decide. Not
+    thread-safe: every caller holds _predecomp_lock."""
+
+    def __init__(self):
+        self.epoch = 0
+        self.clear()
+
+    def clear(self) -> None:
+        cap = _PREDECOMP_MAX_KEYS
+        self.keys = np.zeros((cap, 32), np.uint8)
+        self._keys64 = self.keys.view(np.uint64)        # [cap,4], shared
+        self.rows = np.zeros((cap, 65), np.uint8)
+        self.stamp = np.zeros(cap, np.int64)
+        self.used = 0
+        self.tick = 0
+        # counts the times a slot's key changed under the indices handed
+        # out before: what resolved indices are valid against
+        self.epoch += 1
+        self._prefix = np.zeros(0, np.uint64)   # sorted
+        self._slot = np.zeros(0, np.int32)      # _prefix[i]'s slot
+        self._mirror = None
+
+    def __len__(self) -> int:
+        return self.used
+
+    def lookup(self, pk: np.ndarray):
+        """pk u8[m,32] -> (slot int32[m], miss bool[m], lanes that
+        needed the second step). Exact: a lane resolves to a slot only
+        if all 32 bytes equal the slot's key; a missed lane's slot is
+        meaningless. The second step walks on through the resident keys
+        that share a lane's prefix, one array operation a key of the
+        longest such run (keys are uniform in their low bytes: a run of
+        two takes 2**32 tries to make)."""
+        m = pk.shape[0]
+        if not self.used:
+            return np.zeros(m, np.int32), np.ones(m, np.bool_), 0
+        pk64 = np.ascontiguousarray(pk).view(np.uint64)     # [m,4]
+        prefix = pk64[:, 0]
+        pos = np.searchsorted(self._prefix, prefix)
+        np.minimum(pos, self.used - 1, out=pos)
+        slot = self._slot[pos]
+        found = (self._keys64[slot] == pk64).all(axis=1)
+        # the prefix is resident under another key: its neighbours next
+        todo = np.flatnonzero(~found & (self._prefix[pos] == prefix))
+        second = todo.size
+        while todo.size:
+            nxt = pos[todo] + 1
+            on = nxt < self.used
+            todo, nxt = todo[on], nxt[on]
+            on = self._prefix[nxt] == prefix[todo]
+            todo, nxt = todo[on], nxt[on]
+            pos[todo] = nxt
+            hit = (self._keys64[self._slot[nxt]] == pk64[todo]).all(axis=1)
+            slot[todo[hit]] = self._slot[nxt[hit]]
+            found[todo[hit]] = True
+            todo = todo[~hit]
+        return slot, ~found, second
+
+    def touch(self, slots: np.ndarray) -> None:
+        """One use of `slots`: they are the most recently used now."""
+        self.tick += 1
+        self.stamp[slots] = self.tick
+
+    def insert(self, keys, xneg, y, ok) -> int:
+        """Store the rows of k distinct keys, none resident, k at most
+        the slots that are free or stamped before the current tick: into
+        the free slots first, then over the least recently stamped.
+        Returns how many keys that put out."""
+        k = keys.shape[0]
+        free = min(k, self.keys.shape[0] - self.used)
+        slots = np.arange(self.used, self.used + free)
+        evicted = k - free
+        if evicted:
+            victims = np.argsort(self.stamp[:self.used],
+                                 kind="stable")[:evicted]
+            slots = np.concatenate([victims, slots])
+            self.epoch += 1
+        self.keys[slots] = keys
+        self.rows[slots, :32] = xneg
+        self.rows[slots, 32:64] = y
+        self.rows[slots, 64] = ok
+        self.stamp[slots] = self.tick
+        self.used += free
+        order = np.argsort(self._keys64[:self.used, 0], kind="stable")
+        self._prefix = self._keys64[:self.used, 0][order]
+        self._slot = order.astype(np.int32)
+        self._mirror = None
+        return evicted
+
+    def mirror(self):
+        """`rows` on the device, as of now: a copy, made at the first
+        dispatch after a fill and immutable, so a dispatch in flight
+        keeps the table it was resolved against."""
+        if self._mirror is None:
+            if telemetry.enabled():
+                _m_h2d_bytes.inc(self.rows.nbytes)
+            self._mirror = jnp.array(self.rows)
+        return self._mirror
+
+
+_predecomp = _KeyTable()
+# hit   = batch fully served from the table's rows (pre kernel, no sqrt)
 # fill  = repeat-traffic batch decompressed once + rows stored
 # full  = mostly-unseen batch routed to the fused full kernel
-# evict = per-pubkey rows dropped by the LRU (valset churn beyond
-#         capacity — invisible before this counter: a rotating valset
-#         quietly degraded every "hit" into a re-fill)
+# evict = per-pubkey rows dropped, least recently used first (valset
+#         churn beyond capacity — invisible before this counter: a
+#         rotating valset quietly degraded every "hit" into a re-fill)
 #
 # The same dict counts every device dispatch by the kernel that served
 # it (a sharded dispatch whose per-shard body is the jnp ladder counts
@@ -294,8 +413,8 @@ def _predecomp_note(outcome: str, n: int = 1, how: str = "") -> None:
     (registered by models/verifier beside the other tm_verifier_*
     families; lazy import — models.verifier is loaded in any process
     that dispatches batches here). `how` says, for a batch that gets
-    rows, whether they were "built" from the per-key rows (or the
-    device) or "reused" from the batch memo."""
+    rows, whether its slots were "built" (resolved by the table's
+    lookup) or "reused" (kept by the memo under its key sequence)."""
     _predecomp_stats[outcome] += n
     from tendermint_tpu.models import verifier
     if outcome == "evict":
@@ -305,11 +424,21 @@ def _predecomp_note(outcome: str, n: int = 1, how: str = "") -> None:
     if how:
         verifier._m_predecomp_assembled.labels(how).inc()
     verifier._m_predecomp_keys.set(len(_predecomp))
+
+
+def _predecomp_note_lanes(index: int, second: int) -> None:
+    """Lanes one lookup of the key table settled, by how (see
+    _KeyTable.lookup), into tm_verifier_predecomp_lanes_total."""
+    from tendermint_tpu.models import verifier
+    verifier._m_predecomp_lanes.labels("index").inc(index)
+    if second:
+        verifier._m_predecomp_lanes.labels("second").inc(second)
+
+
 # Batched verifies dispatch concurrently (fast-sync collector, lite
-# certify, RPC handlers all share default_verifier()), and OrderedDict
-# mutation is not thread-safe: a racing popitem against move_to_end can
-# raise KeyError out of verify(), which callers don't treat as a
-# verification failure. One lock guards both cache dicts.
+# certify, RPC handlers all share default_verifier()). One lock guards
+# the table, the memo and the sighted keys: a batch's slots and the
+# mirror they index are taken under ONE hold of it.
 _predecomp_lock = threading.Lock()
 
 
@@ -343,8 +472,19 @@ def _decompress_to_bytes(pk_u8):
     return fe.to_bytes(fe.neg(x)), fe.to_bytes(y), ok
 
 
+def _rows_at(rows, idx):
+    """(xneg bytes, y bytes, ok) of a batch: `rows` u8[m,65] as the
+    table packs them or, with `idx`, the table's mirror gathered at the
+    batch's slots (they come from _KeyTable.lookup, so they are in
+    bounds)."""
+    if idx is not None:
+        rows = rows.at[idx].get(mode="promise_in_bounds")
+    return rows[:, :32], rows[:, 32:64], rows[:, 64] != 0
+
+
 @jax.jit
-def _verify_pre_jnp(xnb, yb, ok, rb, s_bytes, h_bytes):
+def _verify_pre_jnp(rb, s_bytes, h_bytes, rows, idx=None):
+    xnb, yb, ok = _rows_at(rows, idx)
     s_bits = bits_from_bytes_dev(s_bytes)
     h_bits = bits_from_bytes_dev(h_bytes)
     xn, _ = fe.from_bytes(xnb)
@@ -359,8 +499,9 @@ def _verify_pre_jnp(xnb, yb, ok, rb, s_bytes, h_bytes):
 
 
 @jax.jit
-def _verify_pre_pallas(xnb, yb, ok, rb, s_bytes, h_bytes):
+def _verify_pre_pallas(rb, s_bytes, h_bytes, rows, idx=None):
     from tendermint_tpu.ops import ladder_pallas
+    xnb, yb, ok = _rows_at(rows, idx)
     return ladder_pallas.verify_pallas_pre(
         xnb, yb, ok, rb, bits_from_bytes_dev(s_bytes),
         bits_from_bytes_dev(h_bytes))
@@ -370,89 +511,85 @@ def _verify_cached_predecomp(pk_np, rb, s_bytes, h_bytes, mesh=None):
     """Returns verdicts via the predecompressed path, or None when this
     batch takes the fused full kernel (_predecomp_rows says which)."""
     with trace.span("verify.predecomp", rows=pk_np.shape[0]):
-        rows = _predecomp_rows(pk_np, mesh)
-    if rows is None:
+        handed = _predecomp_rows(pk_np, mesh)
+    if handed is None:
         return None
-    return _dispatch("pre", mesh, *rows, rb, s_bytes, h_bytes)
+    return _dispatch("pre", mesh, rb, s_bytes, h_bytes, *handed)
+
+
+def _table_rows(idx, mesh):
+    """What a `pre` program is handed, after R, s and h, for the slots
+    `idx` (the caller holds _predecomp_lock, so slots and rows are of
+    one table): the device mirror and the slots, or with a mesh the
+    rows themselves, taken here because the batch axis is about to be
+    split and the table is not. The slots are stamped as used."""
+    _predecomp.touch(idx)
+    if mesh is None:
+        return _predecomp.mirror(), idx
+    return (_predecomp.rows[idx],)
 
 
 def _predecomp_rows(pk_np, mesh):
-    """The batch's cached rows (xneg bytes, y bytes, ok), or None when
-    its pubkeys are mostly fresh (a first-sighting batch must not
-    pay the extra decompress dispatch — it takes the fused full kernel
-    while its keys are marked seen; any later batch made of seen keys
-    decompresses ONCE and fills per-key rows). A batch whose whole key
-    sequence was assembled before gets those arrays again, read-only
-    and shared between dispatches."""
-    n = pk_np.shape[0]
+    """The batch's keys' rows as _table_rows hands them over (mirror and
+    slots, or rows), or None when its pubkeys are mostly fresh (a first-sighting
+    batch must not pay the extra decompress dispatch — it takes the
+    fused full kernel while its keys are marked seen; any later batch
+    made of seen keys decompresses ONCE and fills the table). A batch
+    whose whole key sequence was resolved before gets the same slots
+    again, read-only and shared between dispatches."""
     raw = pk_np.tobytes()
     with _predecomp_lock:
         memo = _predecomp_memo.get(raw)
-        if memo is not None:
-            distinct, out = memo
-            if distinct is None:
-                # first reuse (a sequence never asked for again never
-                # pays for this): its distinct keys, in the order of
-                # their last row, so that moving them to the end one by
-                # one leaves the LRU as moving every row's key does
-                distinct = memo[0] = tuple(reversed(dict.fromkeys(
-                    raw[i - 32:i] for i in range(32 * n, 0, -32))))
-            if all(map(_predecomp.__contains__, distinct)):
-                for k in distinct:
-                    _predecomp.move_to_end(k)
-                _predecomp_memo.move_to_end(raw)
-                _predecomp_note("hit", how="reused")
-                return out
-            del _predecomp_memo[raw]    # a key was evicted: build anew
-    keys = [raw[i * 32:(i + 1) * 32] for i in range(n)]
-    with _predecomp_lock:
-        rows = [_predecomp.get(k) for k in keys]
-        miss = {k for k, r in zip(keys, rows) if r is None}
-        if not miss:
-            for k in keys:
-                _predecomp.move_to_end(k)
+        if memo is not None and memo[0] == _predecomp.epoch:
+            _predecomp_memo.move_to_end(raw)
+            _predecomp_note("hit", how="reused")
+            return _table_rows(memo[1], mesh)
+        idx, miss, second = _predecomp.lookup(pk_np)
+        _predecomp_note_lanes(idx.size - second, second)
+        if not miss.any():
+            idx.flags.writeable = False
+            _predecomp_memo[raw] = (_predecomp.epoch, idx)
+            while len(_predecomp_memo) > _PREDECOMP_MEMO_MAX:
+                _predecomp_memo.popitem(last=False)
             _predecomp_note("hit", how="built")
-        else:
-            fresh = miss - _predecomp_seen.keys()
-            for k in fresh:
-                _predecomp_seen[k] = True
-            while len(_predecomp_seen) > 4 * _PREDECOMP_MAX_KEYS:
-                _predecomp_seen.popitem(last=False)
-            if fresh:
-                # unseen keys in the batch: fused full kernel (no extra
-                # dispatch); the NEXT batch over these keys fills rows
-                _predecomp_note("full")
-                return None
-            _predecomp_note("fill", how="built")
-    if miss:
-        # repeat traffic over uncached keys: decompress the whole batch
-        # once (outside the lock — device dispatch), store per-key rows.
-        # A concurrent duplicate fill is harmless: same key, same bytes.
-        xnb_d, yb_d, ok_d = _dispatch("decompress", mesh, pk_np)
-        xnb_h = np.asarray(xnb_d)
-        yb_h = np.asarray(yb_d)
-        ok_h = np.asarray(ok_d)
-        with _predecomp_lock:
-            for i, k in enumerate(keys):
-                if k not in _predecomp:
-                    _predecomp[k] = (xnb_h[i].copy(), yb_h[i].copy(),
-                                     bool(ok_h[i]))
-            evicted = 0
-            while len(_predecomp) > _PREDECOMP_MAX_KEYS:
-                _predecomp.popitem(last=False)
-                evicted += 1
+            return _table_rows(idx, mesh)
+        new = np.unique(pk_np[miss], axis=0)
+        fresh = [k for k in map(bytes, new) if k not in _predecomp_seen]
+        for k in fresh:
+            _predecomp_seen[k] = True
+        while len(_predecomp_seen) > 4 * _PREDECOMP_MAX_KEYS:
+            _predecomp_seen.popitem(last=False)
+        distinct = new.shape[0] + np.unique(idx[~miss]).size
+        if fresh or distinct > _predecomp.keys.shape[0]:
+            # unseen keys in the batch: fused full kernel (no extra
+            # dispatch); the NEXT batch over these keys fills rows. So
+            # does a batch of more keys than the table has slots
+            _predecomp_note("full")
+            return None
+        _predecomp_note("fill", how="built")
+    # repeat traffic over keys that are not resident: decompress the
+    # whole batch once (outside the lock — device dispatch) and store
+    # the rows of those still missing (a concurrent fill of the same
+    # keys is harmless: what it stored is found, not stored twice)
+    xnb_d, yb_d, ok_d = _dispatch("decompress", mesh, pk_np)
+    xnb_h = np.asarray(xnb_d)
+    yb_h = np.asarray(yb_d)
+    ok_h = np.asarray(ok_d)
+    with _predecomp_lock:
+        idx, miss, _ = _predecomp.lookup(pk_np)
+        # the batch's resident keys first, so that none of them is put
+        # out for one of its others
+        _predecomp.touch(idx[~miss])
+        lanes = np.flatnonzero(miss)
+        if lanes.size:
+            lanes = lanes[np.unique(pk_np[lanes], axis=0,
+                                    return_index=True)[1]]
+            evicted = _predecomp.insert(pk_np[lanes], xnb_h[lanes],
+                                        yb_h[lanes], ok_h[lanes])
             if evicted:
                 _predecomp_note("evict", evicted)
-        return xnb_h, yb_h, ok_h
-    out = (np.stack([r[0] for r in rows]), np.stack([r[1] for r in rows]),
-           np.array([r[2] for r in rows], np.bool_))
-    for a in out:
-        a.flags.writeable = False
-    with _predecomp_lock:
-        _predecomp_memo[raw] = [None, out]
-        while len(_predecomp_memo) > _PREDECOMP_MEMO_MAX:
-            _predecomp_memo.popitem(last=False)
-    return out
+            idx, _, _ = _predecomp.lookup(pk_np)
+        return _table_rows(idx, mesh)
 
 
 # ---------------------------------------------------------------------------

@@ -140,10 +140,13 @@ def test_dispatches_are_counted_by_kernel(monkeypatch):
     z = np.zeros((24, 32), np.uint8)
     s0 = ed25519.predecomp_stats()
     ed25519.verify_prepared_async(z, z, z, z)          # bucket 32: full
-    ed25519._dispatch("pre", None, z, z, np.ones(24, np.bool_), z, z, z)
+    # rows as a mesh hands them over, then a table's mirror and slots
+    ed25519._dispatch("pre", None, z, z, z, np.zeros((24, 65), np.uint8))
+    ed25519._dispatch("pre", None, z, z, z, np.zeros((64, 65), np.uint8),
+                      np.zeros(24, np.int32))
     s1 = ed25519.predecomp_stats()
     assert s1["jnp_full"] == s0["jnp_full"] + 1
-    assert s1["jnp_pre"] == s0["jnp_pre"] + 1
+    assert s1["jnp_pre"] == s0["jnp_pre"] + 2
     for k in ("pallas_full", "pallas_pre", "mesh_jnp", "sign_pallas"):
         assert s1[k] == s0[k], k
     assert {"jnp_full[32]", "jnp_pre[24]"} <= set(s1["first_call_s"])

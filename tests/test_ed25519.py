@@ -112,15 +112,13 @@ def test_verify_batch_padding_and_empty():
 
 
 def test_predecompressed_cache_path_matches_full():
-    """The stable-valset fast path (pre-decompressed pubkey cache,
+    """The stable-valset fast path (the table of predecompressed rows,
     ops/ed25519._verify_cached_predecomp): the first occurrence of a
     pubkey batch takes the full kernel, repeats take the *_pre kernel
-    with cached (-A) bytes — verdicts must be identical across calls,
-    including invalid pubkeys and tampered signatures."""
-    import random
-
-    from tendermint_tpu.ops import ed25519
-    from tendermint_tpu.utils import ed25519_ref as ref
+    with the table's (-A) bytes gathered by index — verdicts must be
+    identical across calls, including invalid pubkeys and tampered
+    signatures."""
+    from bench_util import fast_signer
 
     rng = random.Random(99)
     n = 8
@@ -130,7 +128,6 @@ def test_predecompressed_cache_path_matches_full():
         m = b"pre-cache %d" % i
         pubs.append(ref.public_key(seed))
         msgs.append(m)
-        from bench_util import fast_signer
         sigs.append(fast_signer(seed)(m))
     # sprinkle failures: tampered sig, wrong msg, non-point pubkey
     sigs[5] = sigs[5][:32] + bytes([sigs[5][32] ^ 1]) + sigs[5][33:]
@@ -138,21 +135,18 @@ def test_predecompressed_cache_path_matches_full():
     pubs[7] = b"\xff" * 32
 
     expect = [i not in (5, 1, 7) for i in range(n)]
-    ed25519._predecomp.clear()
-    ed25519._predecomp_seen.clear()
     # run the cache at batch 8 (shapes earlier tests already compiled —
     # the production 64 gate exists to spare one-shot SMALL batches the
     # decompress dispatch, not because the cache logic differs by size)
-    orig_min = ed25519._PREDECOMP_MIN_BATCH
-    ed25519._PREDECOMP_MIN_BATCH = 8
-    try:
+    with predecomp_sandbox():
         r1 = ed25519.verify_batch(pubs, msgs, sigs)  # full kernel, records
         assert r1.tolist() == expect
-        r2 = ed25519.verify_batch(pubs, msgs, sigs)  # builds + uses cache
+        r2 = ed25519.verify_batch(pubs, msgs, sigs)  # fills + uses table
         assert r2.tolist() == expect
-        # per-pubkey rows: one per distinct key (incl. the invalid one,
-        # cached with ok=False so forged keys never re-pay the sqrt)
+        # one slot per distinct key (incl. the invalid one, kept with
+        # ok=False so forged keys never re-pay the sqrt)
         assert len(ed25519._predecomp) == n, "cache did not engage"
+        assert all(resident(*pubs))
         r3 = ed25519.verify_batch(pubs, msgs, sigs)  # cache hit
         assert r3.tolist() == expect
         assert ed25519._predecomp_stats["hit"] >= 1
@@ -165,22 +159,15 @@ def test_predecompressed_cache_path_matches_full():
                                   [sigs[i] for i in perm])
         assert r4.tolist() == [expect[i] for i in perm]
         assert ed25519._predecomp_stats["hit"] == hits0 + 1
-    finally:
-        ed25519._PREDECOMP_MIN_BATCH = orig_min
-        ed25519._predecomp.clear()
-        ed25519._predecomp_seen.clear()
 
 
 def test_predecomp_telemetry_stays_meaningful_under_churn():
-    """Valset rotation vs the per-pubkey predecompression LRU (ISSUE 11
-    satellite): a rotating valset must show up as full->fill->hit
-    cycles per rotation, evictions must be COUNTED (they were invisible
-    before — a churning valset quietly degraded every hit into a
-    re-fill), and the tm_verifier_predecomp_* counters must mirror the
-    host stats."""
+    """Valset rotation vs the table's capacity (ISSUE 11 satellite): a
+    rotating valset must show up as full->fill->hit cycles per
+    rotation, evictions must be COUNTED (they were invisible before — a
+    churning valset quietly degraded every hit into a re-fill), and the
+    tm_verifier_predecomp_* counters must mirror the host stats."""
     from tendermint_tpu import telemetry
-    from tendermint_tpu.ops import ed25519
-    from tendermint_tpu.utils import ed25519_ref as ref
 
     from bench_util import fast_signer
 
@@ -194,17 +181,9 @@ def test_predecomp_telemetry_stays_meaningful_under_churn():
             sigs.append(fast_signer(seed)(m))
         return pubs, msgs, sigs
 
-    was_enabled = telemetry.enabled()
-    telemetry.set_enabled(True)
-    ed25519._predecomp.clear()
-    ed25519._predecomp_seen.clear()
-    orig_min = ed25519._PREDECOMP_MIN_BATCH
-    orig_max = ed25519._PREDECOMP_MAX_KEYS
-    ed25519._PREDECOMP_MIN_BATCH = 8
-    ed25519._PREDECOMP_MAX_KEYS = 8  # one valset's worth of rows
-    s0 = ed25519.predecomp_stats()
-    ev0 = telemetry.value("verifier_predecomp_evictions_total") or 0.0
-    try:
+    with predecomp_sandbox(max_keys=8):     # one valset's worth of slots
+        s0 = ed25519.predecomp_stats()
+        ev0 = telemetry.value("verifier_predecomp_evictions_total") or 0.0
         a = batch(1)
         for _ in range(3):  # full (first sighting) -> fill -> hit
             assert ed25519.verify_batch(*a).all()
@@ -236,12 +215,6 @@ def test_predecomp_telemetry_stays_meaningful_under_churn():
         assert telemetry.value("verifier_predecomp_keys") == 8.0
         assert telemetry.value("verifier_predecomp_batches_total",
                                {"outcome": "hit"}) >= 2.0
-    finally:
-        telemetry.set_enabled(was_enabled)
-        ed25519._PREDECOMP_MIN_BATCH = orig_min
-        ed25519._PREDECOMP_MAX_KEYS = orig_max
-        ed25519._predecomp.clear()
-        ed25519._predecomp_seen.clear()
 
 
 def test_scalar_openssl_matches_pure_oracle():
@@ -309,19 +282,24 @@ def test_scalar_openssl_matches_pure_oracle():
         keys_mod._ossl_pub_cls = orig
 
 
+
+
 # --------------------------------------------------------------------------
-# The memo of assembled rows in front of the per-pubkey cache (ISSUE 25):
-# a chunk whose keys arrive in a sequence assembled before is handed the
-# same arrays again; the per-pubkey cache stays the source of truth.
+# The table of predecompressed rows (ISSUE 41): a batch gets its rows by
+# INDEX, resolved by array operations, and the memo in front of the table
+# (ISSUE 25) keeps a key sequence's resolved slots; the table stays the
+# source of truth.
 # --------------------------------------------------------------------------
 
 ASSEMBLED = "verifier_predecomp_assembled_total"
+LANES = "verifier_predecomp_lanes_total"
 
 
 @contextlib.contextmanager
 def predecomp_sandbox(min_batch=8, max_keys=None):
-    """Empty caches, counters on, the gate at the small shapes the
-    earlier tests compiled; everything as it was afterwards."""
+    """An empty table (of `max_keys` slots), memo and sighted set,
+    counters on, the gate at the small shapes the earlier tests
+    compiled; everything as it was afterwards."""
     from tendermint_tpu import telemetry
     was_enabled = telemetry.enabled()
     orig = ed25519._PREDECOMP_MIN_BATCH, ed25519._PREDECOMP_MAX_KEYS
@@ -350,28 +328,64 @@ def assembled():
             ed25519.predecomp_stats())
 
 
-def fill_by_hand(keys):
-    """Rows for `keys` as a fill would leave them, without the sqrt:
-    the row bytes are a function of the key, which is all the cache
-    layer knows of them."""
-    for k in keys:
-        d = hashlib.sha512(k).digest()
-        ed25519._predecomp[k] = (np.frombuffer(d[:32], np.uint8).copy(),
-                                 np.frombuffer(d[32:], np.uint8).copy(),
-                                 bool(d[0] & 1))
+def lanes():
+    """(index, second) of the lookup's lane counter."""
+    from tendermint_tpu import telemetry
+    return tuple(telemetry.value(LANES, {"how": how}) or 0.0
+                 for how in ("index", "second"))
+
+
+def row_of(k):
+    """A key's row as fill_by_hand leaves it, without the sqrt: the row
+    bytes are a function of the key, which is all the cache layer knows
+    of them."""
+    d = hashlib.sha512(k).digest()
+    return d[:32], d[32:], bool(d[0] & 1)
 
 
 def as_rows(keys):
     return np.frombuffer(b"".join(keys), np.uint8).reshape(len(keys), 32)
 
 
-def rows_match_their_keys(rows, keys):
-    xn, y, ok = rows
+def resident(*keys):
+    """Which of `keys` hold a slot of the table."""
+    return (~ed25519._predecomp.lookup(as_rows(keys))[1]).tolist()
+
+
+def fill_by_hand(keys):
+    """Rows for distinct `keys`, none resident, as a fill stores them:
+    one tick for the batch, then the insert."""
+    keys = sorted(keys)
+    rows = [row_of(k) for k in keys]
+    with ed25519._predecomp_lock:
+        ed25519._predecomp.touch(np.zeros(0, np.int32))
+        return ed25519._predecomp.insert(
+            as_rows(keys), as_rows([r[0] for r in rows]),
+            as_rows([r[1] for r in rows]), np.array([r[2] for r in rows]))
+
+
+def handed_rows(handed):
+    """What a `pre` program computes on, as ops/ed25519._rows_at takes
+    it apart: the mirror gathered at the slots, or (a mesh) the rows
+    taken on the host."""
+    rows = np.asarray(handed[0])
+    assert rows.dtype == np.uint8 and rows.shape[1] == 65
+    if len(handed) == 2:
+        idx = handed[1]
+        assert idx.dtype == np.int32 and idx.min() >= 0
+        assert idx.max() < len(ed25519._predecomp)
+        rows = rows[idx]
+    assert set(np.unique(rows[:, 64])) <= {0, 1}
+    return rows[:, :32], rows[:, 32:64], rows[:, 64] != 0
+
+
+def rows_match_their_keys(handed, keys):
+    xn, y, ok = handed_rows(handed)
     assert xn.shape == y.shape == (len(keys), 32) and ok.shape == (len(keys),)
     for i, k in enumerate(keys):
-        want = ed25519._predecomp[k]
-        assert xn[i].tobytes() == want[0].tobytes(), i
-        assert y[i].tobytes() == want[1].tobytes(), i
+        want = row_of(k)
+        assert xn[i].tobytes() == want[0], i
+        assert y[i].tobytes() == want[1], i
         assert bool(ok[i]) == want[2], i
 
 
@@ -393,26 +407,30 @@ LAYOUTS = {
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
-def test_reused_rows_equal_rows_built_with_the_memo_emptied(layout):
+def test_reused_slots_equal_slots_resolved_with_the_memo_emptied(layout):
     keys = LAYOUTS[layout]()
     pk = as_rows(keys)
     with predecomp_sandbox():
         fill_by_hand(set(keys))
         (b0, r0), s0 = assembled()
+        i0, x0 = lanes()
         first = ed25519._predecomp_rows(pk, None)
         again = ed25519._predecomp_rows(pk.copy(), None)
         (b1, r1), s1 = assembled()
         assert (b1 - b0, r1 - r0) == (1.0, 1.0)
         assert s1["hit"] == s0["hit"] + 2 and s1["fill"] == s0["fill"]
+        # the same slots and, no fill between them, the same mirror
         assert all(a is b for a, b in zip(first, again))
+        # the lookup ran once, settled every lane and walked for none
+        assert lanes() == (i0 + len(keys), x0)
         ed25519._predecomp_memo.clear()
         built = ed25519._predecomp_rows(pk, None)
-        assert all(a is not b for a, b in zip(built, again))
-        for a, b in zip(built, again):
-            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+        assert built[1] is not again[1]
+        assert built[1].dtype == again[1].dtype == np.int32
+        assert built[1].tobytes() == again[1].tobytes()
         rows_match_their_keys(again, keys)
         # the same keys in another order are another sequence: never
-        # answered with this one's rows, before or after it is memoised
+        # answered with this one's slots, before or after it is memoised
         other = keys[1:] + keys[:1]
         if layout != "8192_distinct":
             other = sorted(keys)
@@ -439,13 +457,13 @@ def signed_batch(tag, n=8):
 
 def test_reuse_hands_out_rows_not_verdicts():
     """Two batches over identical keys, the second with one tampered
-    signature: the rows are reused, each lane's verdict is its own."""
+    signature: the slots are reused, each lane's verdict is its own."""
     pubs, msgs, sigs = signed_batch(11)
     pubs[6] = b"\xff" * 32          # a key that is no point, cached as such
     bad = list(sigs)
     bad[3] = bad[3][:7] + bytes([bad[3][7] ^ 4]) + bad[3][8:]   # R, not s
     with predecomp_sandbox():
-        for _ in range(3):          # full, fill, hit: assembled and kept
+        for _ in range(3):          # full, fill, hit: resolved and kept
             ed25519.verify_batch(pubs, msgs, sigs)
         (b0, r0), _ = assembled()
         good_got = ed25519.verify_batch(pubs, msgs, sigs)
@@ -460,7 +478,7 @@ def test_reuse_hands_out_rows_not_verdicts():
     assert bad_got.tolist() == [i not in (3, 6) for i in range(8)]
 
 
-def test_a_cleared_cache_is_not_answered_from_the_memo():
+def test_a_cleared_table_is_not_answered_from_the_memo():
     a = signed_batch(12)
     with predecomp_sandbox():
         for _ in range(4):          # full, fill, hit, reuse
@@ -469,6 +487,7 @@ def test_a_cleared_cache_is_not_answered_from_the_memo():
         assert r0 >= 1.0 and len(ed25519._predecomp_memo) == 1
         ed25519._predecomp.clear()
         ed25519._predecomp_seen.clear()
+        assert len(ed25519._predecomp) == 0
         for _ in range(3):          # full -> fill -> hit again
             assert ed25519.verify_batch(*a).all()
         (b1, r1), s1 = assembled()
@@ -487,12 +506,15 @@ def test_an_evicted_key_sends_its_sequence_through_fill_again():
         for _ in range(4):
             assert ed25519.verify_batch(*a).all()
         (b0, r0), s0 = assembled()
-        for _ in range(2):          # full, then a fill that stores a 9th row
+        for _ in range(2):          # full, then a fill that needs a 9th slot
             assert ed25519.verify_batch(*mixed).all()
         _, s1 = assembled()
         assert s1["evict"] == s0["evict"] + 1 and s1["keys"] == 8
-        assert a[0][0] not in ed25519._predecomp    # the oldest of a's
+        # the one key the filling batch did not use went, none of its own
+        assert resident(*a[0]) == [True] * 7 + [False]
+        assert all(resident(*mixed[0]))
         (b1, r1), s1 = assembled()
+        assert len(ed25519._predecomp_memo) == 1    # a's, of an epoch ago
         assert ed25519.verify_batch(*a).all()
         (b2, r2), s2 = assembled()
         assert s2["fill"] == s1["fill"] + 1 and s2["hit"] == s1["hit"]
@@ -503,11 +525,12 @@ def test_an_evicted_key_sends_its_sequence_through_fill_again():
 
 
 def test_a_reuse_counts_as_the_hit_it_is_and_refreshes_recency():
-    keys = valset(8, tag=1) * 2
-    with predecomp_sandbox():
-        fill_by_hand(valset(8, tag=1))
+    old, young, newer = (valset(8, tag=t) for t in (1, 2, 3))
+    keys = old * 2
+    with predecomp_sandbox(max_keys=16):
+        fill_by_hand(old)
         ed25519._predecomp_rows(as_rows(keys), None)
-        fill_by_hand(valset(8, tag=2))      # younger than every key of it
+        fill_by_hand(young)                 # younger than every key of it
         from tendermint_tpu import telemetry
         hit0 = telemetry.value("verifier_predecomp_batches_total",
                                {"outcome": "hit"}) or 0.0
@@ -518,8 +541,15 @@ def test_a_reuse_counts_as_the_hit_it_is_and_refreshes_recency():
         assert s1["hit"] == s0["hit"] + 1
         assert telemetry.value("verifier_predecomp_batches_total",
                                {"outcome": "hit"}) == hit0 + 1.0
-        # the LRU's order is what per-row move_to_end leaves
-        assert list(ed25519._predecomp) == valset(8, tag=2) + valset(8, tag=1)
+        # one stamp a use: the reused sequence's slots are the newest
+        table = ed25519._predecomp
+        slots = {k: int(table.lookup(as_rows([k]))[0][0])
+                 for k in old + young}
+        assert (min(table.stamp[slots[k]] for k in old)
+                > max(table.stamp[slots[k]] for k in young))
+        # so a fill past the table's room puts the others out
+        assert fill_by_hand(newer) == 8
+        assert all(resident(*old, *newer)) and not any(resident(*young))
 
 
 def test_the_memo_holds_at_most_its_bound():
@@ -542,17 +572,35 @@ def test_the_memo_holds_at_most_its_bound():
         assert assembled()[0][0] == b1 + 1.0
 
 
-def test_reused_arrays_refuse_a_write():
+def test_handed_out_arrays_refuse_a_write():
     keys = valset(8, tag=4)
     with predecomp_sandbox():
         fill_by_hand(keys)
         for _ in range(2):
-            rows = ed25519._predecomp_rows(as_rows(keys), None)
-            for a in rows:
-                assert not a.flags.writeable
-                with pytest.raises(ValueError):
-                    a[0] = 0
-        rows_match_their_keys(rows, keys)
+            handed = ed25519._predecomp_rows(as_rows(keys), None)
+            mirror, idx = handed
+            assert not idx.flags.writeable
+            with pytest.raises(ValueError):
+                idx[0] = 0
+            with pytest.raises(TypeError):      # immutable by its type
+                mirror[0] = 0
+        rows_match_their_keys(handed, keys)
+        # and the mirror is a copy: the table's next fill does not reach it
+        before = np.asarray(mirror).tobytes()
+        fill_by_hand(valset(8, tag=5))
+        ed25519._predecomp.rows[:8] ^= 0xFF
+        assert np.asarray(mirror).tobytes() == before
+
+
+def test_with_a_mesh_the_rows_are_taken_on_the_host_before_the_shard():
+    keys = valset(8, tag=6) * 2
+    with predecomp_sandbox():
+        fill_by_hand(set(keys))
+        for _ in range(2):              # built, then reused
+            handed = ed25519._predecomp_rows(as_rows(keys), object())
+            rows, = handed
+            assert type(rows) is np.ndarray and rows.shape == (16, 65)
+            rows_match_their_keys(handed, keys)
 
 
 def test_threads_asking_for_one_sequence_at_once_get_equal_rows():
@@ -584,13 +632,185 @@ def test_threads_asking_for_one_sequence_at_once_get_equal_rows():
             assert not any(t.is_alive() for t in threads) and not errors
             (b1, r1), s1 = assembled()
             assert len(got) == n_threads * rounds
-            want = [a.tobytes() for a in got[0]]
-            for rows in got:
-                assert [a.tobytes() for a in rows] == want
+            want = [a.tobytes() for a in handed_rows(got[0])]
+            for handed in got:
+                assert [a.tobytes() for a in handed_rows(handed)] == want
             rows_match_their_keys(got[-1], keys)
             # every call was a hit, built or reused, and none was lost
             assert s1["hit"] - s0["hit"] == len(got)
             assert (b1 - b0) + (r1 - r0) == len(got) and r1 - r0 >= rounds - 1
             assert len(ed25519._predecomp_memo) == 1
+    finally:
+        sys.setswitchinterval(interval)
+
+
+def two_key_lists_82_commits():
+    """A follower's chunk where a key joined: 41 commits of one list of
+    100 keys, 41 of the list with its 38th key replaced (8,200 lanes:
+    the chunk takes 8,192 of them)."""
+    before = valset(100, tag=7)
+    after = before[:37] + valset(1, tag=8) + before[38:]
+    return (before * 41 + after * 41)[:8192]
+
+
+REAL_LAYOUTS = {
+    "82_commits_over_two_key_lists": two_key_lists_82_commits,
+    "8192_distinct": lambda: valset(8192, tag=9),
+    # 60 commits of 100 keys and 2,192 padded lanes of the zero key
+    "padded_zero_key_lanes": lambda: (valset(100, tag=7) * 60
+                                      + [bytes(32)] * 2192),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(REAL_LAYOUTS))
+def test_rows_by_index_equal_decompress_row_for_row(layout):
+    """The rows a dispatch computes on are byte-equal to what
+    _decompress_to_bytes gives for each lane's key (half of these keys
+    are no point: their rows carry ok False). The table is filled the
+    way the program fills it, by the chunk's distinct keys in a batch
+    of their own or (8,192 distinct) by the chunk itself; the chunk
+    then resolves by the lookup alone."""
+    keys = REAL_LAYOUTS[layout]()
+    pk = as_rows(keys)
+    distinct = sorted(set(keys))
+    small = as_rows(distinct + [bytes(32)] * (-len(distinct) % 128))
+    with predecomp_sandbox(min_batch=64):
+        assert ed25519._predecomp_rows(small, None) is None     # sighted
+        filled = ed25519._predecomp_rows(small, None)           # fill
+        assert ed25519.predecomp_stats()["keys"] == len(
+            {small[i].tobytes() for i in range(small.shape[0])})
+        want = [np.asarray(a) for a in ed25519._decompress_to_bytes(small)]
+        for got, ref_rows in zip(handed_rows(filled), want):
+            assert got.dtype == ref_rows.dtype
+            assert got.tobytes() == ref_rows.tobytes()
+        assert 0 < want[2].sum() < len(want[2])     # points and non-points
+        # the reference, lane by lane, in plain Python
+        at = {small[i].tobytes(): i for i in range(small.shape[0])}
+        lane = [at[k] for k in keys]
+        (b0, r0), s0 = assembled()
+        i0, x0 = lanes()
+        handed = ed25519._predecomp_rows(pk, None)
+        for got, ref_rows in zip(handed_rows(handed), want):
+            assert got.tobytes() == ref_rows[lane].tobytes()
+        (b1, r1), s1 = assembled()
+        assert (b1 - b0, r1 - r0) == (1.0, 0.0)
+        assert s1["hit"] == s0["hit"] + 1 and s1["decompress"] == s0["decompress"]
+        assert lanes() == (i0 + 8192, x0)
+
+
+def test_two_resident_keys_sharing_the_lookups_prefix_both_resolve():
+    """The first eight bytes propose a slot and all 32 decide: keys made
+    to share them (2**32 tries for an adversary's pair) resolve each to
+    its own row, by the walk, and the lanes that needed it are counted;
+    a key that shares the prefix and is not resident is a miss."""
+    plain = valset(29, tag=10)
+    twins = [plain[3][:8] + hashlib.sha256(b"twin %d" % i).digest()[8:]
+             for i in range(3)]                 # plain[3]'s prefix, four times
+    stranger = plain[3][:8] + bytes(24)
+    keys = plain + twins
+    chunk = (keys * 4)[:128]
+    with predecomp_sandbox():
+        fill_by_hand(keys)
+        i0, x0 = lanes()
+        handed = ed25519._predecomp_rows(as_rows(chunk), None)
+        rows_match_their_keys(handed, chunk)
+        i1, x1 = lanes()
+        # each key four times; the prefix proposes one of the four that
+        # share it (which is the table's business): its lanes need no walk
+        assert (i1 - i0, x1 - x0) == (128.0 - 12.0, 12.0)
+        slot, miss, second = ed25519._predecomp.lookup(
+            as_rows([stranger, twins[2], plain[0]]))
+        assert miss.tolist() == [True, False, False] and second >= 1
+    # a table of ONE prefix throughout, asked for a key it lacks too
+    with predecomp_sandbox():
+        same = [bytes(8) + hashlib.sha256(b"same %d" % i).digest()[8:]
+                for i in range(16)]
+        fill_by_hand(same)
+        slot, miss, second = ed25519._predecomp.lookup(
+            as_rows(same[::-1] + [bytes(32)]))
+        assert miss.tolist() == [False] * 16 + [True] and second >= 16
+        rows_match_their_keys(
+            ed25519._predecomp_rows(as_rows(same[::-1]), None), same[::-1])
+
+
+def test_a_fill_past_capacity_evicts_the_least_recently_stamped():
+    """... and a dispatch taken before it still verifies right: it keeps
+    the mirror its slots were resolved against."""
+    a = signed_batch(15)
+    b = signed_batch(16, n=4)
+    half = [x[:4] * 2 for x in a]           # a's first four keys, twice
+    newcomers = [x * 2 for x in b]          # four new keys, twice
+    pk, rb, sb, hb, pre = ed25519.prepare_batch_bytes(*a)
+    assert pre.all()
+    with predecomp_sandbox(max_keys=8):
+        for _ in range(2):                  # full, fill: a's eight resident
+            assert ed25519.verify_batch(*a).all()
+        taken = ed25519._predecomp_rows(pk, None)
+        assert ed25519.verify_batch(*half).all()    # a hit: four stamped anew
+        _, s0 = assembled()
+        for _ in range(2):                  # full, then a fill past the room
+            assert ed25519.verify_batch(*newcomers).all()
+        _, s1 = assembled()
+        assert s1["evict"] == s0["evict"] + 4 and s1["keys"] == 8
+        assert all(resident(*a[0][:4], *b[0]))
+        assert not any(resident(*a[0][4:]))
+        # the table's slots hold other keys now; the dispatch taken
+        # before the fill computes on the rows it was resolved against
+        assert ed25519._predecomp.mirror() is not taken[0]
+        got = ed25519._dispatch("pre", None, rb, sb, hb, *taken)
+        assert np.asarray(got).tolist() == [True] * 8
+        # and a's sequence, resolved an epoch ago, goes through fill
+        assert ed25519.verify_batch(*a).all()
+        _, s2 = assembled()
+        assert s2["fill"] == s1["fill"] + 1
+
+
+def test_slots_and_mirror_of_one_dispatch_are_of_one_table_under_fills(
+        monkeypatch):
+    """Threads asking for overlapping key sets through a table too small
+    for all of them, so that fills and evictions run beside the lookups:
+    whatever a call is handed, its mirror gathered at its slots is its
+    keys' rows. The decompress dispatch is a stand-in (rows by
+    row_of); the cache layer is under test."""
+    pool = valset(40, tag=11)
+    sets = [pool[i:i + 16] for i in (0, 8, 16, 24)]
+    n_threads, rounds = 8, 40
+    errors, served = [], []
+
+    def fake_dispatch(variant, mesh, pk):
+        assert variant == "decompress"
+        rows = [row_of(pk[i].tobytes()) for i in range(pk.shape[0])]
+        return (as_rows([r[0] for r in rows]), as_rows([r[1] for r in rows]),
+                np.array([r[2] for r in rows]))
+
+    def ask(t):
+        try:
+            for r in range(rounds):
+                keys = sets[(t + r) % len(sets)]
+                handed = ed25519._predecomp_rows(as_rows(keys), None)
+                if handed is not None:      # None: a first sighting
+                    rows_match_their_keys(handed, keys)
+                    served.append(1)
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    monkeypatch.setattr(ed25519, "_dispatch", fake_dispatch)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with predecomp_sandbox(max_keys=24):
+            _, s0 = assembled()
+            threads = [threading.Thread(target=ask, args=(t,))
+                       for t in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads)
+            assert not errors, errors[:1]
+            _, s1 = assembled()
+            assert len(served) >= n_threads * (rounds - len(sets))
+            assert s1["evict"] > s0["evict"] and s1["fill"] > s0["fill"]
+            assert s1["keys"] <= 24
     finally:
         sys.setswitchinterval(interval)

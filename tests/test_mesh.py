@@ -150,6 +150,35 @@ def test_batch_verifier_mesh_knob():
     assert ed25519.predecomp_stats()["jnp_full"] == k1["jnp_full"] + 1
 
 
+def test_the_predecompressed_path_takes_its_rows_before_the_shard():
+    """Under a mesh a batch over resident keys is handed ROWS, taken
+    from the key table on the host before the batch axis is split (the
+    table is never sharded): full, fill, hit give the verdicts of the
+    unsharded oracle, the `pre` dispatches among them."""
+    mesh = make_mesh(8)
+    pubs, msgs, sigs = signed_batch(16, tamper={2, 11})
+    want = [i not in (2, 11) for i in range(16)]
+    caches = (ed25519._predecomp, ed25519._predecomp_seen,
+              ed25519._predecomp_memo)
+    gate = ed25519._PREDECOMP_MIN_BATCH
+    ed25519._PREDECOMP_MIN_BATCH = 8
+    for c in caches:
+        c.clear()
+    try:
+        for outcome in ("full", "fill", "hit", "hit"):
+            s0 = ed25519.predecomp_stats()
+            got = ed25519.verify_batch(pubs, msgs, sigs, mesh=mesh)
+            assert got.tolist() == want, outcome
+            s1 = ed25519.predecomp_stats()
+            assert s1[outcome] == s0[outcome] + 1
+            assert s1["mesh_jnp"] == s0["mesh_jnp"] + 1
+        assert "jnp_pre[16/8]" in s1["first_call_s"]
+    finally:
+        ed25519._PREDECOMP_MIN_BATCH = gate
+        for c in caches:
+            c.clear()
+
+
 def test_batch_verifier_mesh_spec_errors():
     from tendermint_tpu.models.verifier import BatchVerifier
     # spec validation is eager (at construction, i.e. node startup) ...
