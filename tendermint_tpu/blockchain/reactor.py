@@ -30,6 +30,23 @@ from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
 from tendermint_tpu.types.block import TXS_PATH, Block, BlockID
 
+# How the window engine came by a block's verdict, and what became of
+# the lanes the device verified for it. On a chain whose validator set
+# never moves every block is "batched" and every lane "used"; where the
+# set's hash moves every few blocks nearly every block is "reverified"
+# and its lanes "discarded" (see _sync_window).
+_m_commits = telemetry.counter(
+    "sync_commits_total",
+    "Blocks fast-sync applied, by how their commit was judged: batched "
+    "(the window's pooled verdicts, under the set the window was "
+    "collected with) or reverified (one synchronous verify_commit under "
+    "the live set, because the set moved after collection)", ("how",))
+_m_lanes = telemetry.counter(
+    "sync_lanes_total",
+    "Signature lanes of a window's pooled batch, by what the apply loop "
+    "did with their verdicts: used, or discarded because the validator "
+    "set's hash was no longer the collection set's", ("how",))
+
 BLOCKCHAIN_CHANNEL = 0x40
 # the transactions of the block a block_response carries
 _RESPONSE_TXS = ("block",) + TXS_PATH
@@ -326,8 +343,9 @@ class BlockchainReactor(Reactor):
     def _collect_window(self, skip: int):
         """Build (per_block, items) for the window starting `skip` blocks
         past the pool height, verified OPTIMISTICALLY against the current
-        valset. Returns None when fewer than 2 consecutive blocks are
-        ready there."""
+        valset: the set in force when the window is collected, not the
+        one each block was signed by. Returns None when fewer than 2
+        consecutive blocks are ready there."""
         blocks = self.pool.peek_window(self.verify_window, skip=skip)
         if len(blocks) < 2:
             return None
@@ -345,9 +363,12 @@ class BlockchainReactor(Reactor):
                 items, item_power = batch_valset.commit_verification_items(
                     chain_id, block_id, block.header.height, commit)
             except ValueError:
-                # not necessarily a bad peer: the valset may change inside
-                # the window; such blocks re-verify against the updated
-                # set in the apply loop
+                # not necessarily a bad peer: where membership changed
+                # since the collection set, the commit has another size
+                # and contributes no lanes; the block re-verifies against
+                # the live set in the apply loop. On a chain whose set
+                # moves every few blocks that is no rare case: see
+                # _sync_window and tm_sync_commits_total{how}
                 per_block.append((block, parts, block_id, commit,
                                   None, 0, 0))
                 continue
@@ -383,17 +404,21 @@ class BlockchainReactor(Reactor):
                 parts, block_id = self._parts_and_id(block)
                 rebuilt = True
             vs_now = self.state.validators
+            batched = not rebuilt and item_power is not None and \
+                vs_now.hash() == batch_valset_hash
             try:
-                if not rebuilt and item_power is not None and \
-                        vs_now.hash() == batch_valset_hash:
+                if batched:
                     vs_now.check_commit_results(ok[lo:lo + n], item_power)
                 else:
                     # valset changed since collection (or collect
                     # failed): verify against the set that actually
-                    # signed
-                    vs_now.verify_commit(chain_id, block_id,
-                                         block.header.height, commit,
-                                         verifier=verifier)
+                    # signed, alone and synchronously; the lanes the
+                    # device verified for this block are thrown away
+                    with trace.span("sync.reverify",
+                                    req=block.header.height):
+                        vs_now.verify_commit(chain_id, block_id,
+                                             block.header.height, commit,
+                                             verifier=verifier)
             except ValueError:
                 self._punish_bad_window(block.header.height)
                 return applied
@@ -409,6 +434,8 @@ class BlockchainReactor(Reactor):
                 self.state, block_id, block, trust_last_commit=True)
             self.pool.pop_request()
             applied += 1
+            _m_commits.labels("batched" if batched else "reverified").inc()
+            _m_lanes.labels("used" if batched else "discarded").inc(n)
             if self.after_apply is not None:
                 # recovery plane: interval snapshots + pruning fire on
                 # the sync path too (the app sits at exactly this
@@ -427,7 +454,18 @@ class BlockchainReactor(Reactor):
         collection valset is the one BEFORE the pending window applies.
         If an apply changes the valset, the stale batch results are
         discarded per block by the hash check in _apply_window and those
-        blocks re-verify against the live set. Returns True on progress.
+        blocks re-verify against the live set, one synchronous
+        verify_commit each (`sync.reverify`). That is cheap only where
+        the set is constant. The first change after the collection point
+        discards every later block of the window and, through the
+        pipelining, of the window after it: on a chain whose set's hash
+        moves every few blocks (a delegation moves a power) nearly every
+        block is verified twice, once on the device for nothing and once
+        here, and under `auto_threshold` signatures that second time is
+        scalar on the host. tm_sync_commits_total{how} and
+        tm_sync_lanes_total{how} count both; pooling across set changes
+        is ROADMAP.md Queue 1's "fast-sync pools nothing once the set
+        moves". Returns True on progress.
         """
         pending = self._pending_window
         skip = 0 if pending is None else max(0, len(pending[0]))

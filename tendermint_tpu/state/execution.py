@@ -193,7 +193,9 @@ class BlockExecutor:
                     state_store.save_abci_responses(
                         height, responses.to_obj())
             fail.fail_point("execution.after_save_abci_responses")
-            new_state = update_state(state, block_id, block, responses)
+            with trace.span("apply.update", req=height, changed=len(
+                    responses.end_block_obj.get("validator_updates", ()))):
+                new_state = update_state(state, block_id, block, responses)
 
             # Commit app + update mempool under the mempool lock
             # (state/execution.go:125-156): no CheckTx may interleave

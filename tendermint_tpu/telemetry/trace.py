@@ -88,11 +88,20 @@ SPANS = {
     "sync.wait": "sync window engine",
     "sync.apply": "sync window engine",
     "sync.store": "sync window engine",
+    # one event per block whose batched verdicts the apply loop threw
+    # away because the validator set moved after its window was
+    # collected: the synchronous verify_commit under the live set
+    # (commit.* nest in it; req = the height)
+    "sync.reverify": "verifier",
     "wire.decode_block": "sync window engine",
     "apply.validate": "apply and Merkle",   # incl. the data hash again
     "apply.exec": "apply and Merkle",
     "apply.commit": "apply and Merkle",
     "apply.save": "apply and Merkle",
+    # update_state, one event a block: EndBlock's validator updates
+    # (`changed` of them) through update_with_changes, the proposer
+    # rotation over every power, the next State
+    "apply.update": "apply and Merkle",
     # the authenticated state tree and the read path: one event per
     # StateTree.commit (nests in apply.commit; req = the version), one
     # per bulk load of a store (InitChain's records, a snapshot
