@@ -31,21 +31,29 @@ from tendermint_tpu.types import encoding
 from tendermint_tpu.types.block import TXS_PATH, Block, BlockID
 
 # How the window engine came by a block's verdict, and what became of
-# the lanes the device verified for it. On a chain whose validator set
-# never moves every block is "batched" and every lane "used"; where the
-# set's hash moves every few blocks nearly every block is "reverified"
-# and its lanes "discarded" (see _sync_window).
+# the lanes the device verified for it. A verdict belongs to its key:
+# on a chain whose validator set never moves, and above a change of
+# stake, every block is "batched" and every lane "used"; above a join
+# the lanes "discarded" are the joiners', whose keys the window's set
+# did not hold; a block is "reverified" only where its window had no
+# lanes for it (see _sync_window).
 _m_commits = telemetry.counter(
     "sync_commits_total",
     "Blocks fast-sync applied, by how their commit was judged: batched "
-    "(the window's pooled verdicts, under the set the window was "
-    "collected with) or reverified (one synchronous verify_commit under "
-    "the live set, because the set moved after collection)", ("how",))
+    "(from the window's pooled verdicts, under the set the window was "
+    "collected with or carried to the set in force) or reverified (one "
+    "whole synchronous verify_commit under the live set, because the "
+    "window had no lanes for the block)", ("how",))
 _m_lanes = telemetry.counter(
     "sync_lanes_total",
     "Signature lanes of a window's pooled batch, by what the apply loop "
-    "did with their verdicts: used, or discarded because the validator "
-    "set's hash was no longer the collection set's", ("how",))
+    "did with their verdicts: used, or discarded and verified again "
+    "because the live set holds another key at the vote's slot (or the "
+    "whole block was reverified)", ("how",))
+_m_live_judged = telemetry.counter(
+    "sync_live_judged_total",
+    "Blocks whose pooled verdicts fast-sync judged under a validator set "
+    "other than the one their window was collected with", ())
 
 BLOCKCHAIN_CHANNEL = 0x40
 # the transactions of the block a block_response carries
@@ -341,21 +349,33 @@ class BlockchainReactor(Reactor):
         return verifier
 
     def _collect_window(self, skip: int):
-        """Build (per_block, items) for the window starting `skip` blocks
-        past the pool height, verified OPTIMISTICALLY against the current
-        valset: the set in force when the window is collected, not the
-        one each block was signed by. Returns None when fewer than 2
-        consecutive blocks are ready there."""
+        """Build (per_block, items, valset_hash, part_size) for the
+        window starting `skip` blocks past the pool height, verified
+        OPTIMISTICALLY under the keys of the current valset: the set in
+        force when the window is collected, not the one each block was
+        signed by. Every vote brings one lane, under the best guess of
+        its key. Where a block's header names the collection set, lane
+        i is vote i under that set's key i. Where it names another
+        (the set has moved, or will have by the time the block
+        applies), a join has shifted the slots between the two
+        addresses, so the vote is paired with the key this set holds
+        for the vote's own address (ValidatorSet.rows_by_address). The
+        header is untrusted and only a hint for pairing: _apply_window
+        keeps a verdict for the key it was computed under and no other.
+        Returns None when fewer than 2 consecutive blocks are ready
+        there."""
         blocks = self.pool.peek_window(self.verify_window, skip=skip)
         if len(blocks) < 2:
             return None
         chain_id = self.state.chain_id
         batch_valset = self.state.validators
+        vs_hash = batch_valset.hash()
         part_size = \
             self.state.consensus_params.block_gossip.block_part_size_bytes
         batches = []
         lo = 0
-        per_block = []  # (block, parts, block_id, commit, power|None, lo, n)
+        per_block = []  # (block, parts, block_id, commit,
+        #                  for_block|None, lo, lanes)
         for i in range(len(blocks) - 1):
             block, commit = blocks[i], blocks[i + 1].last_commit
             parts, block_id = self._parts_and_id(block)
@@ -363,38 +383,47 @@ class BlockchainReactor(Reactor):
                 items, item_power = batch_valset.commit_verification_items(
                     chain_id, block_id, block.header.height, commit)
             except ValueError:
-                # not necessarily a bad peer: where membership changed
-                # since the collection set, the commit has another size
-                # and contributes no lanes; the block re-verifies against
-                # the live set in the apply loop. On a chain whose set
-                # moves every few blocks that is no rare case: see
-                # _sync_window and tm_sync_commits_total{how}
+                # not necessarily a bad peer: where the set has grown or
+                # shrunk since the collection set, the commit has
+                # another size and contributes no lanes; the block is
+                # verified whole against the live set in the apply loop
+                # (`sync.reverify`, tm_sync_commits_total{reverified})
                 per_block.append((block, parts, block_id, commit,
-                                  None, 0, 0))
+                                  None, 0, ()))
                 continue
-            per_block.append((block, parts, block_id, commit, item_power,
-                              lo, len(items)))
+            if block.header.validators_hash != vs_hash and \
+                    isinstance(items, SigColumns):
+                items = SigColumns(
+                    batch_valset.columns().pk[
+                        batch_valset.rows_by_address(commit)],
+                    items.sigs, items.msgs, items.idx)
+            per_block.append((block, parts, block_id, commit,
+                              item_power.for_block, lo, items))
             lo += len(items)
             batches.append(items)
-        return (per_block, SigColumns.concat(batches), batch_valset.hash(),
-                part_size)
+        return per_block, SigColumns.concat(batches), vs_hash, part_size
 
     def _apply_window(self, per_block, ok, batch_valset_hash,
                       part_size) -> int:
         """Store + apply one verified window in order; returns how many
         blocks were applied (< len(per_block) when a bad block stopped
-        the window)."""
+        the window). A block's pooled verdicts are judged under the set
+        in force NOW, lane by lane, by the key each lane was verified
+        under (ValidatorSet.check_commit_lanes): exactly verify_commit
+        under the live set, whatever set the window was collected
+        with."""
         chain_id = self.state.chain_id
         verifier = self._verifier()
         applied = 0
-        for block, parts, block_id, commit, item_power, lo, n in per_block:
+        for block, parts, block_id, commit, for_block, lo, lanes \
+                in per_block:
             if block.header.height != self.block_store.height() + 1:
                 # the window no longer lines up with the store (a
                 # predecessor window was cut short): discard the rest
                 return applied
             ps_now = (self.state.consensus_params
                       .block_gossip.block_part_size_bytes)
-            rebuilt = False
+            pooled = for_block is not None
             if ps_now != part_size:
                 # consensus params changed inside the pipeline window:
                 # the pre-built part set used the stale size — rebuild,
@@ -402,18 +431,18 @@ class BlockchainReactor(Reactor):
                 # for-this-block flags were computed against the old
                 # block_id and would zero out the counted power)
                 parts, block_id = self._parts_and_id(block)
-                rebuilt = True
+                pooled = False
             vs_now = self.state.validators
-            batched = not rebuilt and item_power is not None and \
-                vs_now.hash() == batch_valset_hash
+            n = again = len(lanes)
             try:
-                if batched:
-                    vs_now.check_commit_results(ok[lo:lo + n], item_power)
+                if pooled:
+                    again = vs_now.check_commit_lanes(
+                        commit, lanes, ok[lo:lo + n], for_block, verifier)
                 else:
-                    # valset changed since collection (or collect
-                    # failed): verify against the set that actually
-                    # signed, alone and synchronously; the lanes the
-                    # device verified for this block are thrown away
+                    # the window has no lanes for this block (the
+                    # commit is not of the collection set's size, or
+                    # its block id was rebuilt): one whole verify
+                    # against the live set, alone and synchronously
                     with trace.span("sync.reverify",
                                     req=block.header.height):
                         vs_now.verify_commit(chain_id, block_id,
@@ -434,8 +463,13 @@ class BlockchainReactor(Reactor):
                 self.state, block_id, block, trust_last_commit=True)
             self.pool.pop_request()
             applied += 1
-            _m_commits.labels("batched" if batched else "reverified").inc()
-            _m_lanes.labels("used" if batched else "discarded").inc(n)
+            _m_commits.labels("batched" if pooled else "reverified").inc()
+            if n > again:
+                _m_lanes.labels("used").inc(n - again)
+            if again:
+                _m_lanes.labels("discarded").inc(again)
+            if pooled and vs_now.hash() != batch_valset_hash:
+                _m_live_judged.inc()
             if self.after_apply is not None:
                 # recovery plane: interval snapshots + pruning fire on
                 # the sync path too (the app sits at exactly this
@@ -451,21 +485,21 @@ class BlockchainReactor(Reactor):
         instead of serializing (VERDICT r2: fast-sync was host-bound).
 
         A window held in flight covers blocks [height+applied ...]; its
-        collection valset is the one BEFORE the pending window applies.
-        If an apply changes the valset, the stale batch results are
-        discarded per block by the hash check in _apply_window and those
-        blocks re-verify against the live set, one synchronous
-        verify_commit each (`sync.reverify`). That is cheap only where
-        the set is constant. The first change after the collection point
-        discards every later block of the window and, through the
-        pipelining, of the window after it: on a chain whose set's hash
-        moves every few blocks (a delegation moves a power) nearly every
-        block is verified twice, once on the device for nothing and once
-        here, and under `auto_threshold` signatures that second time is
-        scalar on the host. tm_sync_commits_total{how} and
-        tm_sync_lanes_total{how} count both; pooling across set changes
-        is ROADMAP.md Queue 1's "fast-sync pools nothing once the set
-        moves". Returns True on progress.
+        collection valset is the one BEFORE the pending window applies,
+        256 to 512 blocks stale. That costs nothing where the set is
+        constant, and little where it moves: a lane's verdict says that
+        a signature is valid over its sign-bytes under ONE key, and
+        neither depends on the validator set, so _apply_window keeps
+        the verdict of every lane whose key the live set holds at the
+        vote's slot, tallies with the live stake, and verifies again,
+        scalar on the host and in one call a block, only the lanes
+        under another key: the joiners the collection set had never
+        seen. A change of stake discards nothing. Only a commit of
+        another size than the collection set's (a set that grows or
+        shrinks) has no lanes and is verified whole at apply, one
+        synchronous verify_commit (`sync.reverify`). tm_sync_commits_
+        total{how}, tm_sync_lanes_total{how} and tm_sync_live_judged_
+        total count all of it. Returns True on progress.
         """
         pending = self._pending_window
         skip = 0 if pending is None else max(0, len(pending[0]))

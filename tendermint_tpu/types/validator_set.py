@@ -358,6 +358,67 @@ class ValidatorSet:
             raise ValueError(
                 f"insufficient voting power: {power_for_block}/{total}")
 
+    def rows_by_address(self, commit) -> list:
+        """For each vote `commit` holds, in order, the slot at which
+        THIS set has the vote's `validator_address`, and the vote's own
+        slot where it knows no such address: the rows of `columns().pk`
+        under which a set that is not the commit's own has the best
+        chance of verifying each vote under the key its own set holds
+        for it. The address is the vote's claim and only chooses the
+        key that is tried: check_commit_lanes believes a verdict for the
+        key it was computed under and for no other."""
+        known = self._index.get
+        return [known(pc.validator_address, i)
+                for i, pc in enumerate(commit.precommits) if pc is not None]
+
+    def check_commit_lanes(self, commit, lanes, ok, for_block,
+                           verifier) -> int:
+        """Judge phase of verify_commit for a commit whose lanes were
+        paired with their keys by ANOTHER set (fast-sync collects a
+        window under the set it holds then; this set is the one in
+        force when the block is applied). `lanes`: the commit's batch
+        as it was verified, one (key, sign-bytes, signature) per vote
+        that is there, a SigColumns or a list; `ok`: their verdicts;
+        `for_block`: the flags of that collection's CommitPower, which
+        depend on the block id alone.
+
+        A verdict stands for its triple: a lane whose key is the key
+        this set holds at the vote's slot keeps its verdict, every other
+        lane is verified now under that key, all of the commit's in one
+        call on `verifier`. Then every signature valid and +2/3 of THIS
+        set's stake on the block: accepts and refuses exactly as
+        verify_commit under this set, with its messages (the structural
+        checks, which no set but by its size decides, were the
+        collection's). Returns how many lanes were verified again. A
+        lane without a verdict (`ok` shorter than `lanes`) gets none
+        here either, and counts as check_commit_results counts it."""
+        pcs, vals = commit.precommits, self.validators
+        if len(vals) != len(pcs):
+            raise ValueError(
+                f"commit size {len(pcs)} != valset size {len(vals)}")
+        cols = self.columns()
+        whole = len(lanes) == len(pcs)
+        slots = range(len(pcs)) if whole else \
+            [i for i, pc in enumerate(pcs) if pc is not None]
+        rows = slice(None) if whole else slots
+        if cols.pk is not None and isinstance(lanes, SigColumns):
+            stale = np.flatnonzero(
+                (lanes.pk != cols.pk[rows]).any(axis=1)).tolist()
+        else:
+            stale = [i for i, (s, lane) in enumerate(zip(slots, lanes))
+                     if vals[s].pubkey != lane[0]]
+        stale = [i for i in stale if i < len(ok)]
+        if stale:
+            again = verifier.verify(
+                [(vals[slots[i]].pubkey,) + lanes[i][1:] for i in stale])
+            ok = np.array(ok, np.bool_)
+            ok[stale] = again
+        powers = cols.powers[rows]
+        tally = cols.total if whole and for_block.all() \
+            else sum(powers[for_block].tolist())
+        self.check_commit_results(ok, CommitPower(powers, for_block, tally))
+        return len(stale)
+
     def endorsement(self, signing: "ValidatorSet", chain_id: str,
                     block_id, commit):
         """This (trusted) set's side of a change of set between adjacent

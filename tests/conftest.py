@@ -59,37 +59,57 @@ def pytest_configure(config):
         "markers", "slow: long-running schedule, excluded from tier-1")
 
 
-# Two tests of tests/benchrec/ pin where BENCHMARK.json's append-only
-# lists ended the day they were written, and only a `benchmark` PR may
-# edit a file under tests/benchrec/, so each raises AssertionError since
-# a later PR appended what its issue asked for. Strict, so neither can
-# go unnoticed: the day such a test finds its entries by name it
-# passes, this marker fails the run, and its line goes. Every assertion
-# of a marked test is made again, by name and open to later entries, in
-# the test named beside it.
-_PINS_THE_END_OF_A_LIST = {
+# Tests of tests/benchrec/ that a later PR outdated, and only a
+# `benchmark` PR may edit a file under tests/benchrec/. Two pin where
+# BENCHMARK.json's append-only lists ended the day they were written, so
+# each raises AssertionError since a later PR appended what its issue
+# asked for. Two hold the join cell to PR 42's window engine, which
+# threw a window's verdicts away once the set's hash moved; since PR 43
+# a verdict is judged by its key under the set in force. Strict, so
+# none can go unnoticed: the day such a test is repaired it passes,
+# this marker fails the run, and its line goes. Every assertion of a
+# marked test that still holds is made again in the test named beside
+# it. suffix of the node id -> (why, what it raises now).
+_OUTDATED_BY_A_LATER_PR = {
     # per_layer[-1] is PR 25's entry; entries follow it since PR 26
     # (tests/benchrec/test_benchrec_verify_commit.py::
     # test_the_entries_before_this_cell_are_as_they_were)
     "test_benchrec_predecomp_reuse.py::"
     "test_the_entry_is_appended_for_the_lite_cell_alone":
-        "looks at per_layer[-1]; entries follow it now",
+        ("looks at per_layer[-1]; entries follow it now", AssertionError),
     # the per-layer metrics of commit_10kv.verify_commit are exactly PR
     # 26's; PR 27's `columns_share` lists the cell too
     # (tests/test_columns_metrics.py::
     # test_the_single_commit_cell_keeps_its_metrics_and_gains_one)
     "test_benchrec_verify_commit.py::"
     "test_the_cell_and_its_metrics_are_declared":
-        "holds the cell's per-layer metrics to PR 26's set",
+        ("holds the cell's per-layer metrics to PR 26's set",
+         AssertionError),
+    # wants 60-100% of the blocks verified whole a second time and the
+    # three `commit.*` legs above 0; nothing is re-verified whole now
+    # (tests/test_join_metrics.py::
+    # test_the_traced_rehearsal_reports_every_metric_and_the_live_judge)
+    "test_benchrec_join.py::"
+    "test_the_traced_rehearsal_reports_every_new_metric":
+        ("holds the join cell to a second verify of every block",
+         AssertionError),
+    # wants a node whose window names any set to fail its warm pass:
+    # the hash guards nothing now, the keys do (tests/test_live_judge.py::
+    # test_a_judge_that_takes_a_lanes_key_on_trust_does_not_get_through
+    # and ::test_the_windows_hash_guards_nothing)
+    "test_benchrec_join.py::"
+    "test_a_node_that_keeps_stale_verdicts_does_not_get_through_a_join":
+        ("swaps the window's set hash, which no verdict hangs on now",
+         pytest.fail.Exception),
 }
 
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        for suffix, reason in _PINS_THE_END_OF_A_LIST.items():
+        for suffix, (reason, raises) in _OUTDATED_BY_A_LATER_PR.items():
             if item.nodeid.endswith(suffix):
                 item.add_marker(pytest.mark.xfail(
-                    reason=reason, raises=AssertionError, strict=True))
+                    reason=reason, raises=raises, strict=True))
 
 
 @pytest.fixture(autouse=True)
@@ -143,13 +163,20 @@ def _no_leaked_tm_threads():
     # a longer-scoped fixture (module-scoped node) legitimately keeps
     # respawning its threads (each ticker schedule is a fresh Timer
     # thread) — a name that was already live before the test is its
-    before_names = {t.name for t in threading.enumerate()}
+    # (a ThreadPoolExecutor spawns its workers lazily as <prefix>_<n>:
+    # under load a module-scoped server's pool grows a worker inside a
+    # later test, seen once in `tm-rpc-worker_3`; a pool that was live
+    # before the test is its fixture's too)
+    def pool_of(name):
+        head, _, n = name.rpartition("_")
+        return head if n.isdigit() else name
+    before_names = {pool_of(t.name) for t in threading.enumerate()}
 
     def leaked():
         return [t.name for t in threading.enumerate()
                 if t.ident not in before and t.is_alive()
                 and t.name.startswith("tm-")
-                and t.name not in before_names
+                and pool_of(t.name) not in before_names
                 and not t.name.startswith("tm-verify-fetch")
                 and not t.name.startswith("tm-verify-coalesce")
                 and not t.name.startswith("tm-queue-watch")

@@ -15,7 +15,7 @@ from benchmark.chain import ChainBuilder, forge_precommit
 from benchmark.drivers.sync import PEER_ID, drive, fresh_reactor
 from benchmark.drivers.sync_join import synced
 from benchmark.joinchain import (MEMBERSHIP, STAKE, JoinChain,
-                                 departed_signs_for_joiner)
+                                 departed_signs_for_joiner, val_tx)
 from benchmark.spans import SpanLog
 from tendermint_tpu.models.verifier import default_verifier
 
@@ -222,7 +222,7 @@ def recorder():
                 for fam, hows in ((reactor._m_commits,
                                    ("batched", "reverified")),
                                   (reactor._m_lanes, ("used", "discarded")))
-                for how in hows]
+                for how in hows] + [reactor._m_live_judged._implicit]
     held = [c.value for c in children]
     for c in children:
         c.value = 0.0
@@ -240,6 +240,10 @@ def counts(telemetry):
                                      ("sync_lanes_total", "discarded")))
 
 
+def live_judged(telemetry):
+    return int(telemetry.value("sync_live_judged_total") or 0)
+
+
 def events(telemetry, name):
     return [e for e in telemetry.TRACER.events() if e["name"] == name]
 
@@ -255,32 +259,161 @@ def test_a_constant_set_is_batched_and_nothing_is_reverified(recorder):
         reactor.stop()
     assert reactor.state.last_block_height == 12
     assert counts(recorder) == (12, 0, 60, 0)
+    assert live_judged(recorder) == 0
     assert events(recorder, "sync.reverify") == []
     updates = events(recorder, "apply.update")
     assert [e["req"] for e in updates] == list(range(1, 13))
     assert {e["args"]["changed"] for e in updates} == {0}
 
 
+@pytest.fixture
+def collected_under(monkeypatch):
+    """{height: the set its window was collected under}, filled as a
+    sync collects."""
+    from tendermint_tpu.blockchain.reactor import BlockchainReactor
+    under, collect = {}, BlockchainReactor._collect_window
+
+    def recording(self, skip):
+        out = collect(self, skip)
+        for entry in (out[0] if out else ()):
+            under[entry[0].header.height] = self.state.validators
+        return out
+    monkeypatch.setattr(BlockchainReactor, "_collect_window", recording)
+    return under
+
+
 @pytest.mark.parametrize("window", [4, 8])
-def test_blocks_above_a_change_are_reverified_and_counted(recorder, window):
-    chain = PlacedChain(15, {5: STAKE, 12: MEMBERSHIP})
-    reactor, error = sync(chain, window)
-    assert error is None and reactor.state.last_block_height == N_BLOCKS
-    batched, again, used, lost = counts(recorder)
-    assert batched + again == N_BLOCKS
-    assert used == batched * N_VALS and lost == again * N_VALS
-    # blocks 1..5 are judged under the genesis set, which signed them;
-    # the first window above the change was collected before it applied
-    assert batched >= 5 and again >= window - 1
-    heights = [e["req"] for e in events(recorder, "sync.reverify")]
-    assert len(heights) == again and heights == sorted(set(heights))
-    assert min(heights) == 6
-    # every re-verify is one synchronous verify_commit
-    assert len(events(recorder, "commit.collect")) == again
+def test_blocks_above_a_change_are_reverified_and_counted(
+        recorder, collected_under, window):
+    """Nothing above a change is verified whole again: a change of stake
+    discards no lane, a join the joiner's alone, and the joiner's
+    signature is what is verified again."""
+    verifier = default_verifier()
+
+    def synced_and_counted(placed):
+        collected_under.clear()
+        recorder.TRACER.clear()
+        chain = PlacedChain(15, placed)
+        sigs, live = verifier.stats["sigs"], live_judged(recorder)
+        before = counts(recorder)
+        reactor, error = sync(chain, window)
+        assert error is None and reactor.state.last_block_height == N_BLOCKS
+        assert events(recorder, "sync.reverify") == []
+        assert events(recorder, "commit.collect") == []
+        sigs = verifier.stats["sigs"] - sigs
+        _state, sets, _apps = serial(chain)
+        stale = [h for h in range(1, N_BLOCKS + 1)
+                 if collected_under[h].hash() != sets[h - 1]]
+        return (chain, reactor, stale,
+                tuple(a - b for a, b in zip(counts(recorder), before)),
+                sigs, live_judged(recorder) - live)
+
+    # above a change of stake: every window up to two windows above it
+    # was collected under the set before, and every lane's key stands
+    chain, _reactor, stale, got, sigs, live = synced_and_counted({5: STAKE})
+    assert got == (N_BLOCKS, 0, N_BLOCKS * N_VALS, 0)
+    assert sigs == N_BLOCKS * N_VALS
+    assert live == len(stale) >= window - 1 and min(stale) == 6
+
+    # above a join as well: the collection set has never seen the joiner
+    chain, reactor, stale, got, sigs, live = synced_and_counted(
+        {5: STAKE, 12: MEMBERSHIP})
+    (_departed, joiner), = chain.joined_at.values()
+    unseen = [h for h in stale if h >= 13 and joiner not in
+              {v.pubkey for v in collected_under[h].validators}]
+    assert len(unseen) >= min(window - 1, N_BLOCKS - 12)
+    assert unseen[0] == 13
+    batched, again, used, lost = got
+    assert (batched, again) == (N_BLOCKS, 0)
+    assert lost == len(unseen) and used == N_BLOCKS * N_VALS - lost
+    assert sigs == N_BLOCKS * N_VALS + lost
+    assert live == len(stale) > len(unseen) and min(stale) == 6
     changed = {e["req"]: e["args"]["changed"]
                for e in events(recorder, "apply.update")}
     assert changed == {h: {5: 1, 12: 2}.get(h, 0)
                        for h in range(1, N_BLOCKS + 1)}
+
+
+def test_a_commit_of_another_size_than_the_windows_set_is_reverified(
+        recorder):
+    """A set that grows: the commits above the join have one vote more
+    than the set their window was collected under, bring no lanes, and
+    are verified whole under the live set (`sync.reverify`)."""
+    n_blocks, grows_at = 14, 4
+
+    class GrowingChain(PlacedChain):
+        def _val_txs(self, h):
+            if self.change_at.get(h) != MEMBERSHIP:
+                return super()._val_txs(h)
+            new = self._standby.pop(0)
+            # nobody leaves; the member named first is the one whose
+            # signature departed_signs_for_joiner puts in the joiner's vote
+            self.joined_at[h] = (
+                self._state.validators.validators[0].pubkey, new)
+            return [val_tx(new, 1000)]
+
+    chain = GrowingChain(17, {grows_at: MEMBERSHIP, 9: STAKE},
+                         n_blocks=n_blocks)
+    reactor, error = sync(chain, 4)
+    assert error is None and reactor.state.last_block_height == n_blocks
+    assert len(reactor.state.validators) == N_VALS + 1
+    batched, again, used, lost = counts(recorder)
+    assert batched + again == n_blocks and again >= 3
+    heights = [e["req"] for e in events(recorder, "sync.reverify")]
+    assert len(heights) == again and heights == sorted(set(heights))
+    assert heights[0] == grows_at + 1
+    # every one of them one synchronous verify_commit
+    assert len(events(recorder, "commit.collect")) == again
+    want, _sets, _apps = serial(chain)
+    assert reactor.state.to_obj() == want.to_obj()
+    # a block verified whole had no lanes in its window
+    assert lost == 0 and used == grows_at * N_VALS + (
+        n_blocks - grows_at - again) * (N_VALS + 1)
+
+    # and a forged signature there is refused at its height
+    at = heights[1]
+    wire = list(chain.wire)
+    wire[at] = forge_precommit(wire[at], 2)
+    reactor, error = sync(chain, 4, wire)
+    assert error is None and stopped_at(reactor) == (at - 1, True)
+    # as is the joiner's vote signed by a key of the set below
+    at, wire = departed_signs_for_joiner(chain, grows_at)
+    reactor, error = sync(chain, 4, wire)
+    assert error is None and stopped_at(reactor) == (at - 1, True)
+
+
+def copy_header_names_another_set(copy, height, reactor):
+    from tendermint_tpu.types.block import Block
+    header = Block.from_bytes(copy.wire[height - 1]).header
+    return header.validators_hash != reactor.state.validators.hash()
+
+
+@pytest.mark.parametrize("window", [4, 8])
+def test_the_val_transaction_cut_at_the_first_change_is_refused_one_up(
+        recorder, window):
+    """Live set = collection set, header names another: the commit
+    passes the live judge (every signature good, the node's stake on
+    the block) and validate_block refuses the header; nobody is
+    punished."""
+    from tendermint_tpu.state.validation import BlockValidationError
+    cut = min(CHURN)
+    copy = PlacedChain(14, CHURN, cut_val_at=cut)
+    reactor, error = sync(copy, window, copy.wire)
+    assert isinstance(error, BlockValidationError)
+    assert "validators_hash" in str(error)
+    assert stopped_at(reactor) == (cut, False)
+    # the node still holds the genesis set, under which every window was
+    # collected: the commit above the cut came through the pooled lanes
+    # (paired by address, since its header names another set) and not
+    # through a whole verify
+    assert reactor.state.last_height_validators_changed == 1
+    assert copy_header_names_another_set(copy, cut + 1, reactor)
+    assert counts(recorder) == (cut, 0, cut * N_VALS, 0)
+    assert live_judged(recorder) == 0
+    assert events(recorder, "sync.reverify") == []
+    ref = joinref.replay(copy.genesis_wire, copy.wire)
+    assert (ref.height, ref.refused_at, ref.kind) == (
+        cut, cut + 1, joinref.VALIDATORS_HASH)
 
 
 def test_with_telemetry_off_nothing_is_recorded():

@@ -184,7 +184,7 @@ def test_stall_detector_fires_once_per_episode_and_rearms():
 
 def _mk_dump(node, spans, rtt=None):
     return {"node": node, "pid": 1, "wall_ns": 0, "enabled": True,
-            "capacity": 65536, "events": len(spans),
+            "capacity": 262144, "events": len(spans),
             "rtt_s": rtt or {}, "spans": spans}
 
 
