@@ -74,6 +74,7 @@ INSTRUMENTED_MODULES = [
     "tendermint_tpu.ops.ed25519",        # tm_verifier_h2d_bytes_total
     "tendermint_tpu.types.block",        # tm_verifier_commit_block_ids_total,
                                          # tm_wire_block_decodes_total
+    "tendermint_tpu.types.validator_set",  # tm_verifier_vote_walks_total
     "tendermint_tpu.types.vote_set",     # tm_consensus_votes_total
     "tendermint_tpu.p2p.fuzz",           # tm_p2p_link_delay_seconds
     "tendermint_tpu.lite.certifier",     # tm_lite_windows_total,

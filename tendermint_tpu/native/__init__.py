@@ -269,6 +269,28 @@ def prep_columns(pk, sigs, msgs, idx, threads: int = 1):
         len(sigs))
 
 
+def walk_votes(pcs, height: int, round_: int, precommit: int, template):
+    """The vote walk of ValidatorSet.commit_verification_items in one
+    call: `pcs` a commit's precommits, `template(block_id)` ->
+    (prefix str, suffix str, for_block) asked once per distinct block
+    id. -> what types/validator_set._walk_votes returns (sigs,
+    msgs, idx int32[n], for_block bool[n], absent, all_for; the two
+    arrays read-only), raising its ValueErrors, or None when unavailable
+    / for a commit that is not read as expected (pcs not a list, a field
+    that is no `int` within int64, a signature that is no `bytes`): the
+    Python loop then judges it."""
+    mod = _prep()
+    if mod is None:
+        return None
+    out = mod.walk_votes(pcs, height, round_, precommit, template)
+    if out is None:
+        return None
+    import numpy as np
+    sigs, msgs, idx, for_block, absent, all_for = out
+    return (sigs, msgs, np.frombuffer(idx, np.int32),
+            np.frombuffer(for_block, np.bool_), absent, all_for)
+
+
 def _pack(items: List[bytes]):
     data = b"".join(items)
     n = len(items)

@@ -518,6 +518,247 @@ static PyObject *merkle_root_items(PyObject *self, PyObject *arg) {
     return PyBytes_FromStringAndSize((const char *)out, 32);
 }
 
+namespace {
+
+// A reference that is dropped at the end of its scope.
+struct Ref {
+    PyObject *o;
+    explicit Ref(PyObject *p = nullptr) : o(p) {}
+    Ref(const Ref &) = delete;
+    Ref &operator=(const Ref &) = delete;
+    ~Ref() { Py_XDECREF(o); }
+    void take(PyObject *p) {    // steals p
+        Py_XDECREF(o);
+        o = p;
+    }
+    void share(PyObject *p) {   // p stays its owner's too
+        Py_INCREF(p);
+        take(p);
+    }
+};
+
+// The attribute names of the vote walk, interned once at import.
+PyObject *s_type, *s_height, *s_round, *s_block_id, *s_timestamp_ns,
+    *s_signature, *s_hash, *s_parts, *s_total;
+
+// What became of a read: the value, an error that is set, or something
+// the walk does not read as expected, on which it returns None and the
+// Python loop judges the commit.
+enum Read { GOT, RAISED, DECLINED };
+
+// o.<name> by getattr. A missing attribute declines: which of several
+// faults of a vote is reported is the loop's to say. (Reading a plain
+// Vote's fields from its __dict__ instead was tried, PR 44: on 3.12
+// asking for the dict builds it, which costs more than it saves.)
+inline Read field(PyObject *o, PyObject *name, Ref &out) {
+    out.take(PyObject_GetAttr(o, name));
+    if (out.o != nullptr) return GOT;
+    if (!PyErr_ExceptionMatches(PyExc_AttributeError)) return RAISED;
+    PyErr_Clear();
+    return DECLINED;
+}
+
+// An `int` itself (no subclass, so no bool) that an int64 holds.
+inline bool as_i64(PyObject *o, long long *v) {
+    if (!PyLong_CheckExact(o)) return false;
+    int overflow = 0;
+    *v = PyLong_AsLongLongAndOverflow(o, &overflow);
+    return overflow == 0 && !(*v == -1 && PyErr_Occurred());
+}
+
+inline Read int_field(PyObject *o, PyObject *name, long long *v) {
+    Ref r;
+    Read got = field(o, name, r);
+    if (got != GOT) return got;
+    if (as_i64(r.o, v)) return GOT;
+    return PyErr_Occurred() ? RAISED : DECLINED;
+}
+
+inline bool same_bytes(PyObject *a, PyObject *b) {
+    return a == b ||
+           (PyBytes_GET_SIZE(a) == PyBytes_GET_SIZE(b) &&
+            std::memcmp(PyBytes_AS_STRING(a), PyBytes_AS_STRING(b),
+                        (size_t)PyBytes_GET_SIZE(a)) == 0);
+}
+
+// prefix + decimal(ts) + suffix as a new bytes object.
+PyObject *splice(const char *pre, Py_ssize_t pre_n, long long ts,
+                 const char *suf, Py_ssize_t suf_n) {
+    char digits[24];
+    char *end = digits + sizeof digits, *p = end;
+    unsigned long long mag =
+        ts < 0 ? 0ULL - (unsigned long long)ts : (unsigned long long)ts;
+    do {
+        *--p = char('0' + mag % 10);
+        mag /= 10;
+    } while (mag != 0);
+    if (ts < 0) *--p = '-';
+    Py_ssize_t dig_n = end - p;
+    PyObject *out =
+        PyBytes_FromStringAndSize(nullptr, pre_n + dig_n + suf_n);
+    if (out == nullptr) return nullptr;
+    char *w = PyBytes_AS_STRING(out);
+    std::memcpy(w, pre, (size_t)pre_n);
+    std::memcpy(w + pre_n, p, (size_t)dig_n);
+    std::memcpy(w + pre_n + dig_n, suf, (size_t)suf_n);
+    return out;
+}
+
+}  // namespace
+
+// walk_votes(pcs, height, round, precommit, template) — THE vote
+// walk of ValidatorSet.commit_verification_items (types/
+// validator_set.py, whose `_walk_votes` is the specification: the same
+// checks in the same order with the same ValueError texts, the same
+// runs). `pcs`: a commit's precommits, None where a vote is absent.
+// `template(block_id)` -> (prefix str, suffix str, for_block), asked
+// once per distinct block id (identity first, then hash / parts.total /
+// parts.hash): the sign-bytes layout stays Python's, this only splices
+// each distinct timestamp's decimal between the two.
+// -> (sigs: the votes' own signature objects, msgs: a sign-bytes per
+// run of votes that signed the same, idx: int32[n] lane -> msgs as
+// bytes, for_block: bool[n] as bytes, absent: the empty slots,
+// all_for: every lane's vote is for the block), or None, never a guess,
+// for whatever is not read as expected: pcs not a list, a field that is
+// missing, an int field that is no `int` or fits no int64, a hash or
+// signature that is no `bytes`, a template of another shape.
+static PyObject *walk_votes(PyObject *, PyObject *args) {
+    PyObject *pcs, *height_o, *round_o, *precommit_o, *tmpl;
+    if (!PyArg_ParseTuple(args, "OOOOO", &pcs, &height_o, &round_o,
+                          &precommit_o, &tmpl))
+        return nullptr;
+    long long height, round_, precommit;
+    if (!PyList_CheckExact(pcs) || !as_i64(height_o, &height) ||
+        !as_i64(round_o, &round_) || !as_i64(precommit_o, &precommit)) {
+        if (PyErr_Occurred()) return nullptr;
+        Py_RETURN_NONE;
+    }
+    Ref sigs(PyList_New(0)), msgs(PyList_New(0)), absent(PyList_New(0));
+    if (sigs.o == nullptr || msgs.o == nullptr || absent.o == nullptr)
+        return nullptr;
+    std::vector<int32_t> idx;
+    std::vector<uint8_t> flags;
+    idx.reserve((size_t)PyList_GET_SIZE(pcs));
+    flags.reserve((size_t)PyList_GET_SIZE(pcs));
+
+    Ref known;                      // block id fields -> its template
+    Ref bid, bhash, phash, cur;     // the current block id, its template
+    long long ptotal = 0, ts = 0;
+    bool have_ts = false, for_block = false, all_for = true;
+    const char *pre = nullptr, *suf = nullptr;
+    Py_ssize_t pre_n = 0, suf_n = 0;
+    int32_t n_msgs = 0;
+    Read got;
+#define READ(call)                                   \
+    if ((got = (call)) != GOT) {                     \
+        if (got == RAISED) return nullptr;           \
+        Py_RETURN_NONE;                              \
+    }
+
+    // the size is read anew each turn: template() runs Python
+    for (Py_ssize_t i = 0; i < PyList_GET_SIZE(pcs); i++) {
+        Ref pc;
+        pc.share(PyList_GET_ITEM(pcs, i));
+        if (pc.o == Py_None) {
+            Ref at(PyLong_FromSsize_t(i));
+            if (at.o == nullptr || PyList_Append(absent.o, at.o) < 0)
+                return nullptr;
+            continue;
+        }
+        long long v, w;
+        READ(int_field(pc.o, s_type, &v));
+        if (v != precommit) {
+            PyErr_SetString(PyExc_ValueError,
+                            "commit contains non-precommit");
+            return nullptr;
+        }
+        READ(int_field(pc.o, s_height, &v));
+        READ(int_field(pc.o, s_round, &w));
+        if (v != height || w != round_) {
+            PyErr_SetString(PyExc_ValueError,
+                            "commit vote height/round mismatch");
+            return nullptr;
+        }
+        Ref b;
+        READ(field(pc.o, s_block_id, b));
+        if (b.o != bid.o) {
+            bid.share(b.o);
+            Ref parts, h, ph;
+            long long total;
+            READ(field(b.o, s_parts, parts));
+            READ(field(b.o, s_hash, h));
+            READ(int_field(parts.o, s_total, &total));
+            READ(field(parts.o, s_hash, ph));
+            if (!PyBytes_CheckExact(h.o) || !PyBytes_CheckExact(ph.o))
+                Py_RETURN_NONE;
+            if (bhash.o == nullptr || total != ptotal ||
+                !same_bytes(h.o, bhash.o) || !same_bytes(ph.o, phash.o)) {
+                bhash.share(h.o);
+                phash.share(ph.o);
+                ptotal = total;
+                if (known.o == nullptr) {
+                    known.take(PyDict_New());
+                    if (known.o == nullptr) return nullptr;
+                }
+                Ref total_o(PyLong_FromLongLong(total));
+                if (total_o.o == nullptr) return nullptr;
+                Ref key(PyTuple_Pack(3, h.o, total_o.o, ph.o));
+                if (key.o == nullptr) return nullptr;
+                PyObject *t = PyDict_GetItemWithError(known.o, key.o);
+                if (t != nullptr) {
+                    cur.share(t);
+                } else {
+                    if (PyErr_Occurred()) return nullptr;
+                    cur.take(PyObject_CallOneArg(tmpl, b.o));
+                    if (cur.o == nullptr) return nullptr;
+                    if (!PyTuple_CheckExact(cur.o) ||
+                        PyTuple_GET_SIZE(cur.o) != 3 ||
+                        !PyUnicode_CheckExact(PyTuple_GET_ITEM(cur.o, 0)) ||
+                        !PyUnicode_CheckExact(PyTuple_GET_ITEM(cur.o, 1)))
+                        Py_RETURN_NONE;
+                    if (PyDict_SetItem(known.o, key.o, cur.o) < 0)
+                        return nullptr;
+                }
+                // the str keeps its UTF-8 form for as long as it lives,
+                // and `cur` holds it
+                pre = PyUnicode_AsUTF8AndSize(PyTuple_GET_ITEM(cur.o, 0),
+                                              &pre_n);
+                suf = PyUnicode_AsUTF8AndSize(PyTuple_GET_ITEM(cur.o, 1),
+                                              &suf_n);
+                int truth = PyObject_IsTrue(PyTuple_GET_ITEM(cur.o, 2));
+                if (pre == nullptr || suf == nullptr || truth < 0)
+                    return nullptr;
+                for_block = truth != 0;
+                all_for = all_for && for_block;
+                have_ts = false;    // other sign-bytes too
+            }
+        }
+        READ(int_field(pc.o, s_timestamp_ns, &v));
+        if (!have_ts || v != ts) {
+            ts = v;
+            have_ts = true;
+            Ref msg(splice(pre, pre_n, ts, suf, suf_n));
+            if (msg.o == nullptr || PyList_Append(msgs.o, msg.o) < 0)
+                return nullptr;
+            n_msgs++;
+        }
+        Ref sig;
+        READ(field(pc.o, s_signature, sig));
+        if (!PyBytes_CheckExact(sig.o)) Py_RETURN_NONE;
+        if (PyList_Append(sigs.o, sig.o) < 0) return nullptr;
+        idx.push_back(n_msgs - 1);
+        flags.push_back(for_block ? 1 : 0);
+    }
+#undef READ
+    Ref idx_b(PyBytes_FromStringAndSize((const char *)idx.data(),
+                                        (Py_ssize_t)(4 * idx.size())));
+    Ref flags_b(PyBytes_FromStringAndSize((const char *)flags.data(),
+                                          (Py_ssize_t)flags.size()));
+    if (idx_b.o == nullptr || flags_b.o == nullptr) return nullptr;
+    return Py_BuildValue("(OOOOOO)", sigs.o, msgs.o, idx_b.o, flags_b.o,
+                         absent.o, all_for ? Py_True : Py_False);
+}
+
 static PyMethodDef prep_methods[] = {
     {"sign_phase1", sign_phase1, METH_VARARGS,
      "(prefixes n*32, msgs) -> r scalars n*32 (RFC 8032 nonces mod L)"},
@@ -532,6 +773,10 @@ static PyMethodDef prep_methods[] = {
     {"prep_columns", prep_columns, METH_VARARGS,
      "(pk n*32, sigs, msgs, idx int32[n], threads=1) -> what prep_items "
      "returns for the triples (pk[i], msgs[idx[i]], sigs[i])."},
+    {"walk_votes", walk_votes, METH_VARARGS,
+     "(pcs, height, round, precommit, template) -> (sigs, msgs, "
+     "idx int32 bytes, for_block bool bytes, absent, all_for), or None "
+     "where the Python loop must judge the commit."},
     {nullptr, nullptr, 0, nullptr},
 };
 
@@ -548,6 +793,16 @@ PyMODINIT_FUNC PyInit__tmprep(void) {
         ossl_update = (sha512_update_fn)dlsym(crypto, "SHA512_Update");
         if (ossl_init != nullptr && ossl_update != nullptr)
             ossl_final = (sha512_final_fn)dlsym(crypto, "SHA512_Final");
+    }
+    struct { PyObject **at; const char *name; } names[] = {
+        {&s_type, "type"}, {&s_height, "height"}, {&s_round, "round"},
+        {&s_block_id, "block_id"}, {&s_timestamp_ns, "timestamp_ns"},
+        {&s_signature, "signature"}, {&s_hash, "hash"},
+        {&s_parts, "parts"}, {&s_total, "total"},
+    };
+    for (auto &n : names) {
+        *n.at = PyUnicode_InternFromString(n.name);
+        if (*n.at == nullptr) return nullptr;
     }
     PyObject *m = PyModule_Create(&prep_moduledef);
     if (m != nullptr)

@@ -16,12 +16,22 @@ from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from tendermint_tpu import native, telemetry
 from tendermint_tpu.ops import merkle
 from tendermint_tpu.telemetry import trace
 from tendermint_tpu.types import encoding
 from tendermint_tpu.types.keys import PubKey, address_of
 from tendermint_tpu.types.sigcolumns import SigColumns
 from tendermint_tpu.types.vote import VoteType, sign_bytes_template
+
+# counted once per commit_verification_items call that walked a commit
+# to its end
+_m_vote_walks = telemetry.counter(
+    "verifier_vote_walks_total",
+    "Commits whose votes commit_verification_items walked: native "
+    "(native/prep.cpp walk_votes, one call a commit) or pure (the "
+    "Python loop: no extension, or a commit the native walk declined "
+    "to read)", ("how",))
 
 _address_memo = functools.lru_cache(maxsize=65536)(address_of)
 
@@ -102,6 +112,65 @@ def _lane_runs(starts: list, n: int) -> np.ndarray:
         return np.zeros(n, np.int32)
     return np.repeat(np.arange(len(starts), dtype=np.int32),
                      np.diff(starts + [n]))
+
+
+def _walk_votes(pcs, height: int, round_: int, precommit: int, template):
+    """THE vote walk of commit_verification_items, and the specification
+    of native.walk_votes, which takes its place where the extension is
+    loaded: every vote a precommit of this height and round, else the
+    ValueError; -> (sigs: the votes' signature objects, msgs: a
+    sign-bytes per run, idx: int32[n] lane -> msgs, for_block: bool[n],
+    absent: the slots without a vote, all_for: every lane is for the
+    block).
+
+    Within one commit the votes differ in timestamp and, for nil votes,
+    in block id: the sign-bytes are built once per run of votes that
+    signed the same, around the ONE layout definition (`template(b)` ->
+    vote.sign_bytes_template's prefix and suffix and whether `b` is the
+    block, asked once per distinct block id; pinned by
+    test_commit_items_sign_bytes_match). A commit's votes for one block
+    share one BlockID object (as built, and as Commit.from_obj decodes);
+    others compare fields."""
+    sigs, absent = [], []
+    msgs, msg_at = [], []       # a run's sign-bytes, its first lane
+    flags, flag_at = [], []     # for_block of a run of one block id
+    known = {}          # block id fields -> (prefix, suffix, for_block)
+    bid = ts = bhash = ptotal = phash = pre = suf = None
+    for pc in pcs:
+        if pc is None:
+            absent.append(len(sigs) + len(absent))
+            continue
+        if pc.type != precommit:
+            raise ValueError("commit contains non-precommit")
+        if pc.height != height or pc.round != round_:
+            raise ValueError("commit vote height/round mismatch")
+        b = pc.block_id
+        if b is not bid:
+            bid, parts = b, b.parts
+            if b.hash != bhash or parts.total != ptotal \
+                    or parts.hash != phash:
+                bhash, ptotal, phash = key = \
+                    b.hash, parts.total, parts.hash
+                t = known.get(key)
+                if t is None:
+                    t = known[key] = template(b)
+                pre, suf, for_block = t
+                flags.append(for_block)
+                flag_at.append(len(sigs))
+                ts = None       # other sign-bytes too
+        if pc.timestamp_ns != ts:
+            ts = pc.timestamp_ns
+            msgs.append((pre + str(ts) + suf).encode())
+            msg_at.append(len(sigs))
+        sigs.append(pc.signature)
+    n = len(sigs)
+    if len(flags) == 1:
+        for_block = np.empty(n, np.bool_)
+        for_block.fill(flags[0])
+    else:
+        for_block = np.array(flags, np.bool_)[_lane_runs(flag_at, n)]
+    return (sigs, msgs, _lane_runs(msg_at, n), for_block, absent,
+            all(flags))
 
 
 class ValidatorSet:
@@ -272,63 +341,31 @@ class ValidatorSet:
             raise ValueError("commit height mismatch")
         round_ = commit.round()
         precommit = VoteType.PRECOMMIT
-        # THE vote walk. Within one commit the votes differ in
-        # timestamp and, for nil votes, in block id: the sign-bytes are
-        # built once per run of votes that signed the same, around the
-        # ONE layout definition (vote.sign_bytes_template, once per
-        # distinct block id; pinned by test_commit_items_sign_bytes_
-        # match), and for_block is decided once per distinct block id.
-        # A commit's votes for one block share one BlockID object (as
-        # built, and as Commit.from_obj decodes); others compare fields.
-        sigs, absent = [], []
-        msgs, msg_at = [], []       # a run's sign-bytes, its first lane
-        flags, flag_at = [], []     # for_block of a run of one block id
-        known = {}          # block id fields -> (prefix, suffix, for_block)
-        bid = ts = bhash = ptotal = phash = pre = suf = None
-        for pc in pcs:
-            if pc is None:
-                absent.append(len(sigs) + len(absent))
-                continue
-            if pc.type != precommit:
-                raise ValueError("commit contains non-precommit")
-            if pc.height != height or pc.round != round_:
-                raise ValueError("commit vote height/round mismatch")
-            b = pc.block_id
-            if b is not bid:
-                bid, parts = b, b.parts
-                if b.hash != bhash or parts.total != ptotal \
-                        or parts.hash != phash:
-                    bhash, ptotal, phash = key = \
-                        b.hash, parts.total, parts.hash
-                    t = known.get(key)
-                    if t is None:
-                        t = known[key] = sign_bytes_template(
-                            chain_id, b, height, round_, precommit) \
-                            + (b == block_id,)
-                    pre, suf, for_block = t
-                    flags.append(for_block)
-                    flag_at.append(len(sigs))
-                    ts = None       # other sign-bytes too
-            if pc.timestamp_ns != ts:
-                ts = pc.timestamp_ns
-                msgs.append((pre + str(ts) + suf).encode())
-                msg_at.append(len(sigs))
-            sigs.append(pc.signature)
 
-        n = len(sigs)
+        def template(b):
+            return sign_bytes_template(chain_id, b, height, round_,
+                                       precommit) + (b == block_id,)
+
+        # one native call a commit where the extension is loaded; the
+        # Python loop where it is not, and for whatever commit the
+        # native walk declines to read
+        how = "native"
+        walked = native.walk_votes(pcs, height, round_, precommit,
+                                   template)
+        if walked is None:
+            how = "pure"
+            walked = _walk_votes(pcs, height, round_, precommit, template)
+        if telemetry.enabled():
+            _m_vote_walks.labels(how).inc()
+        sigs, msgs, idx, for_block, absent, all_for = walked
+
         cols = self.columns()
-        idx = _lane_runs(msg_at, n)
-        if len(flags) == 1:
-            for_block = np.empty(n, np.bool_)
-            for_block.fill(flags[0])
-        else:
-            for_block = np.array(flags, np.bool_)[_lane_runs(flag_at, n)]
         if absent:
             rows = np.delete(np.arange(len(pcs)), absent)
             powers = cols.powers[rows]
         else:
             rows, powers = slice(None), cols.powers
-        if not absent and flags == [True]:
+        if not absent and all_for:
             tally = cols.total
         else:
             tally = sum(powers[for_block].tolist())
