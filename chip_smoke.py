@@ -590,8 +590,7 @@ def leg_d(seed: int) -> dict:
             "stats_delta": stats,
             "signatures_total": stats["sigs"],
             "signatures_on_device": stats["jax_sigs"],
-            "signatures_on_host": stats["sigs"] - stats["jax_sigs"],
-            "coalesced_calls": stats["coalesced_calls"]},
+            "signatures_on_host": stats["sigs"] - stats["jax_sigs"]},
         "merkle_plane_check": merkle_check,
         "smoke_seconds": {"boot_to_first_block": round(boot_s, 2),
                           "writes_and_reads": round(writes_s, 2)},

@@ -37,7 +37,6 @@ KNOWN_SUBSYSTEMS = {
 
 INSTRUMENTED_MODULES = [
     "tendermint_tpu.models.verifier",
-    "tendermint_tpu.models.coalescer",
     "tendermint_tpu.ops.merkle",
     "tendermint_tpu.parallel.mesh",      # tm_mesh_* sharded dispatches
     "tendermint_tpu.consensus.state",

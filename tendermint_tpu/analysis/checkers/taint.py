@@ -162,36 +162,16 @@ BLESSED: Tuple[Seam, ...] = (
          "native and pure-python codecs are differentially tested "
          "byte-for-byte"),
     Seam("knob", "TM_TPU_VERIFIER",
-         "tests/test_coalescer.py::test_fast_verify_matches_oracle",
+         "tests/test_verifier_calls.py::test_fast_verify_matches_oracle",
          "verifier backend selection; every fast path is proven "
          "bit-equal against the host oracle"),
     Seam("knob", "TM_TPU_AUTO_THRESHOLD",
-         "tests/test_coalescer.py::test_fast_verify_matches_oracle",
+         "tests/test_verifier_calls.py::test_fast_verify_matches_oracle",
          "scalar/batch crossover point only picks between "
          "oracle-equal implementations"),
-    Seam("knob", "TM_TPU_COALESCE",
-         "tests/test_coalescer.py::test_fast_verify_matches_oracle",
-         "coalesced dispatch returns the same verdicts as per-call "
-         "verification (oracle-checked)"),
-    Seam("knob", "TM_TPU_COALESCE_WAIT_MS",
-         "tests/test_coalescer.py::test_fast_verify_matches_oracle",
-         "batching window changes latency/batch size, never verdicts"),
-    Seam("knob", "TM_TPU_COALESCE_MAX_BATCH",
-         "tests/test_coalescer.py::test_fast_verify_matches_oracle",
-         "batch-size cap changes dispatch shape, never verdicts"),
-    Seam("knob", "TM_TPU_FETCH_WORKERS",
-         "tests/test_coalescer.py::"
-         "test_threaded_single_vote_callers_mixed_keys",
-         "pubkey-prefetch pool width; concurrent mixed-key callers "
-         "get identical verdicts at any width"),
     Seam("knob", "TM_TPU_MESH",
          "tests/test_mesh.py::test_root_host_mesh_dispatch_bit_equality",
          "mesh dispatch is bit-equal to the host path"),
-    Seam("knob", "TM_TPU_NO_PALLAS",
-         "tests/test_pallas_kernel.py::"
-         "test_sign_kernel_interpret_matches_reference",
-         "pallas kernels are differentially tested against the "
-         "reference implementation"),
     Seam("knob", "TM_TPU_DIVERGENCE",
          "tests/test_divergence.py::test_dual_hash_seed_replay_bit_identical",
          "the divergence recorder observes the transition, never "

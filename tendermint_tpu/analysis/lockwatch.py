@@ -391,7 +391,6 @@ class _GuardedAttr:
 #: modules whose guarded_by annotations get runtime enforcement under
 #: watch_annotated() — the concurrency-heavy planes
 WATCH_MODULES = (
-    "tendermint_tpu.models.coalescer",
     "tendermint_tpu.models.verifier",
     "tendermint_tpu.p2p.conn.mconn",
     "tendermint_tpu.p2p.conn.secret",

@@ -780,16 +780,6 @@ def run_chaos(spec: Optional[dict] = None, seed: int = 42,
     trace_prev = causal._configured
     causal.configure("on")
     causal.clear()
-    # the runner is SINGLE-THREADED by design (one seed, one
-    # trajectory), so the dispatch coalescer can never merge anything
-    # here — but every per-vote verify would still pay its cross-thread
-    # handoff + linger (measured ~2x step cost at 64 validators).
-    # Verdicts are identical either way (off-hatch is byte-parity,
-    # test-pinned in test_coalescer); restored after the run.
-    from tendermint_tpu.models.verifier import default_verifier
-    _shared_verifier = default_verifier()
-    coalesce_prev = _shared_verifier.coalesce
-    _shared_verifier.coalesce = "off"
     net = ChaosNet(workdir, spec, seed, n=n, lite=lite)
     try:
         net.start()
@@ -818,7 +808,6 @@ def run_chaos(spec: Optional[dict] = None, seed: int = 42,
         return report
     finally:
         net.stop()
-        _shared_verifier.coalesce = coalesce_prev
         causal.configure(trace_prev)
         if own_dir:
             shutil.rmtree(workdir, ignore_errors=True)

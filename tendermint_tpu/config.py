@@ -29,14 +29,6 @@ class BaseConfig:
     # through the same mesh — see docs/knobs.md.
     verifier_backend: str = "auto"
     verifier_mesh: str = "auto"
-    # cross-call dispatch coalescing (models/coalescer.py): merge
-    # concurrent sub-threshold verify calls into one device batch.
-    # auto|on|off; wait_ms is the max linger per merged batch (the
-    # adaptive window never exceeds it); max_batch 0 = BATCH_CHUNK.
-    # Env TM_TPU_COALESCE / _WAIT_MS / _MAX_BATCH win over these.
-    verifier_coalesce: str = "auto"
-    verifier_coalesce_wait_ms: float = 2.0
-    verifier_coalesce_max_batch: int = 0
     # telemetry plane (telemetry/): metrics + tracing on by default; the
     # namespace prefixes every exposed metric (tm_verifier_batch_size).
     # Env TM_TPU_TELEMETRY=off overrides `telemetry` unconditionally.
@@ -115,8 +107,8 @@ class BaseConfig:
     reactor: str = "auto"
     # shard plane (shard/): default chain count a ShardSet(n_shards=
     # None) assembles — N independent chains in one process behind one
-    # front door, sharing the process-default verifier/coalescer and
-    # one ReactorLoop. 0 keeps the single-chain deployment shape.
+    # front door, sharing the process-default verifier and one
+    # ReactorLoop. 0 keeps the single-chain deployment shape.
     # TM_TPU_SHARDS wins.
     shards: int = 0
 

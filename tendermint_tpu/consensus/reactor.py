@@ -395,9 +395,8 @@ class ConsensusReactor(Reactor):
                         hb.validator_address)
                     if val is None or idx != hb.validator_index:
                         return  # not a current validator: drop
-                    # verifier boundary, not scalar PubKey.verify: a
-                    # coalescing verifier batches heartbeats with the
-                    # concurrent vote/proposal verify traffic
+                    # verifier boundary, not scalar PubKey.verify:
+                    # one place holds all signature policy
                     from tendermint_tpu.models.verifier import \
                         default_verifier
                     verifier = self.cs.block_exec.verifier or \

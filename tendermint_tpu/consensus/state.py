@@ -1131,9 +1131,7 @@ class ConsensusState:
             raise ValueError("invalid proposal POL round")
         proposer = rs.validators.proposer()
         # through the BatchVerifier boundary (not scalar PubKey.verify):
-        # a coalescing verifier merges this with the vote traffic of
-        # concurrent peers/nodes into one device batch, and a mesh/jax
-        # verifier keeps ALL signature policy in one place
+        # one place holds ALL signature policy, whatever the backend
         from tendermint_tpu.models.verifier import default_verifier
         verifier = self.block_exec.verifier or default_verifier()
         if not verifier.verify_one(

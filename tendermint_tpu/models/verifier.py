@@ -30,6 +30,14 @@ dispatch so scalar verifies never pay jax backend init, and a no-op
 when only one device exists. `mesh=N` forces an N-device mesh;
 `mesh="off"` disables sharding.
 
+One way from a call to its verdicts: `verify_async` decides from the
+backend and the batch's size alone. A call of 1 to auto_threshold
+signatures under "auto" or "python" (a live vote, a proposal, a small
+set's LastCommit) is verified on the host by the thread that first
+asks for its verdicts; every other call is dispatched where it is
+made, and a device batch goes out through the one chunk loop of
+`_dispatch_direct`.
+
 `stats` counts what this verifier was asked and what it sent to the
 device; which kernel served each device dispatch is counted
 process-wide in ops/ed25519.predecomp_stats().
@@ -128,6 +136,11 @@ _dispatch_seq = itertools.count(1)
 # chip.
 BATCH_CHUNK = 8192
 
+# Threads that fetch a multi-chunk batch's verdict arrays at once.
+# Whether that beats a serial fetch, and at how many workers, is not
+# measured on the attached chip either.
+FETCH_WORKERS = 8
+
 # The native prep's SHA-512 loop runs on several threads once a batch
 # gives each this many lanes, and on no more than the cap. Both from
 # scripts/prep_threads.py on the chip's host (PERF.md section 6, PR 36).
@@ -154,10 +167,8 @@ def prep_threads(n: int, cores: int | None = None) -> int:
 
 _pool_lock = threading.Lock()
 
-# Shared pool for fetching chunk results: a multi-chunk batch's verdict
-# arrays are fetched from several threads at once. Whether that beats a
-# serial fetch, and at how many workers, is not measured on the
-# attached chip (ROADMAP Queue 3 item 7).
+# shared by every verifier of the process, started at the first
+# multi-chunk fetch
 _fetch_pool = None
 
 
@@ -167,24 +178,9 @@ def _fetch_pool_get():
         if _fetch_pool is None:
             from concurrent.futures import ThreadPoolExecutor
             _fetch_pool = ThreadPoolExecutor(
-                max_workers=knobs.knob_int("TM_TPU_FETCH_WORKERS",
-                                           default=8),
+                max_workers=FETCH_WORKERS,
                 thread_name_prefix="tm-verify-fetch")
         return _fetch_pool
-
-
-def _parse_coalesce_spec(spec: str) -> str:
-    """'auto' | 'on' | 'off'. Same eager-validation contract as
-    _parse_mesh_spec: config/env typos must fail at construction."""
-    s = str(spec).strip().lower()
-    if s in ("auto", ""):
-        return "auto"
-    if s in ("on", "1", "true", "yes"):
-        return "on"
-    if s in ("off", "0", "false", "no", "none"):
-        return "off"
-    raise ValueError(
-        f"verifier coalesce must be auto|on|off, got {spec!r}")
 
 
 # 'auto' | 'off' | power-of-two int, validated eagerly (shared with the
@@ -192,19 +188,33 @@ def _parse_coalesce_spec(spec: str) -> str:
 _parse_mesh_spec = _pmesh.parse_mesh_spec
 
 
+def _resolved_by_its_caller(dispatch, items: list):
+    """Resolver of a call no device would ever see: `dispatch(items)`
+    runs on the thread that first asks for the verdicts, which are kept
+    for one that asks again, and an exception is that caller's own. Two
+    threads that ask at once both verify, to the same verdicts."""
+    value = None
+
+    def resolve() -> np.ndarray:
+        nonlocal value
+        if value is None:
+            value = np.asarray(dispatch(items)())
+        return value
+
+    return resolve
+
+
 class BatchVerifier:
     def __init__(self, backend: str = "auto", auto_threshold: int = None,
-                 mesh: str = "off", coalesce: str | None = None,
-                 coalesce_wait_ms: float | None = None,
-                 coalesce_max_batch: int | None = None):
+                 mesh: str = "off"):
         # auto_threshold: batches at or below this verify scalar on host
         # (OpenSSL). Where the scalar/batch breakeven lies depends on
         # the dispatch round trip, which is not measured on the
-        # attached chip (ROADMAP Queue 1 item 2); the default of 128
-        # keeps small interactive commits off it, deployments tune it
-        # with TM_TPU_AUTO_THRESHOLD. Bulk paths (fast-sync windows,
-        # lite chains, 1000+-validator commits) sit far above any
-        # setting.
+        # attached chip (ROADMAP Queue 1, "Live consensus never reaches
+        # the device"); the default of 128 keeps small interactive
+        # commits off it, deployments tune it with
+        # TM_TPU_AUTO_THRESHOLD. Bulk paths (fast-sync windows, lite
+        # chains, 1000+-validator commits) sit far above any setting.
         if auto_threshold is None:
             auto_threshold = knobs.knob_int("TM_TPU_AUTO_THRESHOLD",
                                             default=128)
@@ -225,24 +235,7 @@ class BatchVerifier:
         # arithmetic only (never across a dispatch)
         self._stats_lock = threading.Lock()
         #: guarded_by _stats_lock
-        self.stats = {"calls": 0, "sigs": 0, "jax_sigs": 0,
-                      "coalesced_calls": 0}
-        # cross-call dispatch coalescing (models/coalescer.py): merge
-        # concurrent sub-threshold verify calls into one batch. Env
-        # knobs win over constructor args (same contract as telemetry:
-        # an operator's TM_TPU_COALESCE=off must silence any config).
-        self.coalesce = _parse_coalesce_spec(
-            knobs.knob_str("TM_TPU_COALESCE", config=coalesce,
-                           default="auto"))
-        if coalesce_wait_ms is None:
-            coalesce_wait_ms = knobs.knob_float(
-                "TM_TPU_COALESCE_WAIT_MS", default=2.0)
-        self._coalesce_wait_s = coalesce_wait_ms / 1e3
-        if coalesce_max_batch is None:
-            coalesce_max_batch = knobs.knob_int(
-                "TM_TPU_COALESCE_MAX_BATCH", default=0)
-        self._coalesce_max_batch = coalesce_max_batch or BATCH_CHUNK
-        self._coalescer = None  #: guarded_by _resolve_lock
+        self.stats = {"calls": 0, "sigs": 0, "jax_sigs": 0}
 
     def _resolve_mesh(self) -> None:
         """Build the mesh on first device dispatch. mesh='auto' uses the
@@ -277,55 +270,22 @@ class BatchVerifier:
         fast-sync loop applies window k-1 while window k verifies
         on-device); every chunk is enqueued up front.
 
-        Sub-threshold calls go to the dispatch coalescer
-        (models/coalescer.py) unless coalesce='off'. Backend 'jax',
-        where every call is the device's, queues them: concurrent
-        single-vote callers merge into one batched dispatch, each
-        getting back exactly its own verdicts. For 'auto' and 'python'
-        a merge changes nothing unless it lifts a batch over the
-        threshold, which takes arrivals far denser than live consensus
-        sends; under it the merged batch is verified on the host
-        signature by signature all the same. So a live vote, a proposal
-        or a small set's LastCommit stays on its caller's thread: the
-        resolver runs _verify_async_direct where it is called, as 'off'
-        would have at dispatch. Calls already above the threshold are
-        efficient as-is and dispatch directly."""
-        n = len(items)
-        if self.coalesce != "off" and 0 < n <= self.auto_threshold:
-            with self._stats_lock:
-                self.stats["coalesced_calls"] += 1
-            # double-checked fast path: the unlocked read sees None or
-            # a fully-built coalescer (assignment is atomic, publication
-            # happens under the lock); the slow path re-checks locked.
-            # tmlint: allow(lock-discipline): benign racy read, see above
-            c = self._coalescer
-            if c is None:
-                with self._resolve_lock:
-                    if self._coalescer is None:
-                        from tendermint_tpu.models.coalescer import \
-                            DispatchCoalescer
-                        self._coalescer = DispatchCoalescer(
-                            self._verify_async_direct,
-                            max_batch=self._coalesce_max_batch,
-                            max_wait_s=self._coalesce_wait_s)
-                    c = self._coalescer
-            if self.backend == "jax":
-                return c.submit(items)
-            return c.inline(items)
+        A call of 1 to auto_threshold signatures under 'auto' or
+        'python' is the host's, signature by signature, wherever it
+        runs: a live vote, a proposal or a small set's LastCommit stays
+        on its caller's thread, verified when its resolver is first
+        called (VoteSet and ValidatorSet.verify_commit_async dispatch
+        and resolve at different points, and the work belongs to the
+        second). Under 'jax' every call is the device's and is enqueued
+        here, as is every call above the threshold."""
+        if self.backend != "jax" and 0 < len(items) <= self.auto_threshold:
+            return _resolved_by_its_caller(self._verify_async_direct,
+                                           list(items))
         return self._verify_async_direct(items)
 
-    def close(self) -> None:
-        """Stop the coalescer dispatcher, if one was started. Safe to
-        call repeatedly; the verifier remains usable (a later coalesced
-        call starts a fresh dispatcher)."""
-        with self._resolve_lock:
-            c, self._coalescer = self._coalescer, None
-        if c is not None:
-            c.close()
-
     def _verify_async_direct(self, items):
-        """The non-coalescing dispatch path (also the coalescer's merge
-        target — it must never re-enter verify_async)."""
+        """Count the call, open its `verify.dispatch` span and route
+        it; never re-enters verify_async."""
         n = len(items)
         with self._stats_lock:
             self.stats["calls"] += 1
@@ -357,12 +317,13 @@ class BatchVerifier:
                 _m_dispatch.labels("python").observe(
                     time.perf_counter() - t_dispatch)
             return lambda: out1
-        # fast path: the whole host prep (classification, length/s<L
-        # checks, SHA-512 + mod-L) in one native call, GIL released —
-        # returns None for batches that need the general path below
-        # (secp256k1 keys, non-bytes members, native unavailable). A
-        # batch that arrives as columns is prepared from them in place;
-        # any other Sequence, a SigColumns too, is walked as triples.
+        # the whole host prep (classification, length/s<L checks,
+        # SHA-512 + mod-L) in one native call, GIL released. A batch
+        # that arrives as columns is prepared from them in place; any
+        # other Sequence, a SigColumns too, is walked as triples. What
+        # the native prep declines (secp256k1 keys, non-bytes members,
+        # native unavailable) is split by key type below or, all
+        # ed25519, prepared by ops/ed25519's own host prep.
         from tendermint_tpu import native
         with trace.span("verify.prep", n=n):
             prep, form = None, "columns"
@@ -372,85 +333,38 @@ class BatchVerifier:
                                            items.msgs, items.idx, threads)
             if prep is None:
                 prep, form = native.prep_items(items, threads), "items"
-            if prep is not None and telemetry.enabled():
+            if prep is None:
+                # 33-byte compressed-SEC1 pubkeys are secp256k1
+                secp_idx = [i for i, it in enumerate(items)
+                            if len(it[0]) == 33 and it[0][0] in (2, 3)]
+                if not secp_idx:
+                    from tendermint_tpu.ops import ed25519
+                    prep = ed25519.prepare_batch_bytes(
+                        [it[0] for it in items], [it[1] for it in items],
+                        [it[2] for it in items])
+            elif telemetry.enabled():
                 _m_prep_lanes.labels(
                     "sharded" if threads > 1 else "inline").inc(n)
-        if prep is not None:
-            from tendermint_tpu.ops import ed25519
-            if not self._mesh_resolved:
-                self._resolve_mesh()
-            self._record_jax_dispatch(n, form)
-            pk, rb, sb, hb, pre = prep
-            pending = []
-            occ = telemetry.enabled()
-            t_enqueued = 0.0
-            for lo in range(0, n, BATCH_CHUNK):
-                hi = min(lo + BATCH_CHUNK, n)
-                res = ed25519.verify_prepared_async(
-                    pk[lo:hi], rb[lo:hi], sb[lo:hi], hb[lo:hi],
-                    mesh=self._mesh)
-                if occ and not t_enqueued:
-                    t_enqueued = time.perf_counter()
-                pending.append((lo, hi, res, pre[lo:hi]))
-                if occ:
-                    b = ed25519._bucket(
-                        hi - lo, min_size=max(8, self.mesh_devices))
-                    _m_occupancy.observe((hi - lo) / b)
-                    if self.mesh_devices >= 2:
-                        _pmesh.record_dispatch("verify", hi - lo, b)
-            return self._make_resolver(n, pending, t_dispatch, span,
-                                       t_enqueued)
-        # mixed-key routing: 33-byte compressed-SEC1 pubkeys are
-        # secp256k1 — verified on host (off the TPU hot path by design,
-        # types/keys.py); everything else goes to the ed25519 device
-        # batch, where a non-ed25519 key fails its precheck anyway
-        secp_idx = [i for i, it in enumerate(items)
-                    if len(it[0]) == 33 and it[0][0] in (2, 3)]
-        if secp_idx:
-            from tendermint_tpu.types.keys import verify_any
-            secp_ok = {i: verify_any(*items[i]) for i in secp_idx}
-            ed_items = [it for i, it in enumerate(items)
-                        if i not in secp_ok]
-            if not ed_items:
-                out2 = np.zeros(n, np.bool_)
-                for i, ok in secp_ok.items():
-                    out2[i] = ok
-                return lambda: out2
-            inner = self._verify_async_direct(ed_items)
-            with self._stats_lock:
-                self.stats["calls"] -= 1  # the outer call already counted
-                self.stats["sigs"] -= len(ed_items)
-
-            def resolve_mixed() -> np.ndarray:
-                ed_ok = inner()
-                out3 = np.zeros(n, np.bool_)
-                k = 0
-                for i in range(n):
-                    if i in secp_ok:
-                        out3[i] = secp_ok[i]
-                    else:
-                        out3[i] = ed_ok[k]
-                        k += 1
-                return out3
-
-            return resolve_mixed
+        if prep is None:
+            # outside the prep span: the ed25519 lanes open a dispatch
+            # of their own
+            return self._dispatch_mixed(items, n, secp_idx)
         from tendermint_tpu.ops import ed25519
         if not self._mesh_resolved:
             self._resolve_mesh()
-        self._record_jax_dispatch(n)
-        pubkeys = [it[0] for it in items]
-        msgs = [it[1] for it in items]
-        sigs = [it[2] for it in items]
+        self._record_jax_dispatch(n, form)
+        pk, rb, sb, hb, pre = prep
         pending = []
         occ = telemetry.enabled()
         t_enqueued = 0.0
         for lo in range(0, n, BATCH_CHUNK):
             hi = min(lo + BATCH_CHUNK, n)
-            res, pre = ed25519.verify_batch_async(
-                pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi], mesh=self._mesh)
+            res = ed25519.verify_prepared_async(
+                pk[lo:hi], rb[lo:hi], sb[lo:hi], hb[lo:hi],
+                mesh=self._mesh)
             if occ and not t_enqueued:
                 t_enqueued = time.perf_counter()
-            pending.append((lo, hi, res, pre))
+            pending.append((lo, hi, res, pre[lo:hi]))
             if occ:
                 b = ed25519._bucket(
                     hi - lo, min_size=max(8, self.mesh_devices))
@@ -459,9 +373,42 @@ class BatchVerifier:
                     _pmesh.record_dispatch("verify", hi - lo, b)
         return self._make_resolver(n, pending, t_dispatch, span, t_enqueued)
 
-    def _record_jax_dispatch(self, n: int, form: str = "items") -> None:
+    def _dispatch_mixed(self, items, n: int, secp_idx: list):
+        """Mixed-key routing: the secp256k1 lanes are verified on host
+        (off the TPU hot path by design, types/keys.py); everything
+        else goes to the ed25519 device batch, where a non-ed25519 key
+        fails its precheck anyway."""
+        from tendermint_tpu.types.keys import verify_any
+        secp_ok = {i: verify_any(*items[i]) for i in secp_idx}
+        ed_items = [it for i, it in enumerate(items)
+                    if i not in secp_ok]
+        if not ed_items:
+            out2 = np.zeros(n, np.bool_)
+            for i, ok in secp_ok.items():
+                out2[i] = ok
+            return lambda: out2
+        inner = self._verify_async_direct(ed_items)
+        with self._stats_lock:
+            self.stats["calls"] -= 1  # the outer call already counted
+            self.stats["sigs"] -= len(ed_items)
+
+        def resolve_mixed() -> np.ndarray:
+            ed_ok = inner()
+            out3 = np.zeros(n, np.bool_)
+            k = 0
+            for i in range(n):
+                if i in secp_ok:
+                    out3[i] = secp_ok[i]
+                else:
+                    out3[i] = ed_ok[k]
+                    k += 1
+            return out3
+
+        return resolve_mixed
+
+    def _record_jax_dispatch(self, n: int, form: str) -> None:
         """Stats + calls/sigs samples for one device dispatch (chunk
-        occupancy is observed inside the chunk loops, where lo/hi and
+        occupancy is observed inside the chunk loop, where lo/hi and
         the ed25519 module are already in hand)."""
         with self._stats_lock:
             self.stats["jax_sigs"] += n
@@ -504,59 +451,6 @@ class BatchVerifier:
 
     def verify_one(self, pubkey: bytes, msg: bytes, sig: bytes) -> bool:
         return bool(self.verify([(pubkey, msg, sig)])[0])
-
-    def warmup_buckets(self, max_chunk: int = BATCH_CHUNK) -> None:
-        """Compile EVERY power-of-two bucket shape up to max_chunk, for
-        both the full kernel and the predecompressed variant (repeated
-        same-content batches engage the predecomp cache on the second
-        sighting). Streaming workloads (fast-sync waves) produce
-        arbitrary tail-window sizes; each lands in one of these buckets
-        (ed25519._bucket), so this closes the shape set — without it, a
-        first-ever tail size pays a multi-ten-second Mosaic compile
-        inside the timed region."""
-        if self.backend == "python":
-            return
-        from tendermint_tpu.ops import ed25519
-        if not self._mesh_resolved:
-            self._resolve_mesh()  # warm the kernel verify() will use
-        b = 512
-        while b <= max_chunk:
-            items = [(b"\x00" * 32, b"", b"\x00" * 64)] * b
-            for _ in range(2):  # 2nd pass: predecomp cache -> pre kernel
-                ed25519.verify_batch([it[0] for it in items],
-                                     [it[1] for it in items],
-                                     [it[2] for it in items],
-                                     mesh=self._mesh)
-            b *= 2
-
-    def warmup(self, n_sigs: int) -> None:
-        """Compile every kernel shape a verify() of n_sigs total items
-        will dispatch (the full BATCH_CHUNK shape and the padded tail
-        bucket). Benches call this so multi-minute device compiles never
-        land inside a timed region; the chunking/bucketing knowledge
-        stays here, next to the code that defines it."""
-        if n_sigs <= 0 or self.backend == "python":
-            return  # scalar backend compiles nothing
-        from tendermint_tpu import native
-        from tendermint_tpu.ops import ed25519
-        native.prep_items([])  # force the prep-extension g++ build now
-        shapes = {min(BATCH_CHUNK, n_sigs)}
-        tail = n_sigs % BATCH_CHUNK
-        if n_sigs > BATCH_CHUNK and tail:
-            shapes.add(tail)
-        if not self._mesh_resolved:
-            self._resolve_mesh()
-        for s in shapes:
-            # straight to the device path — self.verify would route tiny
-            # tails through the scalar backend and compile nothing.
-            # Zeroed items are canonical-length with s=0<L, so they run
-            # the full decompress+ladder (that's what makes the compile
-            # happen); the verdicts are discarded.
-            items = [(b"\x00" * 32, b"", b"\x00" * 64)] * s
-            ed25519.verify_batch([it[0] for it in items],
-                                 [it[1] for it in items],
-                                 [it[2] for it in items],
-                                 mesh=self._mesh)
 
 
 _default: BatchVerifier | None = None

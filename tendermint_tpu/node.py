@@ -183,28 +183,12 @@ class Node:
                                                     default_verifier)
         vb = getattr(config.base, "verifier_backend", "auto")
         vm = str(getattr(config.base, "verifier_mesh", "auto"))
-        vc = str(getattr(config.base, "verifier_coalesce", "auto"))
-        vc_wait = float(getattr(config.base,
-                                "verifier_coalesce_wait_ms", 2.0))
-        vc_max = int(getattr(config.base,
-                             "verifier_coalesce_max_batch", 0))
-        if (vb, vm, vc, vc_wait, vc_max) == \
-                ("auto", "auto", "auto", 2.0, 0):
-            # all-default: share the process-wide verifier — in-process
-            # testnets and the shard plane then coalesce vote
-            # verification ACROSS chains, exactly the aggregate-
-            # arrival-rate win the coalescer is for. Ownership is
-            # recorded HERE, at construction: comparing against the
-            # module global at stop() time would close the shared
-            # verifier out from under sibling shards the moment anyone
-            # called set_default_verifier() in between.
+        if (vb, vm) == ("auto", "auto"):
+            # all-default: in-process testnets and the shard plane
+            # share the process-wide verifier, its mesh and its stats
             self.verifier = default_verifier()
-            self._owns_verifier = False
         else:
-            self.verifier = BatchVerifier(
-                vb, mesh=vm, coalesce=vc, coalesce_wait_ms=vc_wait,
-                coalesce_max_batch=vc_max or None)
-            self._owns_verifier = True
+            self.verifier = BatchVerifier(vb, mesh=vm)
 
         # a state-sync restore a crash tore mid-apply is repaired HERE,
         # before the handshake reads the stores (the apply is
@@ -615,13 +599,6 @@ class Node:
         self.app_conns.close()
         if hasattr(self.wal, "close"):
             self.wal.close()
-        # only a verifier this node OWNS (recorded at construction):
-        # the shared default verifier's coalescer keeps serving the
-        # process's other nodes/shards regardless of any later
-        # set_default_verifier() swap, and shards stopping in
-        # arbitrary order can never close it out from under siblings
-        if self._owns_verifier:
-            self.verifier.close()
 
     @property
     def height(self) -> int:

@@ -162,10 +162,7 @@ def _pallas_available() -> bool:
     """The fused Mosaic kernels serve every TPU backend and nothing
     else. The platform alone decides: a TPU on which the Pallas module
     does not import or compile raises at the dispatch and is never
-    demoted to the jnp ladder. TM_TPU_NO_PALLAS asks for jnp."""
-    from tendermint_tpu.utils import knobs
-    if knobs.knob_set("TM_TPU_NO_PALLAS"):
-        return False
+    demoted to the jnp ladder."""
     return _platform() == "tpu"
 
 
@@ -221,12 +218,6 @@ def _dispatch(variant: str, mesh, *args):
     return out
 
 
-def verify_from_bytes_best(pk, rb, s_bytes, h_bytes):
-    """Packed-scalar entry point (32B/scalar host->device; unpack on
-    device), one device, kernel chosen as in _dispatch."""
-    return _dispatch("full", None, pk, rb, s_bytes, h_bytes)
-
-
 # ---------------------------------------------------------------------------
 # Pre-decompressed pubkey cache (stable-valset fast path)
 # ---------------------------------------------------------------------------
@@ -236,7 +227,7 @@ def verify_from_bytes_best(pk, rb, s_bytes, h_bytes):
 # every fast-sync window, every lite header). The cache keys PER
 # 32-BYTE PUBKEY, so once a validator's key has been decompressed once,
 # EVERY later batch containing it hits, whatever the batch's
-# composition or order (models/coalescer.py merges arbitrary votes).
+# composition or order.
 # A key's row is the canonical field bytes of (-A).x and A.y plus the
 # validity flag: 65 bytes, (-A).x | A.y | ok.
 #

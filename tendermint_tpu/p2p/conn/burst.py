@@ -2,8 +2,8 @@
 
 One resolver shared by SecretConnection (vectored seal/open) and
 MConnection (multi-packet drain per link write): burst mode and the max
-packets coalesced per send burst. Resolution order mirrors the verifier
-coalescer's: the TM_TPU_P2P_BURST env var always wins (an operator must
+packets coalesced per send burst. Resolution order is utils/knobs':
+the TM_TPU_P2P_BURST env var always wins (an operator must
 be able to pin a node's transport behavior regardless of config), then
 whatever node.py wired from `config.base.p2p_burst*`, then defaults.
 

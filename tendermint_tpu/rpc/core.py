@@ -83,8 +83,7 @@ _m_tx_batched = None   # registered lazily by TxBatcher (keeps this
 
 
 class TxBatcher:
-    """Front-door admission coalescing (the PR 2 coalescer's pattern at
-    the RPC boundary): concurrent broadcast_tx_sync/async calls arriving
+    """Front-door admission coalescing: concurrent broadcast_tx_sync/async calls arriving
     within a short linger merge into ONE Mempool.check_tx_batch — one
     proxy_mtx acquisition and one tx-WAL append for the whole batch.
     Per-call verdicts demux back to each waiter."""

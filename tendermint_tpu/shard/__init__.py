@@ -7,7 +7,7 @@ INDEPENDENT chains (distinct genesis docs, valsets and on-disk homes)
 inside one process:
 
 - ``set.py``     — ShardSet: assembles N ``Node`` values sharing the
-                   process-default verifier/coalescer/mesh and ONE
+                   process-default verifier and its mesh and ONE
                    ReactorLoop; node assembly is a value, not an
                    ambient (the forcing function that purged the
                    remaining process-global state from node.py).
@@ -22,10 +22,10 @@ inside one process:
                    plus a ``ContinuousCertifier``-backed commit proof,
                    so cross-shard reads are certified, not trusted.
 
-The paper's thesis (batch-crypto amortization) predicts a scaling
-property: concurrent sub-threshold
-verifies from many chains merge into bigger device batches, so the
-coalesce factor should rise with shard count (not measured on the chip).
+The shards share one verifier, so one key table, one mesh and one set
+of compiled kernels serve every chain's bulk verifies; a live vote is
+verified on the host by the thread that received it, whatever the
+shard count.
 
 Knob: ``TM_TPU_SHARDS`` (> ``config.base.shards`` > 0) sets the default
 shard count a ``ShardSet(n_shards=None)`` assembles; 0 keeps the

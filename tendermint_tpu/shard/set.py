@@ -4,17 +4,16 @@
 Each shard is a full ``Node`` (its own genesis doc, valset, stores,
 WAL, mempool, consensus state machine) with a DISTINCT chain id and —
 when a home directory is given — its own on-disk home. What the shards
-SHARE is exactly the process-wide amortization plane the paper's
-thesis is about: the default verifier (so concurrent sub-threshold
-verifies from many chains coalesce into bigger device batches), its
-coalescer and mesh, and one ``ReactorLoop`` for the whole process's
-sockets (the front door listener plus any node-level loop use).
+SHARE is the process-wide verification plane: the default verifier
+(one key table, one set of compiled kernels and one mesh for every
+chain's bulk verifies), and one ``ReactorLoop`` for the whole
+process's sockets (the front door listener plus any node-level loop
+use).
 
 Assembly is value-scoped, not ambient: every node's logger carries a
 ``chain=<id>`` field, per-shard telemetry rides a bounded ``chain``
-label (``tm_shard_height``), verifier ownership is recorded at
-construction (``Node._owns_verifier`` — stopping shards in ANY order
-can never close the shared verifier), and the shared loop is stopped
+label (``tm_shard_height``), the shared verifier holds nothing a
+stopping node would have to release, and the shared loop is stopped
 once by the set, never by a member node. The ``ambient-singleton``
 tmlint checker (analysis/checkers/ambient.py) keeps it that way: new
 module-level mutable singletons outside the blessed catalog fail the

@@ -1,9 +1,8 @@
 """Queue observatory — every bounded queue in the tree, one catalog.
 
 The tree grew bounded queues independently: mconn per-channel send
-queues, the mempool CList, EventBus subscriber buffers, the verifier
-coalescer's pending calls, the fast-sync request window, the statesync
-chunk fetcher. Each had (at best) its own gauge; none answered the
+queues, the mempool CList, EventBus subscriber buffers, the fast-sync
+request window, the statesync chunk fetcher. Each had (at best) its own gauge; none answered the
 backpressure question PR 8 left open — WHICH queue saturates first
 when the reactor plane backs up. This module is the single catalog:
 

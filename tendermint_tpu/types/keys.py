@@ -314,7 +314,7 @@ def _openssl_available() -> bool:
 # precomputed-table oracle. Gated on batch size for the same reason the
 # device predecomp cache is (ops/ed25519._PREDECOMP_MIN_BATCH): tables
 # cost a ladder's worth of build per key plus ~60KB residency, which
-# only aggregated consensus traffic (stable valsets, coalesced vote
+# only aggregated consensus traffic (stable valsets, votes added in
 # batches) amortizes — a one-off interactive verify must not populate
 # a cache it will never reuse.
 _HOST_TABLE_MIN = knobs.knob_int("TM_TPU_HOST_TABLE_MIN", default=4)
@@ -327,8 +327,8 @@ def verify_many(items) -> list:
     oracle would run) and the batch carries >= _HOST_TABLE_MIN ed25519
     members, those route through utils/ed25519_fast — the per-pubkey
     precomputed-table oracle with bit-identical verdicts at ~4-6x the
-    throughput. This is the path coalesced single-vote traffic takes on
-    accelerator-less hosts (models/coalescer.py)."""
+    throughput. This is the path a small commit or an aggregated vote
+    batch takes on hosts without OpenSSL (BatchVerifier's host path)."""
     ed = sum(1 for it in items
              if isinstance(it[0], (bytes, bytearray)) and len(it[0]) == 32)
     if ed >= _HOST_TABLE_MIN and not _openssl_available():

@@ -516,8 +516,9 @@ class ValidatorSet:
         structural failure immediately), and the returned zero-arg
         finisher completes the power check — raising exactly what
         verify_commit would. Opt-in async path: lets fast-sync/replay
-        overlap device crypto with host work and lets a coalescing
-        verifier merge concurrent commit verifies into one batch."""
+        overlap device crypto with host work; a commit small enough to
+        be the host's is verified inside the finisher
+        (BatchVerifier.verify_async)."""
         from tendermint_tpu.models.verifier import default_verifier
         verifier = verifier or default_verifier()
         with trace.span("commit.collect", req=height):

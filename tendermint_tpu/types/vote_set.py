@@ -80,9 +80,10 @@ class VoteSet:
 
     def add_vote_async(self, vote: Vote):
         """Opt-in async add: dispatches the signature verification
-        WITHOUT blocking (through BatchVerifier.verify_async, so a
-        coalescing verifier merges it with concurrent peers' votes into
-        one device batch) and returns a zero-arg resolver that applies
+        WITHOUT blocking (through BatchVerifier.verify_async: under
+        backend jax it is enqueued on the device now, otherwise it is
+        verified when the resolver runs) and returns a zero-arg
+        resolver that applies
         the vote and returns add_vote's result — raising exactly what
         add_vote would. Only the crypto is offloaded: validation runs
         now, the VoteSet mutation runs inside the resolver, which must
@@ -135,8 +136,9 @@ class VoteSet:
                          tally: Optional[dict] = None):
         """Validation now, signature dispatch now (async), application
         in the returned zero-arg finisher — the split that lets callers
-        overlap device crypto with host work and lets the coalescer
-        merge concurrent dispatches. `tally`, where given, is told how
+        overlap device crypto with host work (a batch the host verifies
+        is verified inside the finisher: BatchVerifier.verify_async).
+        `tally`, where given, is told how
         many votes were `duplicate` and how many `sigs` were sent to
         the verifier."""
         from tendermint_tpu.models.verifier import default_verifier

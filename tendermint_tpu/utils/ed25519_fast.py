@@ -13,17 +13,17 @@ Consensus verifies the SAME validator set's keys for every vote and
 commit, so the per-key build (one ladder's worth of doubles) amortizes
 to nothing — steady-state cost drops from ~1030 point ops per signature
 to ~190, a 4-6x speedup of the pure-Python oracle. This is what makes
-the dispatch coalescer's merged host batches fast on machines without
-OpenSSL (`cryptography`) and without a usable accelerator: the scalar
-oracle is the consensus-critical fallback there, and it is exactly the
-path the coalescer saturates.
+BatchVerifier's host batches (types/keys.verify_many: small commits,
+aggregated votes) fast on machines without OpenSSL (`cryptography`)
+and without a usable accelerator: the scalar oracle is the
+consensus-critical fallback there.
 
 SEMANTICS ARE BIT-IDENTICAL to ed25519_ref.verify: the checks are the
 same code, and s*B - h*A is the same group element whether computed by
 ladder or by table walk (extended-Edwards addition is complete), so
 point_compress yields the same 32 bytes. Differential-tested against
 the oracle on valid, tampered, non-canonical and garbage inputs
-(tests/test_coalescer.py::test_fast_verify_matches_oracle).
+(tests/test_verifier_calls.py::test_fast_verify_matches_oracle).
 
 `sign_expanded` reuses the same fixed-base table for the two base-point
 multiplies of RFC 8032 signing (R = r*B, plus the caller's one-time

@@ -1,7 +1,7 @@
 """Central catalog of every TM_TPU_* environment knob.
 
 Before this module each subsystem parsed its own env vars with its own
-truthy vocabulary (telemetry accepted "disabled", the coalescer did not;
+truthy vocabulary (telemetry accepted "disabled", the verifier did not;
 burst lower-cased, chaos did not), and nothing guaranteed a knob was
 documented. Now:
 
@@ -14,7 +14,7 @@ documented. Now:
 - The env-wins-over-config contract lives in one place: every helper
   takes an optional `config=` value and returns env > config > default.
   An operator exporting a knob must override whatever the config file
-  says (the contract telemetry/burst/chaos/coalescer each restated).
+  says (the contract telemetry, burst and chaos each restated).
 - Truthy parsing is unified: FALSY is the single vocabulary for "off".
 
 Import-light by design (stdlib `os` only): telemetry, native, and the
@@ -63,20 +63,6 @@ CATALOG: tuple[Knob, ...] = (
     Knob("TM_TPU_AUTO_THRESHOLD", "int", "128", "",
          "Batches at or below this size verify scalar on host.",
          "models/verifier.py"),
-    Knob("TM_TPU_FETCH_WORKERS", "int", "8", "",
-         "Threads fetching device chunk results concurrently.",
-         "models/verifier.py"),
-    Knob("TM_TPU_COALESCE", "str", "auto", "base.verifier_coalesce",
-         "Cross-call dispatch coalescing: auto|on|off.",
-         "models/verifier.py"),
-    Knob("TM_TPU_COALESCE_WAIT_MS", "float", "2.0",
-         "base.verifier_coalesce_wait_ms",
-         "Max linger per merged dispatch window, milliseconds.",
-         "models/verifier.py"),
-    Knob("TM_TPU_COALESCE_MAX_BATCH", "int", "0 (= BATCH_CHUNK)",
-         "base.verifier_coalesce_max_batch",
-         "Items that force a merged dispatch out early.",
-         "models/verifier.py"),
     Knob("TM_TPU_HOST_TABLE_MIN", "int", "4", "",
          "Min host batch size routed to the precomputed-table oracle.",
          "types/keys.py"),
@@ -87,9 +73,6 @@ CATALOG: tuple[Knob, ...] = (
     Knob("TM_TPU_NO_NATIVE", "bool", "unset (native on)", "",
          "Any non-empty value disables the native C plane entirely.",
          "native/__init__.py"),
-    Knob("TM_TPU_NO_PALLAS", "bool", "unset (pallas auto)", "",
-         "Any non-empty value disables the fused pallas kernel path.",
-         "ops/ed25519.py"),
     # -- p2p frame plane ---------------------------------------------------
     Knob("TM_TPU_P2P_BURST", "spec", "auto", "base.p2p_burst",
          "Burst frame plane: off|on|auto|<max packets per burst>.",

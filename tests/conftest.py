@@ -152,13 +152,11 @@ def _no_leaked_tm_threads():
     log into torn-down streams — the round-2 'Logging error' class.
 
     Only tm-* names opt in; the process-wide verify fetch pool
-    (tm-verify-fetch), the verifier coalescer dispatcher
-    (tm-verify-coalesce — shared by the default verifier, daemon,
-    idle-parked and self-reaping after 30s), and the introspection
-    plane's singletons (tm-queue-watch / tm-prof-sampler — process-
-    global daemons shared by every in-process node; tests that start
-    them explicitly stop them via queues.reset()/profile.stop()) are
-    deliberately long-lived and excluded."""
+    (tm-verify-fetch) and the introspection plane's singletons
+    (tm-queue-watch / tm-prof-sampler — process-global daemons shared
+    by every in-process node; tests that start them explicitly stop
+    them via queues.reset()/profile.stop()) are deliberately
+    long-lived and excluded."""
     before = {t.ident for t in threading.enumerate()}
     # a longer-scoped fixture (module-scoped node) legitimately keeps
     # respawning its threads (each ticker schedule is a fresh Timer
@@ -178,7 +176,6 @@ def _no_leaked_tm_threads():
                 and t.name.startswith("tm-")
                 and pool_of(t.name) not in before_names
                 and not t.name.startswith("tm-verify-fetch")
-                and not t.name.startswith("tm-verify-coalesce")
                 and not t.name.startswith("tm-queue-watch")
                 and not t.name.startswith("tm-prof-sampler")]
 

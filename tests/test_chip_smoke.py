@@ -162,7 +162,7 @@ def test_pallas_choice_is_the_platforms(monkeypatch):
     assert ed25519._pallas_available() is False        # conftest: cpu
     monkeypatch.setattr(ed25519, "_platform", lambda: "tpu")
     assert ed25519._pallas_available() is True
-    monkeypatch.setenv("TM_TPU_NO_PALLAS", "1")
+    monkeypatch.setattr(ed25519, "_platform", lambda: "gpu")
     assert ed25519._pallas_available() is False
 
 

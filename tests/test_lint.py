@@ -409,11 +409,11 @@ def test_lint_report_is_committed_and_clean():
 
 def test_knob_helpers_env_wins_over_config(monkeypatch):
     from tendermint_tpu.utils import knobs
-    monkeypatch.delenv("TM_TPU_COALESCE", raising=False)
-    assert knobs.knob_str("TM_TPU_COALESCE", config="on") == "on"
-    assert knobs.knob_str("TM_TPU_COALESCE", default="auto") == "auto"
-    monkeypatch.setenv("TM_TPU_COALESCE", "OFF")
-    assert knobs.knob_str("TM_TPU_COALESCE", config="on") == "off"
+    monkeypatch.delenv("TM_TPU_VERIFIER", raising=False)
+    assert knobs.knob_str("TM_TPU_VERIFIER", config="jax") == "jax"
+    assert knobs.knob_str("TM_TPU_VERIFIER", default="auto") == "auto"
+    monkeypatch.setenv("TM_TPU_VERIFIER", "PYTHON")
+    assert knobs.knob_str("TM_TPU_VERIFIER", config="jax") == "python"
     monkeypatch.setenv("TM_TPU_AUTO_THRESHOLD", "7")
     assert knobs.knob_int("TM_TPU_AUTO_THRESHOLD", config=3) == 7
     monkeypatch.delenv("TM_TPU_AUTO_THRESHOLD")
@@ -520,22 +520,37 @@ def test_lockwatch_condition_wait_keeps_held_set_honest(watch):
     assert watch.cycles() == []
 
 
-def test_lockwatch_guarded_attr_cross_thread_violation(watch):
-    import numpy as np
+class _Latch:
+    """What watch_annotated reads: an attribute annotated on the line
+    that assigns it, and the lock that guards it."""
 
-    from tendermint_tpu.models.coalescer import DispatchCoalescer
-    assert watch.watch_annotated(
-        ("tendermint_tpu.models.coalescer",)) >= 4
-    c = DispatchCoalescer(
-        lambda items: (lambda: np.zeros(len(items), bool)))
-    resolve = c.submit([1, 2])
-    assert list(resolve()) == [False, False]
-    c.close()
-    # the dispatcher thread touches _queue/_closed under _cond: clean
+    def __init__(self, lock):
+        self._cond = threading.Condition(lock)
+        self._closed = False  #: guarded_by _cond
+
+    def close(self) -> None:
+        with self._cond:
+            self._closed = True
+            self._cond.notify_all()
+
+    def wait_closed(self, timeout: float) -> bool:
+        with self._cond:
+            return self._cond.wait_for(lambda: self._closed, timeout)
+
+
+def test_lockwatch_guarded_attr_cross_thread_violation(watch):
+    assert watch.watch_annotated((__name__,)) == 1
+    # only locks allocated from the package are wrapped by themselves
+    latch = _Latch(watch.make_lock(site="test_lint.py:_Latch"))
+    closer = threading.Thread(target=latch.close, daemon=True)
+    closer.start()
+    assert latch.wait_closed(5)
+    closer.join(5)
+    # both threads touch _closed under _cond: clean
     assert watch.report()["attr_violations"] == []
 
     def poke():  # second thread, no lock: the race the watch exists for
-        _ = c._closed
+        _ = latch._closed
 
     t = threading.Thread(target=poke, daemon=True)
     t.start()

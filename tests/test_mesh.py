@@ -211,11 +211,12 @@ def test_mesh_auto_noop_on_single_device_host(monkeypatch):
 
 
 def test_coalesced_batches_pad_mesh_divisible(monkeypatch):
-    """Cross-caller batches merged by the dispatch coalescer (PR 2)
-    land on the sharded kernel with a mesh-divisible padded axis: the
-    mesh-derived min bucket flows through _verify_async_direct (the
-    coalescer's merge target), so every dispatched shape is a power of
-    two >= the mesh width. Forced 4-device mesh on the 8-device host."""
+    """Two concurrent calls under the threshold, backend jax, each
+    dispatched where it is made: both land on the sharded kernel with a
+    mesh-divisible padded axis (the mesh-derived min bucket flows
+    through _verify_async_direct), so every dispatched shape is a power
+    of two >= the mesh width. Forced 4-device mesh on the 8-device
+    host."""
     from concurrent.futures import ThreadPoolExecutor
 
     from tendermint_tpu.models.verifier import BatchVerifier
@@ -223,8 +224,7 @@ def test_coalesced_batches_pad_mesh_divisible(monkeypatch):
     pubs, msgs, sigs = signed_batch(8, tamper={5})
     items = list(zip(pubs, msgs, sigs))
 
-    v = BatchVerifier("jax", mesh="4", coalesce="on",
-                      coalesce_wait_ms=25.0)
+    v = BatchVerifier("jax", mesh="4")
     v._resolve_mesh()
     assert v.mesh_devices == 4
 
@@ -237,21 +237,15 @@ def test_coalesced_batches_pad_mesh_divisible(monkeypatch):
         return inner(variant, mesh, *args)
 
     monkeypatch.setattr(ed25519, "_dispatch", recording)
-    try:
-        # two concurrent sub-threshold callers -> the coalescer merges
-        # (or, on an unlucky linger, dispatches each separately; either
-        # way every dispatch must be mesh-divisible)
-        with ThreadPoolExecutor(max_workers=2) as pool:
-            futs = [pool.submit(v.verify, items[:4]),
-                    pool.submit(v.verify, items[4:])]
-            first, second = futs[0].result(), futs[1].result()
-    finally:
-        v.close()
+    with ThreadPoolExecutor(max_workers=2) as pool:
+        futs = [pool.submit(v.verify, items[:4]),
+                pool.submit(v.verify, items[4:])]
+        first, second = futs[0].result(), futs[1].result()
     assert first.tolist() == [True] * 4
     assert second.tolist() == [True, False, True, True]  # tamper at 5
-    assert v.stats["coalesced_calls"] == 2
-    assert shapes, "no sharded dispatch recorded"
-    assert all(s % 4 == 0 for s in shapes), shapes
+    assert v.stats == {"calls": 2, "sigs": 8, "jax_sigs": 8}
+    assert len(shapes) == 2, shapes
+    assert all(s % 4 == 0 and s & (s - 1) == 0 for s in shapes), shapes
 
 
 def test_mesh_telemetry_surfaces():

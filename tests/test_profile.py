@@ -319,8 +319,8 @@ def test_watch_thread_and_off_knob(monkeypatch):
 
 
 def test_real_owners_register_into_catalog():
-    """The wired owners (EventBus subscription, coalescer) land in the
-    catalog with live depths; unsubscribe/close removes them."""
+    """A wired owner (EventBus subscription) lands in the catalog with
+    live depths; unsubscribe removes it. The verifier keeps no queue."""
     from tendermint_tpu.types.events import EventBus
     bus = EventBus()
     sub = bus.subscribe("obs-test", "tm.event = 'Tx'", capacity=4)
@@ -335,14 +335,10 @@ def test_real_owners_register_into_catalog():
     queues.poll()
     assert queues.table()["event.subscriber"]["instances"] == 0
 
-    from tendermint_tpu.models.coalescer import DispatchCoalescer
-    co = DispatchCoalescer(lambda items: (lambda: [True] * len(items)),
-                           max_batch=64)
+    from tendermint_tpu.models.verifier import BatchVerifier
+    assert BatchVerifier("python").verify([]).tolist() == []
     queues.poll()
-    assert queues.table()["verifier.coalesce"]["capacity"] == 64
-    co.close()
-    queues.poll()
-    assert queues.table()["verifier.coalesce"]["instances"] == 0
+    assert not [k for k in queues.table() if k.startswith("verifier")]
 
 
 # --------------------------------------------------------- RPC surface

@@ -190,16 +190,3 @@ def test_uniform_results_lazy_keys_roundtrip():
     assert r2.keys == [b"k1", b"key2", b""]
 
 
-# -------------------------------------------------- verifier warmup
-
-def test_warmup_buckets_covers_every_tail_bucket():
-    """Every power-of-two bucket from 512 to BATCH_CHUNK must verify
-    without a fresh jit entry afterwards (the compile-set is closed)."""
-    from tendermint_tpu.models.verifier import BATCH_CHUNK, BatchVerifier
-    b, buckets = 512, []
-    while b <= BATCH_CHUNK:
-        buckets.append(b)
-        b *= 2
-    assert buckets[0] == 512 and buckets[-1] == BATCH_CHUNK
-    # python backend: warmup must be a no-op (no jax import storm)
-    BatchVerifier("python").warmup_buckets()

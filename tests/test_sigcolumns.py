@@ -296,21 +296,18 @@ def signed_commit(net, n=None, tampered=()):
 @pytest.mark.parametrize("n, route", [(N_DEVICE, "device"), (6, "host")])
 def test_verify_async_on_columns_and_on_their_list(net, n, route):
     """Above auto_threshold the columns go to the device (the jnp kernel
-    here), at or under it to the coalescer as a list: the same verdicts
-    lane by lane, which are the scalar oracle's."""
+    here), at or under it to the host's scalar path as a list: the same
+    verdicts lane by lane, which are the scalar oracle's."""
     tampered = {1, 2, 3, n - 1}
     valset, commit = signed_commit(net, n, tampered)
     items, power = valset.commit_verification_items(CHAIN, BLOCK, HEIGHT,
                                                     commit)
     verifier = BatchVerifier("auto", auto_threshold=8, mesh="off")
-    try:
-        before = dict(verifier.stats)
-        from_columns = verifier.verify_async(items)()
-        from_list = verifier.verify_async(list(items))()
-        assert verifier.stats["jax_sigs"] - before["jax_sigs"] == \
-            (2 * n if route == "device" else 0)
-    finally:
-        verifier.close()
+    before = dict(verifier.stats)
+    from_columns = verifier.verify_async(items)()
+    from_list = verifier.verify_async(list(items))()
+    assert verifier.stats["jax_sigs"] - before["jax_sigs"] == \
+        (2 * n if route == "device" else 0)
     oracle = [verify_any(*it) for it in items]
     assert from_columns.tolist() == from_list.tolist() == oracle
     assert oracle == [i not in tampered for i in range(n)]
@@ -339,15 +336,12 @@ def test_a_prep_forced_onto_two_threads_gives_the_same_verdicts(
     monkeypatch.setattr(native, "prep_columns", watch(real[0]))
     monkeypatch.setattr(native, "prep_items", watch(real[1]))
     verifier = BatchVerifier("jax", mesh="off")
-    try:
-        assert verifier_mod.prep_threads(N_DEVICE) == 1
-        alone = [verifier.verify_async(form)()
-                 for form in (items, list(items))]
-        monkeypatch.setattr(verifier_mod, "prep_threads", lambda n: 2)
-        forced = [verifier.verify_async(form)()
-                  for form in (items, list(items))]
-    finally:
-        verifier.close()
+    assert verifier_mod.prep_threads(N_DEVICE) == 1
+    alone = [verifier.verify_async(form)()
+             for form in (items, list(items))]
+    monkeypatch.setattr(verifier_mod, "prep_threads", lambda n: 2)
+    forced = [verifier.verify_async(form)()
+              for form in (items, list(items))]
     assert seen == [1, 1, 2, 2]
     for got in alone + forced:
         assert got.tolist() == [i not in tampered for i in range(N_DEVICE)]
@@ -671,21 +665,16 @@ def test_prepared_lanes_count_as_sharded_or_inline(monkeypatch):
         assert prep_lanes()["inline"] - after["inline"] == len(big)
     finally:
         telemetry.configure(enabled=was)
-        verifier.close()
 
 
 def test_a_four_vote_commit_counts_under_neither(net, telemetry_on):
     valset, commit = signed_commit(net, 4)
     verifier = BatchVerifier("auto", mesh="off")
-    try:
-        before = batch_sigs()
-        valset.verify_commit(CHAIN, BLOCK, HEIGHT, commit,
-                             verifier=verifier)
-        assert batch_sigs() == before
-        assert verifier.stats["jax_sigs"] == 0
-        assert verifier.stats["coalesced_calls"] == 1
-    finally:
-        verifier.close()
+    before = batch_sigs()
+    valset.verify_commit(CHAIN, BLOCK, HEIGHT, commit,
+                         verifier=verifier)
+    assert batch_sigs() == before
+    assert verifier.stats == {"calls": 1, "sigs": 4, "jax_sigs": 0}
 
 
 def test_the_lite_windows_two_passes_are_recorded_once_each(telemetry_on):

@@ -36,7 +36,7 @@ def net():
 @pytest.fixture
 def device_verifier(monkeypatch):
     monkeypatch.setattr(verifier_mod, "BATCH_CHUNK", CHUNK)
-    return BatchVerifier("jax", mesh="off", coalesce="off")
+    return BatchVerifier("jax", mesh="off")
 
 
 def commit_of(net, height, for_block=None):
