@@ -35,8 +35,9 @@ from tendermint_tpu.types.block import TXS_PATH, Block, BlockID
 # on a chain whose validator set never moves, and above a change of
 # stake, every block is "batched" and every lane "used"; above a join
 # the lanes "discarded" are the joiners', whose keys the window's set
-# did not hold; a block is "reverified" only where its window had no
-# lanes for it (see _sync_window).
+# did not hold, whether the set kept its size or grew; a block is
+# "reverified" only where its window had no lanes for it (see
+# _sync_window).
 _m_commits = telemetry.counter(
     "sync_commits_total",
     "Blocks fast-sync applied, by how their commit was judged: batched "
@@ -54,6 +55,12 @@ _m_live_judged = telemetry.counter(
     "sync_live_judged_total",
     "Blocks whose pooled verdicts fast-sync judged under a validator set "
     "other than the one their window was collected with", ())
+_m_resized = telemetry.counter(
+    "sync_resized_total",
+    "Blocks fast-sync applied whose commit was of another size than the "
+    "validator set their window was collected with (the set grew or "
+    "shrank in between) and came with lanes all the same, paired by "
+    "address", ())
 
 BLOCKCHAIN_CHANNEL = 0x40
 # the transactions of the block a block_response carries
@@ -354,14 +361,16 @@ class BlockchainReactor(Reactor):
         OPTIMISTICALLY under the keys of the current valset: the set in
         force when the window is collected, not the one each block was
         signed by. Every vote brings one lane, under the best guess of
-        its key. Where a block's header names the collection set, lane
-        i is vote i under that set's key i. Where it names another
-        (the set has moved, or will have by the time the block
-        applies), a join has shifted the slots between the two
-        addresses, so the vote is paired with the key this set holds
-        for the vote's own address (ValidatorSet.rows_by_address). The
-        header is untrusted and only a hint for pairing: _apply_window
-        keeps a verdict for the key it was computed under and no other.
+        its key. Where a block's header names the collection set and
+        its commit has that set's size, lane i is vote i under that
+        set's key i. Where the header names another (the set has moved,
+        or will have by the time the block applies) or the commit is of
+        another size (the set has grown or shrunk), a join or a leave
+        has shifted the slots between the two addresses, so the vote is
+        paired with the key this set holds for the vote's own address
+        (ValidatorSet.commit_lanes_by_address). Header and address are
+        untrusted and only hints for pairing: _apply_window keeps a
+        verdict for the key it was computed under and no other.
         Returns None when fewer than 2 consecutive blocks are ready
         there."""
         blocks = self.pool.peek_window(self.verify_window, skip=skip)
@@ -375,30 +384,31 @@ class BlockchainReactor(Reactor):
         batches = []
         lo = 0
         per_block = []  # (block, parts, block_id, commit,
-        #                  for_block|None, lo, lanes)
+        #                  for_block|None, lo, lanes, resized)
         for i in range(len(blocks) - 1):
             block, commit = blocks[i], blocks[i + 1].last_commit
             parts, block_id = self._parts_and_id(block)
+            height = block.header.height
+            resized = len(commit.precommits) != len(batch_valset)
             try:
-                items, item_power = batch_valset.commit_verification_items(
-                    chain_id, block_id, block.header.height, commit)
+                if block.header.validators_hash == vs_hash and not resized:
+                    items, item_power = \
+                        batch_valset.commit_verification_items(
+                            chain_id, block_id, height, commit)
+                    for_block = item_power.for_block
+                else:
+                    items, for_block = batch_valset.commit_lanes_by_address(
+                        chain_id, block_id, height, commit)
             except ValueError:
-                # not necessarily a bad peer: where the set has grown or
-                # shrunk since the collection set, the commit has
-                # another size and contributes no lanes; the block is
-                # verified whole against the live set in the apply loop
-                # (`sync.reverify`, tm_sync_commits_total{reverified})
+                # a commit no set would take (a vote that is no
+                # precommit of this height and round): no lanes; the
+                # apply loop hears it from verify_commit under the live
+                # set (`sync.reverify`) and punishes the peer there
                 per_block.append((block, parts, block_id, commit,
-                                  None, 0, ()))
+                                  None, 0, (), resized))
                 continue
-            if block.header.validators_hash != vs_hash and \
-                    isinstance(items, SigColumns):
-                items = SigColumns(
-                    batch_valset.columns().pk[
-                        batch_valset.rows_by_address(commit)],
-                    items.sigs, items.msgs, items.idx)
             per_block.append((block, parts, block_id, commit,
-                              item_power.for_block, lo, items))
+                              for_block, lo, items, resized))
             lo += len(items)
             batches.append(items)
         return per_block, SigColumns.concat(batches), vs_hash, part_size
@@ -415,7 +425,7 @@ class BlockchainReactor(Reactor):
         chain_id = self.state.chain_id
         verifier = self._verifier()
         applied = 0
-        for block, parts, block_id, commit, for_block, lo, lanes \
+        for block, parts, block_id, commit, for_block, lo, lanes, resized \
                 in per_block:
             if block.header.height != self.block_store.height() + 1:
                 # the window no longer lines up with the store (a
@@ -433,23 +443,28 @@ class BlockchainReactor(Reactor):
                 parts, block_id = self._parts_and_id(block)
                 pooled = False
             vs_now = self.state.validators
+            height = block.header.height
             n = again = len(lanes)
             try:
                 if pooled:
+                    t_judge = time.perf_counter() \
+                        if telemetry.enabled() else 0.0
                     again = vs_now.check_commit_lanes(
                         commit, lanes, ok[lo:lo + n], for_block, verifier)
+                    if t_judge:
+                        trace.complete("sync.judge", t_judge,
+                                       time.perf_counter(), req=height,
+                                       again=again)
                 else:
-                    # the window has no lanes for this block (the
-                    # commit is not of the collection set's size, or
-                    # its block id was rebuilt): one whole verify
-                    # against the live set, alone and synchronously
-                    with trace.span("sync.reverify",
-                                    req=block.header.height):
-                        vs_now.verify_commit(chain_id, block_id,
-                                             block.header.height, commit,
-                                             verifier=verifier)
+                    # the window has no lanes for this block (its
+                    # commit is one no set would take, or its block id
+                    # was rebuilt): one whole verify against the live
+                    # set, alone and synchronously
+                    with trace.span("sync.reverify", req=height):
+                        vs_now.verify_commit(chain_id, block_id, height,
+                                             commit, verifier=verifier)
             except ValueError:
-                self._punish_bad_window(block.header.height)
+                self._punish_bad_window(height)
                 return applied
             # seen-commit = the commit FOR this block (= next block's
             # LastCommit), matching the reference's SaveBlock(first,
@@ -470,6 +485,8 @@ class BlockchainReactor(Reactor):
                 _m_lanes.labels("discarded").inc(again)
             if pooled and vs_now.hash() != batch_valset_hash:
                 _m_live_judged.inc()
+            if pooled and resized:
+                _m_resized.inc()
             if self.after_apply is not None:
                 # recovery plane: interval snapshots + pruning fire on
                 # the sync path too (the app sits at exactly this
@@ -494,12 +511,15 @@ class BlockchainReactor(Reactor):
         vote's slot, tallies with the live stake, and verifies again,
         scalar on the host and in one call a block, only the lanes
         under another key: the joiners the collection set had never
-        seen. A change of stake discards nothing. Only a commit of
-        another size than the collection set's (a set that grows or
-        shrinks) has no lanes and is verified whole at apply, one
-        synchronous verify_commit (`sync.reverify`). tm_sync_commits_
-        total{how}, tm_sync_lanes_total{how} and tm_sync_live_judged_
-        total count all of it. Returns True on progress.
+        seen. A change of stake discards nothing, and a set that grows
+        or shrinks nothing but those lanes either: a commit of another
+        size than the collection set's is paired by address like any
+        other (tm_sync_resized_total). Only a commit no set would take
+        and a block whose part set was rebuilt have no lanes and are
+        verified whole at apply, one synchronous verify_commit
+        (`sync.reverify`). tm_sync_commits_total{how}, tm_sync_lanes_
+        total{how} and tm_sync_live_judged_total count all of it.
+        Returns True on progress.
         """
         pending = self._pending_window
         skip = 0 if pending is None else max(0, len(pending[0]))

@@ -92,12 +92,17 @@ SPANS = {
     "sync.apply": "sync window engine",
     "sync.store": "sync window engine",
     # one event per block for which its window had no lanes (a commit
-    # of another size than the set the window was collected under, or a
-    # block id rebuilt at another part size): the synchronous
-    # verify_commit under the live set (commit.* nest in it; req = the
-    # height). A set that only moves fires none: its windows' verdicts
-    # are judged by their keys under the set in force
+    # no set would take, or a block id rebuilt at another part size):
+    # the synchronous verify_commit under the live set (commit.* nest
+    # in it; req = the height). A set that moves, grows or shrinks
+    # fires none: its windows' verdicts are judged by their keys under
+    # the set in force
     "sync.reverify": "verifier",
+    # that judge, one event per block that came with lanes
+    # (ValidatorSet.check_commit_lanes in _apply_window: the keys
+    # compared, the lanes under another key verified again, the live
+    # stake tallied; req = the height, `again` = lanes verified again)
+    "sync.judge": "verifier",
     "wire.decode_block": "sync window engine",
     "apply.validate": "apply and Merkle",   # incl. the data hash again
     "apply.exec": "apply and Merkle",

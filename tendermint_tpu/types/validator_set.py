@@ -24,14 +24,14 @@ from tendermint_tpu.types.keys import PubKey, address_of
 from tendermint_tpu.types.sigcolumns import SigColumns
 from tendermint_tpu.types.vote import VoteType, sign_bytes_template
 
-# counted once per commit_verification_items call that walked a commit
-# to its end
+# counted once per call of a collect phase (commit_verification_items,
+# commit_lanes_by_address) that walked a commit to its end
 _m_vote_walks = telemetry.counter(
     "verifier_vote_walks_total",
-    "Commits whose votes commit_verification_items walked: native "
-    "(native/prep.cpp walk_votes, one call a commit) or pure (the "
-    "Python loop: no extension, or a commit the native walk declined "
-    "to read)", ("how",))
+    "Commits whose votes a collect phase (commit_verification_items, "
+    "commit_lanes_by_address) walked: native (native/prep.cpp "
+    "walk_votes, one call a commit) or pure (the Python loop: no "
+    "extension, or a commit the native walk declined to read)", ("how",))
 
 _address_memo = functools.lru_cache(maxsize=65536)(address_of)
 
@@ -323,20 +323,11 @@ class ValidatorSet:
 
     # -- commit verification: THE batched hot path --------------------------
 
-    def commit_verification_items(self, chain_id: str, block_id,
-                                  height: int, commit):
-        """Collect phase of verify_commit: structural checks, then the
-        commit's signatures as `(items, item_power)`. `items` is a batch
-        for BatchVerifier.verify_async: a SigColumns for an ed25519
-        set, else the list of (pubkey, sign_bytes, sig) triples;
-        `item_power` goes to check_commit_results with the verdicts.
-        Split out so fast-sync and the lite client pool the batches of
-        MANY blocks into one device dispatch (blockchain/reactor.go:286's
-        per-block loop becomes one TPU dispatch per window)."""
+    def _walk_commit(self, chain_id: str, block_id, height: int, commit):
+        """What both collect phases read of a commit, whatever its
+        size: the height and round rules and one sign-bytes per run of
+        votes, as `_walk_votes` gives them."""
         pcs = commit.precommits
-        if len(self.validators) != len(pcs):
-            raise ValueError(
-                f"commit size {len(pcs)} != valset size {len(self.validators)}")
         if height != commit.height():
             raise ValueError("commit height mismatch")
         round_ = commit.round()
@@ -357,7 +348,24 @@ class ValidatorSet:
             walked = _walk_votes(pcs, height, round_, precommit, template)
         if telemetry.enabled():
             _m_vote_walks.labels(how).inc()
-        sigs, msgs, idx, for_block, absent, all_for = walked
+        return walked
+
+    def commit_verification_items(self, chain_id: str, block_id,
+                                  height: int, commit):
+        """Collect phase of verify_commit: structural checks, then the
+        commit's signatures as `(items, item_power)`. `items` is a batch
+        for BatchVerifier.verify_async: a SigColumns for an ed25519
+        set, else the list of (pubkey, sign_bytes, sig) triples;
+        `item_power` goes to check_commit_results with the verdicts.
+        Split out so fast-sync and the lite client pool the batches of
+        MANY blocks into one device dispatch (blockchain/reactor.go:286's
+        per-block loop becomes one TPU dispatch per window)."""
+        pcs = commit.precommits
+        if len(self.validators) != len(pcs):
+            raise ValueError(
+                f"commit size {len(pcs)} != valset size {len(self.validators)}")
+        sigs, msgs, idx, for_block, absent, all_for = self._walk_commit(
+            chain_id, block_id, height, commit)
 
         cols = self.columns()
         if absent:
@@ -378,6 +386,30 @@ class ValidatorSet:
                              sigs))
         return items, CommitPower(powers, for_block, tally)
 
+    def commit_lanes_by_address(self, chain_id: str, block_id,
+                                height: int, commit):
+        """Collect phase for a commit that is NOT this set's to judge:
+        fast-sync's window is collected under the set held then, and a
+        block's own set, the one in force when it applies, may hold
+        other members, in other slots, and more or fewer of them. The
+        walk of commit_verification_items (its height and round rules,
+        its sign-bytes) without the size rule and without this set's
+        stake: `(items, for_block)`, one lane per vote that is there,
+        in order, each under the key of `rows_by_address`; `items` in
+        commit_verification_items' two forms, `for_block` the flags its
+        CommitPower carries. What judges them is check_commit_lanes
+        under the set in force, which takes a verdict only for the key
+        it was computed under."""
+        sigs, msgs, idx, for_block, _absent, _all_for = self._walk_commit(
+            chain_id, block_id, height, commit)
+        rows = self.rows_by_address(commit)
+        cols = self.columns()
+        if cols.pk is not None:
+            return SigColumns(cols.pk[rows], sigs, msgs, idx), for_block
+        vals = self.validators
+        return list(zip([vals[r].pubkey for r in rows],
+                        map(msgs.__getitem__, idx.tolist()), sigs)), for_block
+
     def check_commit_results(self, ok, item_power) -> None:
         """Judge phase of verify_commit: every signature valid and +2/3
         power on the block. `ok`: the verdicts of the commit's lanes, a
@@ -397,15 +429,20 @@ class ValidatorSet:
 
     def rows_by_address(self, commit) -> list:
         """For each vote `commit` holds, in order, the slot at which
-        THIS set has the vote's `validator_address`, and the vote's own
-        slot where it knows no such address: the rows of `columns().pk`
-        under which a set that is not the commit's own has the best
-        chance of verifying each vote under the key its own set holds
-        for it. The address is the vote's claim and only chooses the
-        key that is tried: check_commit_lanes believes a verdict for the
-        key it was computed under and for no other."""
-        known = self._index.get
-        return [known(pc.validator_address, i)
+        THIS set has the vote's `validator_address`: the rows of
+        `columns().pk` under which a set that is not the commit's own
+        has the best chance of verifying each vote under the key its
+        own set holds for it. An address this set does not know (a
+        validator that joined since) gets a row all the same, so that a
+        commit brings one lane a vote whatever set pairs it: the vote's
+        own slot, or this set's last where the commit is the larger.
+        That lane's verdict is for a key the vote's set does not hold
+        there, and check_commit_lanes verifies the vote again. The
+        address is the vote's claim and only chooses the key that is
+        tried: check_commit_lanes believes a verdict for the key it was
+        computed under and for no other."""
+        known, last = self._index.get, len(self.validators) - 1
+        return [known(pc.validator_address, min(i, last))
                 for i, pc in enumerate(commit.precommits) if pc is not None]
 
     def check_commit_lanes(self, commit, lanes, ok, for_block,

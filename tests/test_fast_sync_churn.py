@@ -14,8 +14,10 @@ from benchmark import joinref
 from benchmark.chain import ChainBuilder, forge_precommit
 from benchmark.drivers.sync import PEER_ID, drive, fresh_reactor
 from benchmark.drivers.sync_join import synced
+from benchmark.growchain import (JOIN, LEAVE, GrowChain, address_rewritten,
+                                 leaver_still_in_commit)
 from benchmark.joinchain import (MEMBERSHIP, STAKE, JoinChain,
-                                 departed_signs_for_joiner, val_tx)
+                                 departed_signs_for_joiner)
 from benchmark.spans import SpanLog
 from tendermint_tpu.models.verifier import default_verifier
 
@@ -222,7 +224,8 @@ def recorder():
                 for fam, hows in ((reactor._m_commits,
                                    ("batched", "reverified")),
                                   (reactor._m_lanes, ("used", "discarded")))
-                for how in hows] + [reactor._m_live_judged._implicit]
+                for how in hows] + [reactor._m_live_judged._implicit,
+                                    reactor._m_resized._implicit]
     held = [c.value for c in children]
     for c in children:
         c.value = 0.0
@@ -334,52 +337,246 @@ def test_blocks_above_a_change_are_reverified_and_counted(
                        for h in range(1, N_BLOCKS + 1)}
 
 
-def test_a_commit_of_another_size_than_the_windows_set_is_reverified(
-        recorder):
+class PlacedGrowChain(GrowChain):
+    """GrowChain (joins that grow the set, leaves that shrink it) with
+    its changes where a test puts them."""
+
+    def __init__(self, seed, placed, n_blocks=N_BLOCKS, n_vals=N_VALS, **kw):
+        self._placed = dict(placed)
+        kinds = list(placed.values())
+        super().__init__(seed, n_blocks, n_vals, n_vals + kinds.count(JOIN),
+                         kinds.count(JOIN), kinds.count(LEAVE),
+                         kinds.count(STAKE), n_txs=3, tx_bytes=48,
+                         key_space=8, **kw)
+
+    def _place_changes(self, n_blocks, stake_changes, joins):
+        return self._placed
+
+
+def resized_blocks(telemetry):
+    return int(telemetry.value("sync_resized_total") or 0)
+
+
+def judged(telemetry):
+    """{height: lanes verified again} of the `sync.judge` events."""
+    return {e["req"]: e["args"]["again"]
+            for e in events(telemetry, "sync.judge")}
+
+
+def test_a_commit_of_another_size_than_the_windows_set_comes_with_lanes(
+        recorder, collected_under):
     """A set that grows: the commits above the join have one vote more
-    than the set their window was collected under, bring no lanes, and
-    are verified whole under the live set (`sync.reverify`)."""
+    than the set their window was collected under, and come through the
+    window's pooled batch all the same, paired by address; the joiner's
+    lane alone is verified again."""
     n_blocks, grows_at = 14, 4
-
-    class GrowingChain(PlacedChain):
-        def _val_txs(self, h):
-            if self.change_at.get(h) != MEMBERSHIP:
-                return super()._val_txs(h)
-            new = self._standby.pop(0)
-            # nobody leaves; the member named first is the one whose
-            # signature departed_signs_for_joiner puts in the joiner's vote
-            self.joined_at[h] = (
-                self._state.validators.validators[0].pubkey, new)
-            return [val_tx(new, 1000)]
-
-    chain = GrowingChain(17, {grows_at: MEMBERSHIP, 9: STAKE},
-                         n_blocks=n_blocks)
+    chain = PlacedGrowChain(17, {grows_at: JOIN, 9: STAKE},
+                            n_blocks=n_blocks)
+    sizes = [chain.size_at[h] for h in range(1, n_blocks + 1)]
+    assert sizes == [N_VALS] * grows_at + [N_VALS + 1] * (n_blocks - grows_at)
+    verifier = default_verifier()
+    sigs = verifier.stats["sigs"]
     reactor, error = sync(chain, 4)
     assert error is None and reactor.state.last_block_height == n_blocks
     assert len(reactor.state.validators) == N_VALS + 1
+    # no block is verified whole again
     batched, again, used, lost = counts(recorder)
-    assert batched + again == n_blocks and again >= 3
-    heights = [e["req"] for e in events(recorder, "sync.reverify")]
-    assert len(heights) == again and heights == sorted(set(heights))
-    assert heights[0] == grows_at + 1
-    # every one of them one synchronous verify_commit
-    assert len(events(recorder, "commit.collect")) == again
+    assert (batched, again) == (n_blocks, 0)
+    assert events(recorder, "sync.reverify") == []
+    assert events(recorder, "commit.collect") == []
+    sigs = verifier.stats["sigs"] - sigs
     want, _sets, _apps = serial(chain)
     assert reactor.state.to_obj() == want.to_obj()
-    # a block verified whole had no lanes in its window
-    assert lost == 0 and used == grows_at * N_VALS + (
-        n_blocks - grows_at - again) * (N_VALS + 1)
+    # the blocks above the join whose window had not seen it
+    joiner = chain.joined_at[grows_at][1]
+    other_size = [h for h in range(1, n_blocks + 1)
+                  if len(collected_under[h]) != chain.size_at[h]]
+    assert other_size and other_size[0] == grows_at + 1
+    assert len(other_size) >= 3
+    assert resized_blocks(recorder) == len(other_size)
+    assert all(joiner not in {v.pubkey for v in collected_under[h].validators}
+               for h in other_size)
+    # every vote brought a lane, and the joiner's alone was lost
+    assert used + lost == sum(sizes) and lost == len(other_size)
+    assert sigs == sum(sizes) + lost
+    # the live judge, one event a block
+    assert judged(recorder) == {h: int(h in other_size)
+                                for h in range(1, n_blocks + 1)}
 
-    # and a forged signature there is refused at its height
-    at = heights[1]
+    # a forged signature there is refused at its height
+    at = other_size[1]
     wire = list(chain.wire)
     wire[at] = forge_precommit(wire[at], 2)
     reactor, error = sync(chain, 4, wire)
     assert error is None and stopped_at(reactor) == (at - 1, True)
-    # as is the joiner's vote signed by a key of the set below
+    # as is the joiner's vote signed by a member of the set below
     at, wire = departed_signs_for_joiner(chain, grows_at)
     reactor, error = sync(chain, 4, wire)
     assert error is None and stopped_at(reactor) == (at - 1, True)
+    assert events(recorder, "sync.reverify") == []
+
+
+RESIZING = {
+    "a_set_that_shrinks": (4, {5: LEAVE, 9: STAKE}),
+    "a_join_and_a_leave_inside_one_window": (8, {3: JOIN, 5: LEAVE}),
+    "grown_then_shrunk_past_its_windows": (
+        4, {2: JOIN, 3: JOIN, 4: JOIN, 13: LEAVE, 14: LEAVE, 15: LEAVE}),
+    "consecutive_joins_at_a_windows_edge": (4, {4: JOIN, 5: JOIN, 8: JOIN}),
+    "a_leave_below_the_last_block": (4, {N_BLOCKS - 1: LEAVE}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RESIZING))
+def test_a_set_that_changes_size_stays_on_the_pooled_path(
+        recorder, collected_under, case):
+    window, placed = RESIZING[case]
+    chain = PlacedGrowChain(18, placed)
+    reactor, error = sync(chain, window)
+    assert error is None and reactor.switch.stopped == []
+    assert reactor.state.last_block_height == N_BLOCKS
+    want, sets, apps = serial(chain)
+    assert reactor.state.to_obj() == want.to_obj()
+    ref = joinref.replay(chain.genesis_wire, chain.wire)
+    assert (ref.height, ref.refused_at) == (N_BLOCKS, None)
+    assert ref.validators_hashes == sets and ref.app_hashes == apps
+    heights = range(1, N_BLOCKS + 1)
+    assert [len(collected_under[h]) for h in heights] != \
+        [chain.size_at[h] for h in heights]
+    smaller = [h for h in heights
+               if len(collected_under[h]) < chain.size_at[h]]
+    larger = [h for h in heights
+              if len(collected_under[h]) > chain.size_at[h]]
+    batched, again, used, lost = counts(recorder)
+    assert (batched, again) == (N_BLOCKS, 0)
+    assert events(recorder, "sync.reverify") == []
+    assert resized_blocks(recorder) == len(smaller) + len(larger)
+    assert used + lost == sum(chain.size_at[h] for h in heights)
+    # a lane is lost where the live set holds a key its window's set
+    # did not: the joiners', never a leaver's
+    unseen = sum(
+        len({v.pubkey for v in store_set(reactor, h).validators}
+            - {v.pubkey for v in collected_under[h].validators})
+        for h in heights)
+    assert lost == unseen == sum(judged(recorder).values())
+    if case == "a_set_that_shrinks":
+        assert larger and not smaller and lost == 0
+    if case == "grown_then_shrunk_past_its_windows":
+        # a window collected under a set smaller than the commit, and
+        # one under a larger
+        assert smaller and larger
+    if case == "a_join_and_a_leave_inside_one_window":
+        # the joiner enters at the bottom, so it is the one that leaves:
+        # above the leave the set is the genesis set again
+        assert smaller == [4, 5] and not larger and lost == 2
+
+
+class SecpSigner:
+    """A secp256k1 key in the shape of `kvref.openssl_signer`'s keys,
+    for the chain builders: `.sign`, `.public_key().public_bytes_raw()`."""
+
+    def __init__(self, seed):
+        from tendermint_tpu.types.keys import Secp256k1PrivKey
+        self.key = Secp256k1PrivKey.generate(seed)
+        self.sign = self.key.sign
+
+    def public_key(self):
+        return self
+
+    def public_bytes_raw(self):
+        return self.key.pubkey.secp256k1
+
+
+def store_set(reactor, height):
+    return reactor.block_exec.state_store.load_validators(height)
+
+
+def test_a_leavers_slot_still_in_the_commit_is_refused_at_its_height(
+        recorder):
+    chain = PlacedGrowChain(19, {3: JOIN, 6: LEAVE, 9: STAKE})
+    at, wire = leaver_still_in_commit(chain, 6)
+    assert at == 7 and len(wire) == 8
+    reactor, error = sync(chain, 4, wire)
+    assert error is None and stopped_at(reactor) == (at - 1, True)
+    assert events(recorder, "sync.reverify") == []
+    ref = joinref.replay(chain.genesis_wire, wire)
+    assert (ref.height, ref.refused_at, ref.kind) == (
+        at - 1, at, joinref.COMMIT)
+
+
+@pytest.mark.parametrize("at", [5, 8, 11])
+def test_a_wrong_validator_address_costs_one_lane_and_no_verdict(
+        recorder, at):
+    """Above a change of size a vote is paired with the key its address
+    names. A vote that names another member's address is verified under
+    the wrong key, its lane is lost, and the live judge verifies it
+    again under the key of its slot: the commit is as good as before."""
+    from tendermint_tpu.types.block import Block
+    from tendermint_tpu.types.keys import address_of
+    chain = PlacedGrowChain(20, {4: JOIN, 9: JOIN})
+    # two members every collection set has seen
+    founders = {address_of(v.pubkey) for v in chain.gen.validators}
+    slot, other = [i for i, v in enumerate(
+        Block.from_bytes(chain.wire[at]).last_commit.precommits)
+        if v.validator_address in founders][:2]
+    claims = address_rewritten(chain, at, slot, other)
+    lost = {}
+    for name, wire in (("honest", chain.wire[:at + 1]), ("claims", claims)):
+        before = counts(recorder)
+        reactor, error = sync(chain, 4, wire)
+        assert error is None and stopped_at(reactor) == (at, False)
+        lost[name] = counts(recorder)[3] - before[3]
+    assert lost["claims"] == lost["honest"] + 1
+    assert joinref.replay(chain.genesis_wire, claims).height == at
+
+
+def test_a_secp256k1_member_keeps_the_set_on_the_pooled_path(
+        recorder, monkeypatch):
+    """A set with a key that is not Ed25519 travels as triples: paired
+    by address like columns, judged by the same judge."""
+    from benchmark import joinchain
+    made, openssl = [], joinchain.openssl_signer
+
+    def signer(seed):
+        made.append(seed)
+        return SecpSigner(seed) if len(made) == 2 else openssl(seed)
+    monkeypatch.setattr(joinchain, "openssl_signer", signer)
+    chain = PlacedGrowChain(21, {4: JOIN, 9: LEAVE, 12: STAKE, 15: JOIN})
+    assert sorted(len(v.pubkey) for v in chain.gen.validators) == \
+        [32] * (N_VALS - 1) + [33]
+    reactor, error = sync(chain, 4)
+    assert error is None and reactor.state.last_block_height == N_BLOCKS
+    want, _sets, _apps = serial(chain)
+    assert reactor.state.to_obj() == want.to_obj()
+    batched, again, used, lost = counts(recorder)
+    assert (batched, again) == (N_BLOCKS, 0) and lost > 0
+    assert used + lost == sum(chain.size_at[h]
+                              for h in range(1, N_BLOCKS + 1))
+    assert resized_blocks(recorder) > 0
+    assert events(recorder, "sync.reverify") == []
+    at = 6
+    wire = list(chain.wire)
+    wire[at] = forge_precommit(wire[at], 2)
+    reactor, error = sync(chain, 4, wire)
+    assert error is None and stopped_at(reactor) == (at - 1, True)
+
+
+def test_a_commit_no_set_would_take_is_heard_from_verify_commit(recorder):
+    """What is left of the `sync.reverify` branch: a vote that is no
+    precommit of its commit's round brings no lanes, and the block is
+    refused by verify_commit under the live set."""
+    from tendermint_tpu.types import encoding
+    from tendermint_tpu.types.block import Block
+    chain = PlacedGrowChain(22, {3: JOIN})
+    at = 6
+    wire = list(chain.wire[:at + 2])
+    blk = Block.from_bytes(wire[at])
+    blk.last_commit.precommits[2].round = 1
+    blk.header.last_commit_hash = blk.last_commit.hash()
+    wire[at] = encoding.cdumps(blk.to_obj())
+    reactor, error = sync(chain, 4, wire)
+    assert error is None and stopped_at(reactor) == (at - 1, True)
+    assert [e["req"] for e in events(recorder, "sync.reverify")] == [at]
+    assert counts(recorder)[:2] == (at - 1, 0)
 
 
 def copy_header_names_another_set(copy, height, reactor):
