@@ -45,7 +45,9 @@ INSTRUMENTED_MODULES = [
     "tendermint_tpu.blockchain.reactor",  # tm_sync_commits_total,
                                           # tm_sync_lanes_total,
                                           # tm_sync_live_judged_total,
-                                          # tm_sync_resized_total
+                                          # tm_sync_resized_total,
+                                          # tm_sync_repairs_total,
+                                          # tm_sync_repaired_lanes_total
     "tendermint_tpu.p2p.switch",
     "tendermint_tpu.p2p.conn.secret",    # tm_p2p_seal/open_seconds
     "tendermint_tpu.p2p.conn.mconn",     # tm_p2p_frames_per_burst

@@ -18,7 +18,9 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Optional
+from typing import NamedTuple, Optional
+
+import numpy as np
 
 from tendermint_tpu import telemetry
 from tendermint_tpu.p2p.base_reactor import Reactor
@@ -35,9 +37,10 @@ from tendermint_tpu.types.block import TXS_PATH, Block, BlockID
 # on a chain whose validator set never moves, and above a change of
 # stake, every block is "batched" and every lane "used"; above a join
 # the lanes "discarded" are the joiners', whose keys the window's set
-# did not hold, whether the set kept its size or grew; a block is
-# "reverified" only where its window had no lanes for it (see
-# _sync_window).
+# did not hold, whether the set kept its size or grew: each is verified
+# once more, in the join's one batch (a "repair") or, where that did
+# not foresee it, by the block's judge; a block is "reverified" only
+# where its window had no lanes for it (see _sync_window).
 _m_commits = telemetry.counter(
     "sync_commits_total",
     "Blocks fast-sync applied, by how their commit was judged: batched "
@@ -49,8 +52,9 @@ _m_lanes = telemetry.counter(
     "sync_lanes_total",
     "Signature lanes of a window's pooled batch, by what the apply loop "
     "did with their verdicts: used, or discarded and verified again "
-    "because the live set holds another key at the vote's slot (or the "
-    "whole block was reverified)", ("how",))
+    "because the live set holds another key at the vote's slot (by the "
+    "repair at the key's join or by the block's judge; or the whole "
+    "block was reverified)", ("how",))
 _m_live_judged = telemetry.counter(
     "sync_live_judged_total",
     "Blocks whose pooled verdicts fast-sync judged under a validator set "
@@ -61,6 +65,18 @@ _m_resized = telemetry.counter(
     "validator set their window was collected with (the set grew or "
     "shrank in between) and came with lanes all the same, paired by "
     "address", ())
+
+_m_repairs = telemetry.counter(
+    "sync_repairs_total",
+    "Blocks fast-sync applied that brought a key into force under an "
+    "address the validator set before did not hold (a join, or a "
+    "member replaced), each followed by one repair: the newcomers' "
+    "lanes in every block collected and not yet applied, verified under "
+    "their key in one batch", ())
+_m_repaired_lanes = telemetry.counter(
+    "sync_repaired_lanes_total",
+    "Signature lanes those repairs verified (each counts as discarded "
+    "in tm_sync_lanes_total when its block applies)", ())
 
 BLOCKCHAIN_CHANNEL = 0x40
 # the transactions of the block a block_response carries
@@ -85,6 +101,63 @@ VERIFY_WINDOW = 256               # blocks batched per device dispatch:
 #                                   only ever drains what the pool has,
 #                                   so the cap is free when fewer blocks
 #                                   are downloaded
+
+
+class _Collected(NamedTuple):
+    """One block of a window with what its collection kept: `lo` and
+    `n` are its lanes' place in the window's batch (`for_block` None and
+    n 0: no lanes), `strangers` the addresses its votes claim that the
+    collection set did not hold, each with its lane among the n
+    (ValidatorSet.rows_by_address), or None."""
+    block: object
+    parts: object
+    block_id: object
+    commit: object
+    for_block: object
+    lo: int
+    n: int
+    resized: bool
+    strangers: Optional[dict]
+
+
+class _Window:
+    """A collected window from its dispatch to its last applied block:
+    the blocks, the batch as it was verified (lane by lane, whatever
+    its form) and the verdicts, `ok`, None until they are fetched. A
+    repair writes into both; what it verified while the window was in
+    flight waits in `repairs` for the verdicts it replaces, and goes
+    with the window where that is dropped. `mended`: lanes a repair
+    replaced, by block (its index in `per_block`)."""
+
+    __slots__ = ("per_block", "items", "vs_hash", "part_size", "verdicts",
+                 "ok", "repairs", "mended")
+
+    def __init__(self, per_block, items, vs_hash, part_size, verdicts):
+        self.per_block, self.items = per_block, items
+        self.vs_hash, self.part_size = vs_hash, part_size
+        self.verdicts = verdicts        # the resolver thread's future
+        self.ok, self.repairs, self.mended = None, [], {}
+
+    def settle(self) -> None:
+        """Wait for the verdicts and lay the waiting repairs over
+        them."""
+        self.ok = np.array(self.verdicts.result(), np.bool_)
+        for lanes, verdicts in self.repairs:
+            self.ok[lanes] = verdicts
+        self.repairs = []
+
+    def lay(self, lanes: list, key: bytes, verdicts) -> None:
+        """`lanes` were verified under `key`, to `verdicts`: that pair
+        replaces each lane's."""
+        if isinstance(self.items, SigColumns):
+            self.items.pk[lanes] = np.frombuffer(key, np.uint8)
+        else:
+            for i in lanes:
+                self.items[i] = (key,) + self.items[i][1:]
+        if self.ok is None:
+            self.repairs.append((lanes, verdicts))
+        else:
+            self.ok[lanes] = verdicts
 
 
 class BlockchainReactor(Reactor):
@@ -128,14 +201,13 @@ class BlockchainReactor(Reactor):
         self._last_redial = 0.0
         self._no_peer_since: Optional[float] = None
         # one window in flight on the device while its predecessor
-        # applies on the host: (per_block, result_future, valset_hash,
-        # part_size) — see _sync_window. The window's verdicts are
-        # fetched on a single resolver thread (the blocking fetch
+        # applies on the host (see _sync_window). The window's verdicts
+        # are fetched on a single resolver thread (the blocking fetch
         # releases the GIL) while this thread applies the previous
         # window. How much of the overlap jax's own asynchronous
         # dispatch would give without the thread is not measured on the
         # attached chip.
-        self._pending_window = None
+        self._pending_window: Optional[_Window] = None
         self._resolver: Optional[ThreadPoolExecutor] = None
 
     def get_channels(self):
@@ -368,11 +440,13 @@ class BlockchainReactor(Reactor):
         another size (the set has grown or shrunk), a join or a leave
         has shifted the slots between the two addresses, so the vote is
         paired with the key this set holds for the vote's own address
-        (ValidatorSet.commit_lanes_by_address). Header and address are
-        untrusted and only hints for pairing: _apply_window keeps a
-        verdict for the key it was computed under and no other.
-        Returns None when fewer than 2 consecutive blocks are ready
-        there."""
+        (ValidatorSet.commit_lanes_by_address), and a vote whose address
+        this set does not hold is kept with its block by that address
+        (_Collected.strangers), for the join that brings its key
+        (_repair). Header and address are untrusted and only hints for
+        pairing: _apply_window keeps a verdict for the key it was
+        computed under and no other. Returns None when fewer than 2
+        consecutive blocks are ready there."""
         blocks = self.pool.peek_window(self.verify_window, skip=skip)
         if len(blocks) < 2:
             return None
@@ -383,13 +457,13 @@ class BlockchainReactor(Reactor):
             self.state.consensus_params.block_gossip.block_part_size_bytes
         batches = []
         lo = 0
-        per_block = []  # (block, parts, block_id, commit,
-        #                  for_block|None, lo, lanes, resized)
+        per_block = []
         for i in range(len(blocks) - 1):
             block, commit = blocks[i], blocks[i + 1].last_commit
             parts, block_id = self._parts_and_id(block)
             height = block.header.height
             resized = len(commit.precommits) != len(batch_valset)
+            strangers = None
             try:
                 if block.header.validators_hash == vs_hash and not resized:
                     items, item_power = \
@@ -397,36 +471,39 @@ class BlockchainReactor(Reactor):
                             chain_id, block_id, height, commit)
                     for_block = item_power.for_block
                 else:
+                    strangers = {}
                     items, for_block = batch_valset.commit_lanes_by_address(
-                        chain_id, block_id, height, commit)
+                        chain_id, block_id, height, commit, strangers)
             except ValueError:
                 # a commit no set would take (a vote that is no
                 # precommit of this height and round): no lanes; the
                 # apply loop hears it from verify_commit under the live
                 # set (`sync.reverify`) and punishes the peer there
-                per_block.append((block, parts, block_id, commit,
-                                  None, 0, (), resized))
+                per_block.append(_Collected(block, parts, block_id, commit,
+                                            None, 0, 0, resized, None))
                 continue
-            per_block.append((block, parts, block_id, commit,
-                              for_block, lo, items, resized))
+            per_block.append(_Collected(block, parts, block_id, commit,
+                                        for_block, lo, len(items), resized,
+                                        strangers or None))
             lo += len(items)
             batches.append(items)
         return per_block, SigColumns.concat(batches), vs_hash, part_size
 
-    def _apply_window(self, per_block, ok, batch_valset_hash,
-                      part_size) -> int:
+    def _apply_window(self, window: _Window) -> int:
         """Store + apply one verified window in order; returns how many
-        blocks were applied (< len(per_block) when a bad block stopped
-        the window). A block's pooled verdicts are judged under the set
-        in force NOW, lane by lane, by the key each lane was verified
-        under (ValidatorSet.check_commit_lanes): exactly verify_commit
-        under the live set, whatever set the window was collected
-        with."""
+        blocks were applied (< len(window.per_block) when a bad block
+        stopped the window). A block's pooled verdicts are judged under
+        the set in force NOW, lane by lane, by the key each lane was
+        verified under (ValidatorSet.check_commit_lanes): exactly
+        verify_commit under the live set, whatever set the window was
+        collected with. A block that brings a new key into force has
+        the lanes waiting for that key verified before the next block
+        is judged (_repair)."""
         chain_id = self.state.chain_id
         verifier = self._verifier()
         applied = 0
-        for block, parts, block_id, commit, for_block, lo, lanes, resized \
-                in per_block:
+        for at, (block, parts, block_id, commit, for_block, lo, n, resized,
+                 _strangers) in enumerate(window.per_block):
             if block.header.height != self.block_store.height() + 1:
                 # the window no longer lines up with the store (a
                 # predecessor window was cut short): discard the rest
@@ -434,7 +511,7 @@ class BlockchainReactor(Reactor):
             ps_now = (self.state.consensus_params
                       .block_gossip.block_part_size_bytes)
             pooled = for_block is not None
-            if ps_now != part_size:
+            if ps_now != window.part_size:
                 # consensus params changed inside the pipeline window:
                 # the pre-built part set used the stale size — rebuild,
                 # and DISCARD the batched results too (their
@@ -444,13 +521,14 @@ class BlockchainReactor(Reactor):
                 pooled = False
             vs_now = self.state.validators
             height = block.header.height
-            n = again = len(lanes)
+            again = n
             try:
                 if pooled:
                     t_judge = time.perf_counter() \
                         if telemetry.enabled() else 0.0
                     again = vs_now.check_commit_lanes(
-                        commit, lanes, ok[lo:lo + n], for_block, verifier)
+                        commit, window.items[lo:lo + n],
+                        window.ok[lo:lo + n], for_block, verifier)
                     if t_judge:
                         trace.complete("sync.judge", t_judge,
                                        time.perf_counter(), req=height,
@@ -479,20 +557,78 @@ class BlockchainReactor(Reactor):
             self.pool.pop_request()
             applied += 1
             _m_commits.labels("batched" if pooled else "reverified").inc()
-            if n > again:
-                _m_lanes.labels("used").inc(n - again)
-            if again:
-                _m_lanes.labels("discarded").inc(again)
-            if pooled and vs_now.hash() != batch_valset_hash:
+            # a lane whose window verdict was replaced is lost once,
+            # whoever replaced it (one that a repair AND the judge
+            # replaced, a vote that claims a joiner's address from
+            # another member's slot, is still one lane of its n)
+            lost = min(n, again + window.mended.pop(at, 0))
+            if n > lost:
+                _m_lanes.labels("used").inc(n - lost)
+            if lost:
+                _m_lanes.labels("discarded").inc(lost)
+            if pooled and vs_now.hash() != window.vs_hash:
                 _m_live_judged.inc()
             if pooled and resized:
                 _m_resized.inc()
+            joined = self.state.validators.joined_since(vs_now)
+            if joined:
+                self._repair(height, joined, window, at + 1)
             if self.after_apply is not None:
                 # recovery plane: interval snapshots + pruning fire on
                 # the sync path too (the app sits at exactly this
                 # height until the next iteration applies)
                 self.after_apply(self.state)
         return applied
+
+    def _repair(self, height: int, joined: list, window: _Window,
+                after: int) -> None:
+        """Repair at the join: block `height` brought the keys of
+        `joined` into force, and every lane already collected for one
+        of their addresses and not yet applied (`window` from block
+        `after` on, and the whole window in flight) was verified under
+        a placeholder key, because its collection set did not hold the
+        address. All of them are verified under the live key now, in
+        ONE call on the verifier, whose own routing says where (a batch
+        of some hundreds of lanes is the device's, a chain's last few
+        the host's), and key and verdict replace each lane's: the judge
+        of those blocks finds the lanes under the key its set holds and
+        verifies nothing again. A pair is only ever replaced by a pair
+        that was verified: a bad signature carries False to its block
+        and is refused there, by check_commit_lanes, which stays the
+        judge of everything and verifies itself what this did not
+        foresee (an address that a vote merely claims, a key that does
+        not fit the batch's columns)."""
+        t0 = time.perf_counter() if telemetry.enabled() else 0.0
+        batch, found, blocks = [], [], set()
+        for w, first in ((window, after), (self._pending_window, 0)):
+            if w is None:
+                continue
+            for v in joined:
+                key, address = v.pubkey, v.address
+                if isinstance(w.items, SigColumns) and \
+                        len(key) != w.items.pk.shape[1]:
+                    continue
+                lanes = []
+                for at in range(first, len(w.per_block)):
+                    entry = w.per_block[at]
+                    if entry.strangers and address in entry.strangers:
+                        lanes.append(entry.lo + entry.strangers.pop(address))
+                        w.mended[at] = w.mended.get(at, 0) + 1
+                        blocks.add(entry.block.header.height)
+                if lanes:
+                    found.append((w, lanes, key))
+                    batch += [(key,) + w.items[i][1:] for i in lanes]
+        if batch:
+            ok = self._verifier().verify(batch)
+            lo = 0
+            for w, lanes, key in found:
+                w.lay(lanes, key, ok[lo:lo + len(lanes)])
+                lo += len(lanes)
+        _m_repairs.inc()
+        _m_repaired_lanes.inc(len(batch))
+        if t0:
+            trace.complete("sync.repair", t0, time.perf_counter(),
+                           req=height, lanes=len(batch), blocks=len(blocks))
 
     def _sync_window(self) -> bool:
         """PIPELINED window sync: collect window k and dispatch its ONE
@@ -508,21 +644,27 @@ class BlockchainReactor(Reactor):
         a signature is valid over its sign-bytes under ONE key, and
         neither depends on the validator set, so _apply_window keeps
         the verdict of every lane whose key the live set holds at the
-        vote's slot, tallies with the live stake, and verifies again,
-        scalar on the host and in one call a block, only the lanes
-        under another key: the joiners the collection set had never
-        seen. A change of stake discards nothing, and a set that grows
-        or shrinks nothing but those lanes either: a commit of another
-        size than the collection set's is paired by address like any
-        other (tm_sync_resized_total). Only a commit no set would take
-        and a block whose part set was rebuilt have no lanes and are
-        verified whole at apply, one synchronous verify_commit
-        (`sync.reverify`). tm_sync_commits_total{how}, tm_sync_lanes_
-        total{how} and tm_sync_live_judged_total count all of it.
-        Returns True on progress.
+        vote's slot and tallies with the live stake. The lanes under
+        another key are the joiners', whom the collection set had never
+        seen, and a joiner's lanes are verified when its key comes into
+        force: the block that brings the key has every lane collected
+        for its address, in the rest of its window and in the window in
+        flight, verified in one batch of the verifier's routing
+        (_repair, `sync.repair`, tm_sync_repairs_total), so a newcomer
+        costs one call and not one in every block until its windows
+        end. What a repair did not foresee, the block's judge verifies
+        again, scalar on the host. A change of stake discards nothing,
+        and a set that grows or shrinks nothing but those lanes either:
+        a commit of another size than the collection set's is paired by
+        address like any other (tm_sync_resized_total). Only a commit
+        no set would take and a block whose part set was rebuilt have
+        no lanes and are verified whole at apply, one synchronous
+        verify_commit (`sync.reverify`). tm_sync_commits_total{how},
+        tm_sync_lanes_total{how} and tm_sync_live_judged_total count
+        all of it. Returns True on progress.
         """
         pending = self._pending_window
-        skip = 0 if pending is None else max(0, len(pending[0]))
+        skip = 0 if pending is None else len(pending.per_block)
         with trace.span("sync.collect", req=self.pool.height + skip):
             collected = self._collect_window(skip)
 
@@ -547,28 +689,29 @@ class BlockchainReactor(Reactor):
             fut = resolver.submit(resolve)
         except RuntimeError:  # shutdown raced the submit
             return False
-        self._pending_window = (per_block, fut, vs_hash, psz)
+        self._pending_window = _Window(per_block, all_items, vs_hash, psz,
+                                       fut)
         progress = False
         if pending is not None:
             applied = self._settle_window(pending)
             progress = applied > 0
-            if applied < len(pending[0]):
+            if applied < len(pending.per_block):
                 # the window was cut short (bad block -> punish + redo):
                 # the in-flight successor sits past a gap of re-requested
                 # heights and may hold blocks from the punished peer —
-                # drop it and re-collect once the pool recovers
+                # drop it, and what was repaired in it, and re-collect
+                # once the pool recovers
                 self._pending_window = None
         return progress or self._pending_window is not None
 
-    def _settle_window(self, pending) -> int:
+    def _settle_window(self, window: _Window) -> int:
         """Wait for a dispatched window's verdicts, then store + apply
         it; returns how many blocks were applied."""
-        per_block, fut, vs_hash, psz = pending
-        req = per_block[0][0].header.height     # the window's first
+        req = window.per_block[0].block.header.height   # the window's first
         with trace.span("sync.wait", req=req):
-            ok = fut.result()
+            window.settle()
         with trace.span("sync.apply", req=req):
-            return self._apply_window(per_block, ok, vs_hash, psz)
+            return self._apply_window(window)
 
     def _punish_bad_window(self, height: int) -> None:
         for peer_id in self.pool.redo_request(height):

@@ -184,7 +184,7 @@ def _dispatch(variant: str, mesh, *args):
     """Hand one padded batch (host arrays) to the device and enqueue
     it on the kernel its shape selects, counted; the transfers and the
     jitted call are the `verify.enqueue` span.
-    variant: 'full' | 'pre' | 'decompress'. The fused Pallas
+    variant: 'full' | 'pre'. The fused Pallas
     kernel takes every batch whose per-device rows fill its 512 tile
     on a TPU; everything else (CPU backends, interactive sizes where
     kernel choice barely matters) takes the jnp ladder. With a mesh
@@ -192,9 +192,7 @@ def _dispatch(variant: str, mesh, *args):
     rows = args[0].shape[0]
     ndev = 1 if mesh is None else mesh.devices.size
     local = rows // ndev
-    if variant == "decompress":
-        fn, name = _decompress_to_bytes, "decompress"
-    elif _pallas_available() and local >= 512 and local % 512 == 0:
+    if _pallas_available() and local >= 512 and local % 512 == 0:
         fn, name = ((_verify_pre_pallas, "pallas_pre") if variant == "pre"
                     else (_verify_from_bytes_pallas, "pallas_full"))
     else:
@@ -266,8 +264,10 @@ _PREDECOMP_MIN_BATCH = 64
 # chunk once.
 _PREDECOMP_MEMO_MAX = 8
 _predecomp_memo: "OrderedDict[bytes, tuple]" = OrderedDict()
-# pubkeys sighted once (first sighting stays on the fused full kernel:
-# a one-shot batch must not pay a separate decompress dispatch)
+# pubkeys sighted once (a first sighting of keys that each fill one lane
+# stays on the fused full kernel: a one-shot batch must not pay for
+# decompressing them apart; a window's chunk, which shows each key in
+# many lanes, fills at once, so a chain's sync compiles no full kernel)
 _predecomp_seen: "OrderedDict[bytes, bool]" = OrderedDict()
 
 
@@ -387,7 +387,9 @@ _predecomp = _KeyTable()
 #
 # The same dict counts every device dispatch by the kernel that served
 # it (a sharded dispatch whose per-shard body is the jnp ladder counts
-# as mesh_jnp, sign_scalar counts host-signed batches), and keeps under
+# as mesh_jnp, sign_scalar counts host-signed batches; `decompress`
+# stays 0 since a fill decompresses on the host, and stays a key for
+# the readers that ask for it), and keeps under
 # first_call_s, per "program[rows]" or "program[rows/devices]", the
 # seconds the FIRST dispatch of that shape spent inside the jit call:
 # trace + lower + compile, or the persistent-cache load. Later
@@ -457,10 +459,46 @@ def predecomp_stats() -> dict:
 
 @jax.jit
 def _decompress_to_bytes(pk_u8):
-    """One-time per valset batch: (-A).x and A.y as canonical field
-    bytes + validity mask (inputs to the *_pre kernels)."""
+    """(-A).x and A.y as canonical field bytes + validity mask (inputs
+    to the *_pre kernels), by the device's own field arithmetic: the
+    statement that _decompress_keys is held to, row for row."""
     (x, y, _one, _t), ok = curve.decompress(pk_u8)
     return fe.to_bytes(fe.neg(x)), fe.to_bytes(y), ok
+
+
+def _decompress_keys(keys: np.ndarray):
+    """keys u8[k,32] -> ((-A).x u8[k,32], A.y u8[k,32], ok bool[k]):
+    what _decompress_to_bytes gives, bit for bit (points and
+    non-points, y >= p, x = 0 under the sign bit), in Python integers
+    on the host, a seventh of a millisecond a key. A fill decompresses
+    the keys it stores and nothing else, and with it a batch can fill
+    at its first sighting (_predecomp_rows): as a device program the
+    decompression took a whole padded batch, a dispatch and a fetch,
+    and a compile of two seconds a batch shape."""
+    p, d, sqrt_m1 = fe.P, fe.D_INT, fe.SQRT_M1_INT
+    low = (1 << 255) - 1
+    xneg, ys, oks = [], [], []
+    for raw in map(bytes, keys):
+        n = int.from_bytes(raw, "little")
+        sign, y = n >> 255, (n & low) % p
+        y2 = y * y % p
+        u, v = (y2 - 1) % p, (y2 * d + 1) % p
+        v3 = v * v % p * v % p
+        x = u * v3 % p * pow(u * v3 % p * v3 % p * v % p,
+                             (p - 5) // 8, p) % p
+        check = v * x % p * x % p
+        flipped = check == (p - u) % p
+        if flipped:
+            x = x * sqrt_m1 % p
+        ok = (check == u or flipped) and not (x == 0 and sign == 1)
+        if x & 1 != sign:
+            x = (p - x) % p
+        xneg.append(((p - x) % p).to_bytes(32, "little"))
+        ys.append(y.to_bytes(32, "little"))
+        oks.append(ok)
+    as_rows = lambda rows: np.frombuffer(     # noqa: E731
+        b"".join(rows), np.uint8).reshape(-1, 32)
+    return as_rows(xneg), as_rows(ys), np.array(oks, np.bool_)
 
 
 def _rows_at(rows, idx):
@@ -522,10 +560,13 @@ def _table_rows(idx, mesh):
 
 def _predecomp_rows(pk_np, mesh):
     """The batch's keys' rows as _table_rows hands them over (mirror and
-    slots, or rows), or None when its pubkeys are mostly fresh (a first-sighting
-    batch must not pay the extra decompress dispatch — it takes the
-    fused full kernel while its keys are marked seen; any later batch
-    made of seen keys decompresses ONCE and fills the table). A batch
+    slots, or rows), or None when it takes the fused full kernel: a first
+    sighting of keys that fill one lane each (a one-shot batch must not
+    pay for decompressing keys it may never show again; they are marked
+    seen). Any later batch made of seen keys, and any batch that shows
+    its missing keys in several lanes (a window's chunk, a joiner's
+    lanes), decompresses them ONCE, on the host (_decompress_keys), and
+    fills the table. A batch
     whose whole key sequence was resolved before gets the same slots
     again, read-only and shared between dispatches."""
     raw = pk_np.tobytes()
@@ -551,32 +592,35 @@ def _predecomp_rows(pk_np, mesh):
         while len(_predecomp_seen) > 4 * _PREDECOMP_MAX_KEYS:
             _predecomp_seen.popitem(last=False)
         distinct = new.shape[0] + np.unique(idx[~miss]).size
-        if fresh or distinct > _predecomp.keys.shape[0]:
-            # unseen keys in the batch: fused full kernel (no extra
-            # dispatch); the NEXT batch over these keys fills rows. So
-            # does a batch of more keys than the table has slots
+        # a batch that shows a missing key in several lanes is repeat
+        # traffic within itself: the full kernel would decompress that
+        # key once a lane, the host does it once
+        repeated = int(miss.sum()) > new.shape[0]
+        if (fresh and not repeated) or distinct > _predecomp.keys.shape[0]:
+            # unseen keys, each in one lane: fused full kernel (nothing
+            # decompressed that may never show again); the NEXT batch
+            # over these keys fills rows. So does a batch of more keys
+            # than the table has slots
             _predecomp_note("full")
             return None
         _predecomp_note("fill", how="built")
-    # repeat traffic over keys that are not resident: decompress the
-    # whole batch once (outside the lock — device dispatch) and store
-    # the rows of those still missing (a concurrent fill of the same
-    # keys is harmless: what it stored is found, not stored twice)
-    xnb_d, yb_d, ok_d = _dispatch("decompress", mesh, pk_np)
-    xnb_h = np.asarray(xnb_d)
-    yb_h = np.asarray(yb_d)
-    ok_h = np.asarray(ok_d)
+    # repeat traffic over keys that are not resident: decompress them
+    # once (outside the lock) and store the rows of those still missing
+    # (a concurrent fill of the same keys is harmless: what it stored
+    # is found, not stored twice)
+    rows = _decompress_keys(new)
     with _predecomp_lock:
         idx, miss, _ = _predecomp.lookup(pk_np)
         # the batch's resident keys first, so that none of them is put
         # out for one of its others
         _predecomp.touch(idx[~miss])
-        lanes = np.flatnonzero(miss)
-        if lanes.size:
-            lanes = lanes[np.unique(pk_np[lanes], axis=0,
-                                    return_index=True)[1]]
-            evicted = _predecomp.insert(pk_np[lanes], xnb_h[lanes],
-                                        yb_h[lanes], ok_h[lanes])
+        if miss.any():
+            # sorted as `new` is: the same keys, unless another fill or
+            # an eviction moved the table in between
+            missing = np.unique(pk_np[miss], axis=0)
+            if not np.array_equal(missing, new):
+                rows = _decompress_keys(missing)
+            evicted = _predecomp.insert(missing, *rows)
             if evicted:
                 _predecomp_note("evict", evicted)
             idx, _, _ = _predecomp.lookup(pk_np)

@@ -103,6 +103,14 @@ SPANS = {
     # compared, the lanes under another key verified again, the live
     # stake tallied; req = the height, `again` = lanes verified again)
     "sync.judge": "verifier",
+    # one event per applied block that brought a key into force under
+    # an address the set before did not hold (BlockchainReactor._repair:
+    # the newcomers' lanes in the blocks collected and not yet applied,
+    # verified under that key in one verifier call, verify.* nest in
+    # it; req = the height that brought the key, `lanes` = lanes
+    # verified, `blocks` = blocks they lie in). What it missed shows as
+    # `again` in those blocks' sync.judge
+    "sync.repair": "verifier",
     "wire.decode_block": "sync window engine",
     "apply.validate": "apply and Merkle",   # incl. the data hash again
     "apply.exec": "apply and Merkle",

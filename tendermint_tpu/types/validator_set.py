@@ -387,7 +387,7 @@ class ValidatorSet:
         return items, CommitPower(powers, for_block, tally)
 
     def commit_lanes_by_address(self, chain_id: str, block_id,
-                                height: int, commit):
+                                height: int, commit, strangers=None):
         """Collect phase for a commit that is NOT this set's to judge:
         fast-sync's window is collected under the set held then, and a
         block's own set, the one in force when it applies, may hold
@@ -397,12 +397,12 @@ class ValidatorSet:
         stake: `(items, for_block)`, one lane per vote that is there,
         in order, each under the key of `rows_by_address`; `items` in
         commit_verification_items' two forms, `for_block` the flags its
-        CommitPower carries. What judges them is check_commit_lanes
-        under the set in force, which takes a verdict only for the key
-        it was computed under."""
+        CommitPower carries; `strangers` is `rows_by_address`'s. What
+        judges them is check_commit_lanes under the set in force, which
+        takes a verdict only for the key it was computed under."""
         sigs, msgs, idx, for_block, _absent, _all_for = self._walk_commit(
             chain_id, block_id, height, commit)
-        rows = self.rows_by_address(commit)
+        rows = self.rows_by_address(commit, strangers)
         cols = self.columns()
         if cols.pk is not None:
             return SigColumns(cols.pk[rows], sigs, msgs, idx), for_block
@@ -427,7 +427,7 @@ class ValidatorSet:
             raise ValueError(
                 f"insufficient voting power: {power_for_block}/{total}")
 
-    def rows_by_address(self, commit) -> list:
+    def rows_by_address(self, commit, strangers=None) -> list:
         """For each vote `commit` holds, in order, the slot at which
         THIS set has the vote's `validator_address`: the rows of
         `columns().pk` under which a set that is not the commit's own
@@ -437,13 +437,25 @@ class ValidatorSet:
         commit brings one lane a vote whatever set pairs it: the vote's
         own slot, or this set's last where the commit is the larger.
         That lane's verdict is for a key the vote's set does not hold
-        there, and check_commit_lanes verifies the vote again. The
-        address is the vote's claim and only chooses the key that is
-        tried: check_commit_lanes believes a verdict for the key it was
-        computed under and for no other."""
+        there, and check_commit_lanes verifies the vote again, unless
+        its caller has by then: a dict given as `strangers` is told
+        each such address and the lane that claims it (the last, where
+        two do), for whoever learns the address's key before the commit
+        is judged. The address is the vote's claim and only chooses the
+        key that is tried: check_commit_lanes believes a verdict for
+        the key it was computed under and for no other."""
         known, last = self._index.get, len(self.validators) - 1
-        return [known(pc.validator_address, min(i, last))
-                for i, pc in enumerate(commit.precommits) if pc is not None]
+        rows = []
+        for i, pc in enumerate(commit.precommits):
+            if pc is None:
+                continue
+            row = known(pc.validator_address)
+            if row is None:
+                row = min(i, last)
+                if strangers is not None:
+                    strangers[pc.validator_address] = len(rows)
+            rows.append(row)
+        return rows
 
     def check_commit_lanes(self, commit, lanes, ok, for_block,
                            verifier) -> int:
@@ -492,6 +504,17 @@ class ValidatorSet:
             else sum(powers[for_block].tolist())
         self.check_commit_results(ok, CommitPower(powers, for_block, tally))
         return len(stale)
+
+    def joined_since(self, before: "ValidatorSet") -> list:
+        """The members of this set under an address that `before` does
+        not hold: who joined, or took a member's place, on the way from
+        `before` to this set. A set that shares `before`'s members (its
+        copy, as apply_block hands on a set no update touched) answers
+        from one identity comparison."""
+        if self._index is before._index:
+            return []
+        return [self.validators[self._index[a]]
+                for a in self._index.keys() - before._index.keys()]
 
     def endorsement(self, signing: "ValidatorSet", chain_id: str,
                     block_id, commit):

@@ -60,47 +60,20 @@ def pytest_configure(config):
 
 
 # Tests of tests/benchrec/ that a later PR outdated, and only a
-# `benchmark` PR may edit a file under tests/benchrec/. Two pin where
-# BENCHMARK.json's append-only lists ended the day they were written, so
-# each raises AssertionError since a later PR appended what its issue
-# asked for. Two hold the join cell to PR 42's window engine, which
-# threw a window's verdicts away once the set's hash moved; since PR 43
-# a verdict is judged by its key under the set in force. Strict, so
-# none can go unnoticed: the day such a test is repaired it passes,
-# this marker fails the run, and its line goes. Every assertion of a
-# marked test that still holds is made again in the test named beside
-# it. suffix of the node id -> (why, what it raises now).
+# `benchmark` PR may edit a file under tests/benchrec/. Strict, so none
+# can go unnoticed: the day such a test is repaired it passes, this
+# marker fails the run, and its line goes. Every assertion of a marked
+# test is made again, as it holds now, in the test named beside it.
+# suffix of the node id -> (why, what it raises now).
 _OUTDATED_BY_A_LATER_PR = {
-    # per_layer[-1] is PR 25's entry; entries follow it since PR 26
-    # (tests/benchrec/test_benchrec_verify_commit.py::
-    # test_the_entries_before_this_cell_are_as_they_were)
-    "test_benchrec_predecomp_reuse.py::"
-    "test_the_entry_is_appended_for_the_lite_cell_alone":
-        ("looks at per_layer[-1]; entries follow it now", AssertionError),
-    # the per-layer metrics of commit_10kv.verify_commit are exactly PR
-    # 26's; PR 27's `columns_share` lists the cell too
-    # (tests/test_columns_metrics.py::
-    # test_the_single_commit_cell_keeps_its_metrics_and_gains_one)
-    "test_benchrec_verify_commit.py::"
-    "test_the_cell_and_its_metrics_are_declared":
-        ("holds the cell's per-layer metrics to PR 26's set",
+    # holds the grow cell's per-layer names to PR 47's exact set; PR
+    # 48's `grow_repair_share` lists the cell too
+    # (tests/benchrec/test_benchrec_grow_repair.py::
+    # test_the_grow_cells_per_layer_metrics_are_these_and_no_namesake)
+    "test_benchrec_grow.py::"
+    "test_the_cells_per_layer_metrics_are_these_and_no_namesake":
+        ("holds the grow cell's per-layer metrics to PR 47's set",
          AssertionError),
-    # wants 60-100% of the blocks verified whole a second time and the
-    # three `commit.*` legs above 0; nothing is re-verified whole now
-    # (tests/test_join_metrics.py::
-    # test_the_traced_rehearsal_reports_every_metric_and_the_live_judge)
-    "test_benchrec_join.py::"
-    "test_the_traced_rehearsal_reports_every_new_metric":
-        ("holds the join cell to a second verify of every block",
-         AssertionError),
-    # wants a node whose window names any set to fail its warm pass:
-    # the hash guards nothing now, the keys do (tests/test_live_judge.py::
-    # test_a_judge_that_takes_a_lanes_key_on_trust_does_not_get_through
-    # and ::test_the_windows_hash_guards_nothing)
-    "test_benchrec_join.py::"
-    "test_a_node_that_keeps_stale_verdicts_does_not_get_through_a_join":
-        ("swaps the window's set hash, which no verdict hangs on now",
-         pytest.fail.Exception),
 }
 
 

@@ -70,6 +70,69 @@ def test_decompress_valid_and_invalid():
             assert enc[i].tobytes() == c
 
 
+def _edge_encodings():
+    """y at and around 0, 1, p and 2**255, each with and without the
+    sign bit: y >= p, x = 0 under the sign bit, the largest encoding."""
+    p = (1 << 255) - 19
+    return [(y | (sign << 255)).to_bytes(32, "little")
+            for y in (0, 1, 2, p - 2, p - 1, p, p + 1, p + 2, p + 18,
+                      (1 << 255) - 1)
+            for sign in (0, 1)]
+
+
+@pytest.mark.parametrize("keys", [
+    "public_keys", "random_strings", "edge_encodings"])
+def test_the_hosts_decompression_is_the_devices_row_for_row(keys):
+    """A fill stores what _decompress_keys computes in Python integers;
+    the *_pre kernels were written against _decompress_to_bytes, the
+    device's field arithmetic. Equal to the byte, valid or not."""
+    made = {"public_keys": [ref.public_key(s) for s in seeds(24)],
+            "random_strings": [rng.randbytes(32) for _ in range(200)],
+            "edge_encodings": _edge_encodings()}[keys]
+    pk = np.stack([np.frombuffer(k, np.uint8) for k in made])
+    want = [np.asarray(a) for a in ed25519._decompress_to_bytes(pk)]
+    got = ed25519._decompress_keys(pk)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+    valid = want[2].tolist()
+    assert valid == [ref.point_decompress(k) is not None for k in made]
+    if keys == "public_keys":
+        assert all(valid)
+    else:
+        assert 0 < sum(valid) < len(valid)
+    assert [a.shape for a in ed25519._decompress_keys(pk[:0])] == \
+        [(0, 32), (0, 32), (0,)]
+
+
+def test_a_batch_that_repeats_its_unseen_keys_fills_at_once():
+    """The full kernel decompresses a key once a lane, the host once a
+    key: a first sighting goes to the full kernel only where every
+    missing key fills one lane (a commit of distinct keys); a window's
+    chunk or a joiner's lanes, one key in many lanes, fill the table at
+    their first sighting and take the predecompressed kernel, so a
+    chain's sync dispatches no full kernel at all."""
+    keys = [ref.public_key(s) for s in seeds(8)]
+    once = np.stack([np.frombuffer(k, np.uint8) for k in keys])
+    with predecomp_sandbox(min_batch=8):
+        s0 = ed25519.predecomp_stats()
+        assert ed25519._predecomp_rows(once, None) is None
+        s1 = ed25519.predecomp_stats()
+        assert (s1["full"], s1["fill"]) == (s0["full"] + 1, s0["fill"])
+        assert ed25519._predecomp_rows(once, None) is not None
+    with predecomp_sandbox(min_batch=8):
+        joiner = np.repeat(once[:1], 8, axis=0)         # one key, 8 lanes
+        s0 = ed25519.predecomp_stats()
+        handed = ed25519._predecomp_rows(joiner, None)
+        s1 = ed25519.predecomp_stats()
+        assert handed is not None
+        assert (s1["full"], s1["fill"]) == (s0["full"], s0["fill"] + 1)
+        chunk = np.concatenate([once, once])            # each key twice
+        assert ed25519._predecomp_rows(chunk, None) is not None
+        assert ed25519.predecomp_stats()["full"] == s0["full"]
+        assert ed25519.predecomp_stats()["keys"] == 8
+
+
 def test_verify_batch_good_and_bad():
     from bench_util import fast_signer, scalar_verify_one
     sds = seeds(6)
@@ -675,8 +738,14 @@ def test_rows_by_index_equal_decompress_row_for_row(layout):
     distinct = sorted(set(keys))
     small = as_rows(distinct + [bytes(32)] * (-len(distinct) % 128))
     with predecomp_sandbox(min_batch=64):
-        assert ed25519._predecomp_rows(small, None) is None     # sighted
-        filled = ed25519._predecomp_rows(small, None)           # fill
+        # sighted, then filled; or filled at once, where the padding
+        # shows the zero key in several lanes
+        repeats = small.shape[0] > len(
+            {small[i].tobytes() for i in range(small.shape[0])})
+        filled = ed25519._predecomp_rows(small, None)
+        assert (filled is None) == (not repeats)
+        if filled is None:
+            filled = ed25519._predecomp_rows(small, None)
         assert ed25519.predecomp_stats()["keys"] == len(
             {small[i].tobytes() for i in range(small.shape[0])})
         want = [np.asarray(a) for a in ed25519._decompress_to_bytes(small)]
@@ -770,15 +839,14 @@ def test_slots_and_mirror_of_one_dispatch_are_of_one_table_under_fills(
     """Threads asking for overlapping key sets through a table too small
     for all of them, so that fills and evictions run beside the lookups:
     whatever a call is handed, its mirror gathered at its slots is its
-    keys' rows. The decompress dispatch is a stand-in (rows by
-    row_of); the cache layer is under test."""
+    keys' rows. The decompression is a stand-in (rows by row_of); the
+    cache layer is under test."""
     pool = valset(40, tag=11)
     sets = [pool[i:i + 16] for i in (0, 8, 16, 24)]
     n_threads, rounds = 8, 40
     errors, served = [], []
 
-    def fake_dispatch(variant, mesh, pk):
-        assert variant == "decompress"
+    def fake_decompress(pk):
         rows = [row_of(pk[i].tobytes()) for i in range(pk.shape[0])]
         return (as_rows([r[0] for r in rows]), as_rows([r[1] for r in rows]),
                 np.array([r[2] for r in rows]))
@@ -794,7 +862,7 @@ def test_slots_and_mirror_of_one_dispatch_are_of_one_table_under_fills(
         except BaseException as e:  # noqa: BLE001 - reported below
             errors.append(e)
 
-    monkeypatch.setattr(ed25519, "_dispatch", fake_dispatch)
+    monkeypatch.setattr(ed25519, "_decompress_keys", fake_decompress)
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:

@@ -363,6 +363,13 @@ def judged(telemetry):
             for e in events(telemetry, "sync.judge")}
 
 
+def repaired(telemetry):
+    """{the height that brought a key: lanes verified for it} of the
+    `sync.repair` events."""
+    return {e["req"]: e["args"]["lanes"]
+            for e in events(telemetry, "sync.repair")}
+
+
 def test_a_commit_of_another_size_than_the_windows_set_comes_with_lanes(
         recorder, collected_under):
     """A set that grows: the commits above the join have one vote more
@@ -399,9 +406,10 @@ def test_a_commit_of_another_size_than_the_windows_set_comes_with_lanes(
     # every vote brought a lane, and the joiner's alone was lost
     assert used + lost == sum(sizes) and lost == len(other_size)
     assert sigs == sum(sizes) + lost
-    # the live judge, one event a block
-    assert judged(recorder) == {h: int(h in other_size)
-                                for h in range(1, n_blocks + 1)}
+    # the live judge, one event a block, and nothing left for it to
+    # verify again: the join's one repair took the joiner's lanes
+    assert judged(recorder) == {h: 0 for h in range(1, n_blocks + 1)}
+    assert repaired(recorder) == {grows_at: len(other_size)}
 
     # a forged signature there is refused at its height
     at = other_size[1]
@@ -457,7 +465,10 @@ def test_a_set_that_changes_size_stays_on_the_pooled_path(
         len({v.pubkey for v in store_set(reactor, h).validators}
             - {v.pubkey for v in collected_under[h].validators})
         for h in heights)
-    assert lost == unseen == sum(judged(recorder).values())
+    assert lost == unseen == sum(repaired(recorder).values())
+    assert set(repaired(recorder)) == {
+        h for h, kind in placed.items() if kind == JOIN}
+    assert set(judged(recorder).values()) == {0}
     if case == "a_set_that_shrinks":
         assert larger and not smaller and lost == 0
     if case == "grown_then_shrunk_past_its_windows":
